@@ -28,11 +28,12 @@ at the old window-4096/kv-2^20 shape). kv 2^18 holds the 100k-key
 workload at 0.38 load, comfortable for the two-choice table.
 
 Writes one JSON object to BENCH_TCP.json. Run: ``python bench_tcp.py``
-(``BENCH_TCP_Q`` overrides the per-trial request count). Servers run
-on the CPU JAX backend (N processes cannot share one TPU —
-models/cluster.py pod mode is the on-accelerator deployment; this file
-measures the HOST runtime: framed TCP wire, batched column packing,
-durable store).
+(``BENCH_TCP_Q`` overrides the per-trial request count). One process
+owns the chip, so the N server PROCESSES this file boots run on the CPU
+JAX backend: it measures the HOST runtime (framed TCP wire, batched
+column packing, durable store). Replicas whose steps run on the chip
+live in one process — chip_smoke.py phase B serves this file's
+``SERVER_SHAPE`` that way.
 """
 
 from __future__ import annotations

@@ -4,8 +4,9 @@ A brand-new framework with the capabilities of arobertlin/MinPaxos (a
 Go Multi-Paxos replicated key-value store; see SURVEY.md at the repo
 root), re-designed for TPU hardware: quorum voting over thousands of
 independent Paxos instances is computed as batched, data-parallel array
-ops inside single XLA-compiled steps (JAX / pjit / shard_map / Pallas),
-instead of one goroutine per message.
+ops inside single XLA-compiled steps (JAX: jit / vmap / lax.scan, laid
+over a device mesh with NamedSharding), instead of one goroutine per
+message.
 
 Subpackages
 -----------

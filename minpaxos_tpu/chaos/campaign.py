@@ -218,7 +218,13 @@ class ChaosCluster:
     def __init__(self, n: int = 3, store_dir: str | None = None,
                  durable: bool = False, tick_s: float = 0.001,
                  q1: int = 0, q2: int = 0,
-                 flags: dict | None = None):
+                 flags: dict | None = None, cfg=None,
+                 boot_timeout_s: float = 20.0):
+        """``cfg``: a full MinPaxosConfig in place of the 1,024-slot
+        harness default (chip_smoke.py serves at bench_tcp's
+        SERVER_SHAPE); ``boot_timeout_s``: how long the leader may take
+        to report prepared — a first compile on a TPU outlasts the
+        CPU-calibrated 20 s."""
         # late imports: chaos/__init__ must stay importable without JAX
         from minpaxos_tpu.models.minpaxos import MinPaxosConfig
         from minpaxos_tpu.runtime.master import Master, register_with_master
@@ -247,7 +253,7 @@ class ChaosCluster:
             for host, port in self.addrs:
                 register_with_master(self.maddr, host, port,
                                      timeout_s=10.0)
-            self.cfg = MinPaxosConfig(
+            self.cfg = cfg or MinPaxosConfig(
                 n_replicas=n, window=1 << 10, inbox=1024, exec_batch=512,
                 kv_pow2=12, catchup_rows=64, recovery_rows=64,
                 q1=q1, q2=q2)
@@ -267,7 +273,7 @@ class ChaosCluster:
                 self.servers[i] = s
             # "prepared" is leader state (replica 0 owns the initial
             # phase 1; followers never set it) — wait for it, loudly
-            deadline = time.monotonic() + 20
+            deadline = time.monotonic() + boot_timeout_s
             while not self.servers[0].snapshot["prepared"]:
                 if time.monotonic() > deadline:
                     # fail loud: driving load into an unprepared
@@ -275,7 +281,8 @@ class ChaosCluster:
                     # (acked != expected) and sends the operator
                     # replaying a seed that chases a boot problem
                     raise TimeoutError(
-                        "leader not prepared within 20 s of boot")
+                        f"leader not prepared within {boot_timeout_s:g} "
+                        f"s of boot")
                 time.sleep(0.05)
         except BaseException:
             self.stop()

@@ -15,6 +15,7 @@ paces one ``-batch`` per interval).
 from __future__ import annotations
 
 import argparse
+import sys
 import threading
 import time
 
@@ -155,6 +156,7 @@ def main(argv=None) -> None:
     cli = Client((args.maddr, args.mport), check=args.check)
 
     total_acked = 0
+    check_failed = False
     t_all = time.monotonic()
     for rnd in range(args.r):
         ops, keys, vals = gen_workload(
@@ -278,6 +280,8 @@ def main(argv=None) -> None:
                 if not stats["missing"] and not stats["duplicates"]:
                     print("CHECK OK: exactly-once for all commands",
                           flush=True)
+                else:
+                    check_failed = True
         # fresh cmd_id space per round
         cli.replies.clear()
         cli.rejected.clear()
@@ -291,6 +295,8 @@ def main(argv=None) -> None:
     if multi is not None:
         multi.close()
     cli.close_conn()
+    if check_failed:
+        sys.exit(1)  # a failed -check is a failed run, not a printout
 
 
 if __name__ == "__main__":

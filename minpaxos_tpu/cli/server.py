@@ -3,9 +3,12 @@
 The reference's protocol selector flags are honored: ``-min`` (MinPaxos,
 the default and only active path in the reference too — server.go:58-79
 has every other protocol commented out). ``-platform`` picks the JAX
-backend; the default is ``cpu`` because N replica processes on one host
-cannot share one TPU — pod mode (models/cluster.py) or the sharded mesh
-(parallel/) are the on-accelerator deployments.
+backend and is the ONLY selector: an absent platform fails the boot,
+loudly, before the replica registers. One process owns a chip, so N
+server processes on one host run ``-platform cpu`` (the default);
+replicas whose steps run on the chip live in ONE process
+(chip_smoke.py phase B composes them from these same flags), as do pod
+mode (models/cluster.py) and the sharded mesh (parallel/).
 """
 
 from __future__ import annotations
@@ -17,7 +20,7 @@ import sys
 import time
 
 
-def main(argv=None) -> None:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser("minpaxos-server")
     p.add_argument("-port", type=int, default=7070, help="data port")
     p.add_argument("-addr", default="127.0.0.1", help="listen address")
@@ -187,7 +190,72 @@ def main(argv=None) -> None:
                    help="jax platform for the replica step (cpu/tpu)")
     p.add_argument("-cpuprofile", default="",
                    help="write a profile dump on SIGINT (pprof-style)")
-    args = p.parse_args(argv)
+    return p
+
+
+def config_from_args(args, n_replicas: int):
+    """The MinPaxosConfig these flags compile for an N-replica
+    deployment (quorum pair certified before anything serves)."""
+    from minpaxos_tpu.models.minpaxos import MinPaxosConfig
+
+    # kv_pow2 default 16 (65536 slots) comfortably dominates the
+    # client's default -sr key range (30000) — the runtime FAIL-STOPS
+    # on table saturation rather than silently dropping acknowledged
+    # writes (the reference's Go map just grows, state.go:33-36), so
+    # capacity and key space must be sized together: the bucketized
+    # two-choice table (ops/kvstore.py) keeps per-tick cost O(batch),
+    # but the table's residual per-step traffic still grows with
+    # capacity — raise -kvpow2 deliberately, with the workload in
+    # mind (keep load under ~0.5 for comfortable two-choice placement)
+    cfg = MinPaxosConfig(
+        n_replicas=n_replicas, window=args.window, inbox=args.inbox,
+        exec_batch=args.execbatch or args.inbox, kv_pow2=args.kvpow2,
+        catchup_rows=256, recovery_rows=256,
+        gossip_ticks=args.gossipticks, noop_delay=args.noopdelay,
+        explicit_commit=args.classic and not args.mencius,
+        q1=args.q1, q2=args.q2)
+    # refuse a split-brain-capable (q1, q2) BEFORE serving traffic;
+    # the raised witness is the pair of disjoint quorums
+    from minpaxos_tpu.verify.quorum import validate_config_quorums
+
+    validate_config_quorums(cfg)
+    return cfg
+
+
+def flags_from_args(args, profile=None):
+    """The RuntimeFlags these flags select — long-lived deployments
+    precompile their step variants (warm_variants)."""
+    from minpaxos_tpu.runtime.replica import RuntimeFlags
+
+    return RuntimeFlags(dreply=args.dreply,
+                        durable=args.durable, thrifty=args.thrifty,
+                        beacon=args.beacon, store_dir=args.storedir,
+                        fuse_ticks=args.fuseticks,
+                        idle_fastpath=not args.noidlefast,
+                        idle_skip_max_s=args.idlemaxskip,
+                        narrow_window=args.narrow,
+                        pipeline=not args.nopipeline,
+                        coalesce=not args.nocoalesce,
+                        coalesce_wait_us=args.coalesce_wait_us,
+                        coalesce_rows=args.coalesce_rows,
+                        overlap_exec=not args.nooverlapexec,
+                        key_hint=args.keyhint,
+                        warm_variants=True,
+                        recorder=not args.norecorder,
+                        recorder_ring=args.recring,
+                        trace=not args.notrace,
+                        trace_pow2=args.tracepow2,
+                        trace_ring=args.tracering,
+                        watch=not args.nowatch,
+                        watch_ring=args.watchring,
+                        snapshots=not args.nosnap,
+                        snap_every_bytes=args.snap_every,
+                        snap_interval_s=args.snap_interval,
+                        profile=profile)
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
 
     # opportunistic native-layer build (C++ frame scan + cycle clock);
     # everything falls back to pure Python when g++ is absent
@@ -198,6 +266,11 @@ def main(argv=None) -> None:
     import jax
 
     jax.config.update("jax_platforms", args.platform)
+    # touch the backend NOW: an absent platform must fail the boot
+    # here, before this process registers as a replica
+    dev = jax.devices()[0]
+    print(f"server: jax platform {dev.platform} ({dev.device_kind})",
+          flush=True)
     # shared persistent compile cache: without it every server process
     # re-jits identical kernels at boot (~10-40 s each, and concurrent
     # first boots starve each other on small hosts — utils/backend.py)
@@ -205,9 +278,8 @@ def main(argv=None) -> None:
 
     enable_compile_cache()
 
-    from minpaxos_tpu.models.minpaxos import MinPaxosConfig
     from minpaxos_tpu.runtime.master import get_replica_list, register_with_master
-    from minpaxos_tpu.runtime.replica import ReplicaServer, RuntimeFlags
+    from minpaxos_tpu.runtime.replica import ReplicaServer
 
     maddr = (args.maddr, args.mport)
     my_id = register_with_master(maddr, args.addr, args.port)
@@ -220,57 +292,13 @@ def main(argv=None) -> None:
     print(f"server: registered as replica {my_id} of {len(nodes)}",
           flush=True)
 
-    protocol = ("mencius" if args.mencius
-                else "classic" if args.classic else "minpaxos")
-    # kv_pow2 default 16 (65536 slots) comfortably dominates the
-    # client's default -sr key range (30000) — the runtime FAIL-STOPS
-    # on table saturation rather than silently dropping acknowledged
-    # writes (the reference's Go map just grows, state.go:33-36), so
-    # capacity and key space must be sized together: the bucketized
-    # two-choice table (ops/kvstore.py) keeps per-tick cost O(batch),
-    # but the table's residual per-step traffic still grows with
-    # capacity — raise -kvpow2 deliberately, with the workload in
-    # mind (keep load under ~0.5 for comfortable two-choice placement)
-    cfg = MinPaxosConfig(
-        n_replicas=len(nodes), window=args.window, inbox=args.inbox,
-        exec_batch=args.execbatch or args.inbox, kv_pow2=args.kvpow2,
-        catchup_rows=256, recovery_rows=256,
-        gossip_ticks=args.gossipticks, noop_delay=args.noopdelay,
-        explicit_commit=args.classic and not args.mencius,
-        q1=args.q1, q2=args.q2)
-    # refuse a split-brain-capable (q1, q2) BEFORE serving traffic;
-    # the raised witness is the pair of disjoint quorums
-    from minpaxos_tpu.verify.quorum import validate_config_quorums
-
-    validate_config_quorums(cfg)
+    cfg = config_from_args(args, len(nodes))
     prof = cProfile.Profile() if args.cpuprofile else None
-    flags = RuntimeFlags(dreply=args.dreply,
-                         durable=args.durable, thrifty=args.thrifty,
-                         beacon=args.beacon, store_dir=args.storedir,
-                         fuse_ticks=args.fuseticks,
-                         idle_fastpath=not args.noidlefast,
-                         idle_skip_max_s=args.idlemaxskip,
-                         narrow_window=args.narrow,
-                         pipeline=not args.nopipeline,
-                         coalesce=not args.nocoalesce,
-                         coalesce_wait_us=args.coalesce_wait_us,
-                         coalesce_rows=args.coalesce_rows,
-                         overlap_exec=not args.nooverlapexec,
-                         key_hint=args.keyhint,
-                         warm_variants=True,
-                         recorder=not args.norecorder,
-                         recorder_ring=args.recring,
-                         trace=not args.notrace,
-                         trace_pow2=args.tracepow2,
-                         trace_ring=args.tracering,
-                         watch=not args.nowatch,
-                         watch_ring=args.watchring,
-                         snapshots=not args.nosnap,
-                         snap_every_bytes=args.snap_every,
-                         snap_interval_s=args.snap_interval,
-                         profile=prof)
-    server = ReplicaServer(my_id, [tuple(n) for n in nodes], cfg, flags,
-                           protocol=protocol)
+    server = ReplicaServer(my_id, [tuple(n) for n in nodes], cfg,
+                           flags_from_args(args, profile=prof),
+                           protocol=("mencius" if args.mencius
+                                     else "classic" if args.classic
+                                     else "minpaxos"))
 
     server.start()
     print(f"server: replica {my_id} serving on {args.addr}:{args.port}",

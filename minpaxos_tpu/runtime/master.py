@@ -321,33 +321,31 @@ def register_with_master(maddr: tuple[str, int], my_host: str, my_port: int,
                          retry_s: float = 0.25, timeout_s: float = 60.0,
                          seed: int | None = None) -> int:
     """Server-side registration retry loop (server.go:91-108). Returns
-    the assigned replica id once the full membership is known. Retries
-    back off exponentially (jittered, seeded by ``seed`` or the
-    caller's port so concurrent registrants decorrelate) instead of
-    the old fixed 0.5 s cadence."""
+    the replica id as soon as the master assigns one; the full
+    membership is awaited by ``get_replica_list``, which every server
+    calls next (waiting for it here as well cost a harness that
+    registers its replicas one after another a full ``timeout_s`` per
+    replica but the last). Retries back off exponentially (jittered,
+    seeded by ``seed`` or the caller's port so concurrent registrants
+    decorrelate) instead of the old fixed 0.5 s cadence."""
     import numpy as _np
 
     rng = _np.random.default_rng(my_port if seed is None else seed)
     sleeps = backoff_sleeps(retry_s, 3.0, rng)
     deadline = time.monotonic() + timeout_s
-    rid = None
     while time.monotonic() < deadline:
         try:
             resp = _rpc(maddr, {"m": "register",
                                 "addr": my_host, "port": my_port})
             if resp.get("ok"):
-                rid = int(resp["id"])
-                if resp.get("ready"):
-                    return rid
-            # reachable master, membership not complete yet: this is a
-            # readiness poll, not a failure — base cadence, streak reset
+                return int(resp["id"])
+            # reachable master that refuses (cluster full): a poll, not
+            # a failure — base cadence, streak reset
             sleeps = backoff_sleeps(retry_s, 3.0, rng)
             sleep_s = retry_s
         except (OSError, json.JSONDecodeError):
             sleep_s = next(sleeps)
         time.sleep(min(sleep_s, max(deadline - time.monotonic(), 0.05)))
-    if rid is not None:
-        return rid
     raise TimeoutError("could not register with master")
 
 

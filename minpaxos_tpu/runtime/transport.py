@@ -350,6 +350,13 @@ class Transport:
         while not self._stop.is_set():
             try:
                 chunk = sock.recv(1 << 16)
+            except TimeoutError:
+                # a dialed socket keeps dial_peer's 1 s timeout: a read
+                # that times out is an IDLE link, not a dead one (every
+                # quiet link used to drop after a second, and a replica
+                # that only accepts — id 0 — then sat out its whole
+                # _wait_for_peers window behind a long first compile)
+                continue
             except OSError:
                 break
             if not chunk:

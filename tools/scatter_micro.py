@@ -16,7 +16,8 @@ M=1408 updates, S=2048 targets):
   b. argmax+gather — 1 scatter-max of row index, then 10 gathers
   c. onehot-matmul — one-hot [S, M] f32 matmul against [M, 10] payload
 
-Run (relay must be free): python tools/scatter_micro.py
+Run: python tools/scatter_micro.py (on the machine with the chip; one
+process owns it)
 """
 
 from __future__ import annotations

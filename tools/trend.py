@@ -19,11 +19,9 @@ cheap enough to paste into a PR description.
     python tools/trend.py              # markdown tables on stdout
     python tools/trend.py --json      # machine form
 
-Driver captures (`BENCH_r*.json`) are best-effort parses: some rounds
-crashed mid-write (r01), some hold only a replayed prior record in a
-truncated tail (r05) — rows from a replay are labeled `replay`, rows
-with no parseable record report their error instead of a number, and
-nothing is ever silently skipped.
+Driver captures (`BENCH_r*.json`) are best-effort parses — there may
+be none: a capture with no parseable record reports its error instead
+of a number, and nothing is ever silently skipped.
 """
 
 from __future__ import annotations
@@ -39,37 +37,10 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parents[1]
 
 
-def _balanced_json(text: str, start: int) -> dict | None:
-    """Parse the {...} object starting at ``start`` by brace matching
-    (tolerates trailing garbage; returns None on truncation)."""
-    depth = 0
-    in_str = esc = False
-    for i in range(start, len(text)):
-        c = text[i]
-        if esc:
-            esc = False
-        elif c == "\\":
-            esc = True
-        elif c == '"':
-            in_str = not in_str
-        elif not in_str:
-            if c == "{":
-                depth += 1
-            elif c == "}":
-                depth -= 1
-                if depth == 0:
-                    try:
-                        return json.loads(text[start:i + 1])
-                    except json.JSONDecodeError:
-                        return None
-    return None
-
-
 def _extract_record(cap: dict) -> tuple[dict | None, str]:
     """(bench record, provenance) from one BENCH_r*.json driver
     capture: the `parsed` record when the driver got one, else the
-    last parseable JSON line of the captured tail, else an embedded
-    `"record":` replay inside a truncated tail (labeled as such)."""
+    last parseable JSON line of the captured tail."""
     rec = cap.get("parsed")
     if isinstance(rec, dict) and "value" in rec:
         return rec, "live"
@@ -82,12 +53,6 @@ def _extract_record(cap: dict) -> tuple[dict | None, str]:
             continue
         if "value" in rec:
             return rec, "live"
-    i = tail.find('"record":')
-    if i >= 0:
-        j = tail.find("{", i)
-        rec = _balanced_json(tail, j) if j >= 0 else None
-        if isinstance(rec, dict) and "value" in rec:
-            return rec, "replay"
     return None, "unparseable"
 
 
@@ -101,11 +66,6 @@ def _fmt(v, nd=1):
 
 def _row_from_record(name: str, rec: dict, provenance: str,
                      mtime: float) -> dict:
-    # a record whose own headline is the error stanza may still carry
-    # a replayed prior value at top level (bench.py replay_marks)
-    value = rec.get("value")
-    if rec.get("error") and not value and rec.get("replayed_value"):
-        value, provenance = rec["replayed_value"], "replay"
     shape = rec.get("shape") or {}
     return {
         "artifact": name,
@@ -115,7 +75,7 @@ def _row_from_record(name: str, rec: dict, provenance: str,
         # flexible quorums (PR 16): absent on pre-PR-16 artifacts
         "q1": rec.get("q1"),
         "q2": rec.get("q2"),
-        "inst_per_sec": value,
+        "inst_per_sec": rec.get("value"),
         "p50_ms": rec.get("p50_quorum_decision_ms",
                           rec.get("p50_quorum_decision_ms_censored")),
         "p99_ms": rec.get("p99_quorum_decision_ms"),
@@ -465,8 +425,7 @@ def render_markdown(bench, tcp, progress, health=None, verify=None,
            "| p99 ms | concurrent | shape | note |")
     out += [hdr, "|" + "---|" * 10]
     for r in bench:
-        note = r.get("error") or (
-            "replay" if r.get("provenance") == "replay" else "")
+        note = r.get("error") or ""
         shape = r.get("shape", "-")
         if r.get("q1") and r.get("q2"):
             shape = f"{shape} q={r['q1']}/{r['q2']}"
