@@ -1,0 +1,10 @@
+"""Median milliseconds the leader's protocol thread spent blocked in the three ``np.asarray`` reads of a dispatch,
+per loaded dispatch: the recorder's ``readback_us`` (span ``paxos.tick.readback``).
+A median: neither the 2 s of warm-up at the cell's own rate nor the 4
+profiled seconds in the ring move it."""
+
+from benchmarks.lib import progobs
+
+
+def read(obs):
+    return progobs.tick_median_ms("readback_us")

@@ -1,0 +1,10 @@
+"""Median milliseconds the leader's protocol thread spent in ``_reply_stacked``,
+per loaded dispatch: the recorder's ``reply_us`` (span ``paxos.tick.reply``).
+A median: neither the 2 s of warm-up at the cell's own rate nor the 4
+profiled seconds in the ring move it."""
+
+from benchmarks.lib import progobs
+
+
+def read(obs):
+    return progobs.tick_median_ms("reply_us")

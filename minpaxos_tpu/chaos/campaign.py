@@ -564,7 +564,7 @@ def run_schedule(name: str, seed: int, n: int = 3, ops_n: int = 400,
         kinds = counts_by_kind(aligned)
         result["cluster_events"] = kinds
         if durable:
-            # the durability scorecard tools/trend.py rows key on:
+            # the durability scorecard:
             # did snapshots happen, how much log did truncation free,
             # how long did crash recovery take, where did disk end up
             from minpaxos_tpu.obs.watch import (

@@ -121,11 +121,14 @@ def _route_segmented(cfg: MinPaxosConfig, out_msgs: MsgBatch,
     tests/test_route_fabric.py and the golden kernel fixtures."""
     r = cfg.n_replicas
     m = out_msgs.kind.shape[1]
-    flat = jax.tree_util.tree_map(lambda x: x.reshape(-1), out_msgs)  # [R*M]
-    src_rep = jnp.repeat(jnp.arange(r, dtype=jnp.int32), m)
-    win, hit = route_plan(flat.kind, src_rep, dst.reshape(-1), alive,
-                          capacity)
-    return gather_rows(flat, win, hit)
+    with jax.named_scope("px.route.plan"):
+        flat = jax.tree_util.tree_map(lambda x: x.reshape(-1),
+                                      out_msgs)  # [R*M]
+        src_rep = jnp.repeat(jnp.arange(r, dtype=jnp.int32), m)
+        win, hit = route_plan(flat.kind, src_rep, dst.reshape(-1), alive,
+                              capacity)
+    with jax.named_scope("px.route.gather"):
+        return gather_rows(flat, win, hit)
 
 
 def _deliver_inbox(cfg: MinPaxosConfig, pending: MsgBatch, ext: MsgBatch,
@@ -180,7 +183,8 @@ def cluster_step_impl(
     # strip the gate at this choke point so callers don't each have to
     # remember to pass gate_exec=False
     cfg = cfg._replace(gate_exec=False)
-    inbox = _deliver_inbox(cfg, cs.pending, ext, cs.alive)
+    with jax.named_scope("px.deliver"):
+        inbox = _deliver_inbox(cfg, cs.pending, ext, cs.alive)
     states, outbox, execr = jax.vmap(
         functools.partial(step_impl, cfg))(cs.states, inbox)
     route = _route if cfg.route_fabric == "dense" else _route_segmented

@@ -71,6 +71,7 @@ from minpaxos_tpu.ops.ackruns import (
 )
 from minpaxos_tpu.ops.kvstore import KVState, kv_apply_batch, kv_init
 from minpaxos_tpu.ops.scan import commit_frontier, segmented_scan_max
+from minpaxos_tpu.ops.sections import Sections
 from minpaxos_tpu.ops.winner import gather_const, gather_row, slot_winner
 from minpaxos_tpu.wire.messages import MsgKind, Op
 
@@ -159,6 +160,14 @@ def mencius_step_impl(
     substeps of a fused burst — see models/minpaxos.py
     replica_step_impl); keeps the stall/takeover counters wall-honest
     under the TCP runtime's multi-substep dispatches."""
+    with Sections() as sec:
+        return _mencius_step_sections(sec, cfg, state, inbox, tick_inc)
+
+
+def _mencius_step_sections(sec, cfg, state, inbox, tick_inc):
+    """``mencius_step_impl``'s body; ``sec(name)`` opens the ``px.*``
+    scope of the section that follows (ops/sections.py). Sections that
+    MinPaxos has too carry its names."""
     S, R = cfg.window, cfg.n_replicas
     M = inbox.kind.shape[0]
     # flexible quorums (models/minpaxos.py config field note): the
@@ -183,6 +192,7 @@ def mencius_step_impl(
     out = MsgBatch.empty(M)
     dst = jnp.full(M, -1, jnp.int32)
 
+    sec("px.propose")
     # ---- 1. PROPOSE into my owned slots (handlePropose :429-447) ----
     csum_p = jnp.cumsum(is_propose.astype(jnp.int32))
     prefix = csum_p - 1
@@ -243,6 +253,7 @@ def mencius_step_impl(
     )
     dst = jnp.where(fits, -1, jnp.where(reject, -2, dst))
 
+    sec("px.accept")
     # ---- 2. ACCEPT from other owners (handleAccept :503-590) ----
     rel_a, in_win_a = _rel(state, inbox.inst, S)
     rel_a_safe = jnp.minimum(rel_a, S - 1)
@@ -327,6 +338,7 @@ def mencius_step_impl(
     )
     dst = jnp.where(is_accept, inbox.src, dst)
 
+    sec("px.skip_cede")
     # ---- 3. skip-cede (handleAccept's skip side, :520-556) ----
     # Accepts for slots ahead of my cursor mean peers are running ahead
     # of me: cede my untouched owned slots below the horizon as
@@ -361,6 +373,7 @@ def mencius_step_impl(
             jnp.min(jnp.where(cede, idx_abs, jnp.int32(2 ** 30))), 0)[None],
     )
 
+    sec("px.skip_rows")
     # ---- 4. SKIP rows from peers (handleSkip :449-501) ----
     # Mark src's owned slots in [start, end] as committed no-ops.
     # Safe against value loss: only the owner proposes VALUES at
@@ -387,6 +400,7 @@ def mencius_step_impl(
                              jnp.max(jnp.where(is_skip, inbox.inst, -1)) + 1),
     )
 
+    sec("px.vote_count")
     # ---- 5. ACCEPT_REPLY vote counting (handleAcceptReply :692-742) --
     # One reply row acks [inst, inst + count) (run-length compression;
     # count in cmd_id). Ranges expand to per-slot coverage via a
@@ -417,6 +431,7 @@ def mencius_step_impl(
             vote_cov & drv_slot[:, None]),
         peer_commits=jnp.where(replied, pc_seen[:R], state.peer_commits))
 
+    sec("px.commit_rows")
     # ---- 6. COMMIT rows (explicit commit transfer, bcastCommit) ----
     rel_c, in_win_c = _rel(state, inbox.inst, S)
     com_ok = is_commit & in_win_c
@@ -443,6 +458,7 @@ def mencius_step_impl(
                 jnp.maximum(inbox.inst, inbox.last_committed), -1)) + 1),
     )
 
+    sec("px.takeover_phase1")
     # ---- 7. takeover phase 1 (forceCommit :244-257, :878-897) ----
     # 7a. answer PREPARE_INST: my slot contents or explicit empty; a
     # promise here blocks my own future ballot-0 writes only if the
@@ -517,6 +533,7 @@ def mencius_step_impl(
         votes=gather_const(hit_v, me_bit, state.votes),
     )
 
+    sec("px.commit_scan")
     # ---- 8. commit scan: my owned slots at majority, frontier ----
     n_votes = jax.lax.population_count(state.votes).astype(jnp.int32)
     driven_by_me = own_mask | (
@@ -538,6 +555,7 @@ def mencius_step_impl(
         stall_ticks=jnp.where(in_flight & ~advanced,
                               state.stall_ticks + tick_inc, 0))
 
+    sec("px.commit_bcast")
     # ---- 9. chunked COMMIT broadcast for my newly committed slots ----
     # Strides over MY OWN slots (me, me+R, ...): a window over raw log
     # slots would contain only 1/R own slots, capping the announce rate
@@ -687,6 +705,7 @@ def mencius_step_impl(
         client_id=state.client_id[cu_rel_safe],
     )
 
+    sec("px.takeover")
     # ---- 10. takeover driver: successor sweeps the blocked range ----
     blocking = state.committed_upto + 1
     blk_owner = jnp.mod(blocking, R)
@@ -777,6 +796,7 @@ def mencius_step_impl(
         takeover_ballot=jnp.where(advanced, jnp.int32(NO_BALLOT),
                                   state.takeover_ballot))
 
+    sec("px.outbox")
     out = _concat_rows(_concat_rows(_concat_rows(_concat_rows(_concat_rows(
         _concat_rows(_concat_rows(out, skip_row), cb), ta), rt), cu), tk), rd)
     dst = jnp.concatenate([
@@ -790,6 +810,7 @@ def mencius_step_impl(
         jnp.full(K2, -1, jnp.int32),   # takeover re-drive
     ])
 
+    sec("px.exec")
     # ---- 11. conflict-aware out-of-order execution (:799-876) ----
     # A committed, unexecuted slot executes this step iff every EARLIER
     # window slot that conflicts with it (same key, at least one PUT —
@@ -910,6 +931,7 @@ def mencius_step_impl(
         client_id=jnp.where(evalid, state.client_id[slot_of_safe], 0),
     )
 
+    sec("px.window_slide")
     # ---- 12. window slide (same scheme as minpaxos step 9) ----
     if cfg.slide_window:
         retention = cfg.retention if cfg.retention >= 0 else S // 2

@@ -45,6 +45,7 @@ from minpaxos_tpu.ops.ackruns import (
 )
 from minpaxos_tpu.ops.kvstore import KVState, kv_apply_batch, kv_init
 from minpaxos_tpu.ops.scan import commit_frontier
+from minpaxos_tpu.ops.sections import Sections
 from minpaxos_tpu.wire.messages import MsgKind
 
 # Log-slot statuses (reference minpaxosproto.go:8-15 plus EXECUTED,
@@ -413,6 +414,13 @@ def replica_step_impl(
     first substep and 0 for the rest; every other caller uses the
     default 1.
     """
+    with Sections() as sec:
+        return _replica_step_sections(sec, cfg, state, inbox, tick_inc)
+
+
+def _replica_step_sections(sec, cfg, state, inbox, tick_inc):
+    """``replica_step_impl``'s body; ``sec(name)`` opens the ``px.*``
+    scope of the section that follows (ops/sections.py)."""
     S, R = cfg.window, cfg.n_replicas
     M = inbox.kind.shape[0]  # actual batch rows (pending + ext concat)
     # flexible quorums (config field note): phase-1 sites take q1,
@@ -432,6 +440,7 @@ def replica_step_impl(
     out = MsgBatch.empty(M)
     dst = jnp.full(M, -1, jnp.int32)
 
+    sec("px.prepare")
     # ---- 1. PREPARE (handlePrepare bareminpaxos.go:712-751) ----
     # Adopt the highest proposed ballot if it beats our promise.
     prep_ballot = jnp.max(jnp.where(is_prep, inbox.ballot, NO_BALLOT))
@@ -460,6 +469,7 @@ def replica_step_impl(
     )
     dst = jnp.where(is_prep, inbox.src, dst)
 
+    sec("px.phase1_reply")
     # ---- 1c. PREPARE_INST_REPLY: phase-1 answers for the leader's
     # per-instance discovery sweep (see 1e/7e). Two effects:
     # * value adoption — the highest-vballot reported value is adopted
@@ -509,6 +519,7 @@ def replica_step_impl(
     hit_v = vb_max[:S] > NO_BALLOT
     ballot1 = jnp.where(hit_v, vb_max[:S], state.ballot)
 
+    sec("px.accept")
     # ---- 2. ACCEPT (handleAccept :753-806) ----
     # Seeing a higher ballot in an ACCEPT also deposes us: a leader
     # that missed the new leader's PREPARE must stop serving, or two
@@ -535,6 +546,7 @@ def replica_step_impl(
         jnp.where(acc_pre, rel_i, S)].max(inbox.ballot, mode="drop")
     acc_ok = acc_pre & (inbox.ballot == ab_max[rel_i_safe])
 
+    sec("px.slot_write_a")
     # ---- fused slot write A (PIR + ACCEPT) ----
     # One keyed winner scatter replaces the two sections' slot_winner
     # passes and 2x9 column writes: key = section*M + row, so an
@@ -573,6 +585,7 @@ def replica_step_impl(
             jnp.maximum(jnp.max(jnp.where(pir_ok, inbox.inst, -1)),
                         jnp.max(jnp.where(acc_ok, inbox.inst, -1))) + 1),
     )
+    sec("px.accept_ack")
     # A re-ACCEPT of a slot we already hold COMMITTED is acked (not
     # NACKed) iff it carries the identical decided value: commitment is
     # final, so voting for the decided value again is always safe, and
@@ -637,6 +650,7 @@ def replica_step_impl(
                            & (inbox.ballot >= state.default_ballot),
                            inbox.last_committed, -1))
 
+    sec("px.prepare_inst")
     # ---- 2b. PREPARE_INST (classic per-instance phase 1; the pull
     # side of new-leader value discovery — see 7e) ----
     # Answer ONLY truthfully: slots in our window answer with contents
@@ -690,6 +704,7 @@ def replica_step_impl(
             state.crt_inst,
             jnp.max(jnp.where(is_pinst, inbox.inst, -1)) + 1))
 
+    sec("px.commit_rows")
     # ---- 3. COMMIT rows (explicit per-slot commit, cold path) ----
     # A replica with no known leader (revived with an empty store into
     # a quiescent cluster) adopts the committer as its leader hint, so
@@ -712,6 +727,7 @@ def replica_step_impl(
             state.crt_inst, jnp.max(jnp.where(com_ok, inbox.inst, -1)) + 1),
     )
 
+    sec("px.prepare_reply")
     # ---- 4. PREPARE_REPLY (handlePrepareReply :912-966) ----
     pr_ok = (
         is_prep_reply
@@ -742,6 +758,7 @@ def replica_step_impl(
         | (state.is_leader & (state.prepare_oks.sum() >= quorum1)),
     )
 
+    sec("px.propose")
     # ---- 5. PROPOSE (handlePropose :617-710) ----
     can_serve = state.is_leader & state.prepared
     if cfg.fast_path:
@@ -762,6 +779,7 @@ def replica_step_impl(
     rel_p = slots - state.window_base
     fits = prop & (rel_p >= 0) & (rel_p < S)
 
+    sec("px.slot_write_b")
     # ---- fused slot write B (COMMIT + PROPOSE) ----
     # The two sections' targets are disjoint within one batch: every
     # com_ok row bumped crt_inst past its inst (section 3, above), and
@@ -833,6 +851,7 @@ def replica_step_impl(
         )
         dst = jnp.where(fastrow, state.leader_id, dst)
 
+    sec("px.vote_count")
     # ---- 6. ACCEPT_REPLY (handleAcceptReply :1014-1064) ----
     # One reply row acks the RANGE [inst, inst + count) (count in
     # cmd_id — the run-length compression emitted by step 2 / carried
@@ -879,6 +898,7 @@ def replica_step_impl(
         peer_commits=jnp.where(replied, pc_seen[:R], state.peer_commits),
     )
 
+    sec("px.commit_scan")
     # ---- 7. commit scan ----
     idx_abs = state.window_base + jnp.arange(S, dtype=jnp.int32)
     n_votes = jax.lax.population_count(state.votes).astype(jnp.int32)
@@ -904,6 +924,7 @@ def replica_step_impl(
         committed_upto=jnp.maximum(state.committed_upto,
                                    frontier_rel + state.window_base))
 
+    sec("px.gossip")
     # ---- 7b. frontier gossip + stall tracking ----
     # The reference's followers only learn commitment from the NEXT
     # Accept's piggyback (SURVEY.md section 3.2), stalling their exec
@@ -982,6 +1003,7 @@ def replica_step_impl(
     fb_dst = jnp.where(lead_adv, jnp.int32(-1),
                        jnp.clip(state.leader_id, 0, R - 1))[None]
 
+    sec("px.catchup")
     # ---- 7c. catch-up (CatchUpLog, bareminpaxos.go:488-513) ----
     # One peer per step: if its known frontier trails ours, append up
     # to `catchup_rows` committed slots as ACCEPT rows at the current
@@ -1023,6 +1045,7 @@ def replica_step_impl(
         client_id=state.client_id[cu_rel_safe],
     )
 
+    sec("px.retry")
     # ---- 7d. in-flight retry + gap no-op fill ----
     # When the frontier stalls (lost accepts, leader change), rebroad-
     # cast the first `catchup_rows` uncommitted slots at the current
@@ -1102,6 +1125,7 @@ def replica_step_impl(
         client_id=state.client_id[rt_rel_safe],
     )
 
+    sec("px.sweep")
     # ---- 7e. per-instance phase-1 sweep (new-leader value discovery,
     # replacing the reference's one-shot CatchUpLog shipping with a
     # chunked, majority-audited pull: bareminpaxos.go:488-513/:912-966
@@ -1149,6 +1173,7 @@ def replica_step_impl(
             sweep_on, jnp.minimum(cursor + K2, eff_limit), cursor),
     )
 
+    sec("px.outbox")
     out = _concat_rows(_concat_rows(_concat_rows(_concat_rows(out, pi), fb), cu), rt)
     dst = jnp.concatenate([
         dst,
@@ -1158,6 +1183,7 @@ def replica_step_impl(
         jnp.full(K, -1, jnp.int32),  # retry broadcast
     ])
 
+    sec("px.exec")
     # ---- 8. execute (executeCommands :1066-1098) ----
     E = cfg.exec_batch
     avail = state.committed_upto - state.executed_upto
@@ -1202,6 +1228,7 @@ def replica_step_impl(
         client_id=jnp.where(evalid, state.client_id[rel_e_safe], 0),
     )
 
+    sec("px.window_slide")
     # ---- 9. window slide ----
     # Retire the executed prefix: roll every per-slot array left by the
     # executed count and reset the freed tail, advancing window_base.

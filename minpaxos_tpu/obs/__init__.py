@@ -28,10 +28,14 @@ Consumers:
 * ``tools/paxtop.py`` — the live terminal view.
 * ``bench.py`` / ``bench_tcp.py`` — embed end-of-run snapshots in
   their artifacts.
+* a harness that holds the servers in its own process (the
+  benchmark's ``served`` runner) — ``process_collection()`` below.
 
 See OBSERVABILITY.md at the repo root for the metric catalogue and
 the trace field glossary.
 """
+
+import collections
 
 from minpaxos_tpu.obs.metrics import (
     Counter,
@@ -45,6 +49,7 @@ from minpaxos_tpu.obs.recorder import (
     TRACE_PID,
     WATCH_PID,
     FlightRecorder,
+    PhaseClock,
     KIND_FULL,
     KIND_FUSED,
     KIND_IDLE_SKIP,
@@ -55,6 +60,7 @@ from minpaxos_tpu.obs.recorder import (
     TEL_FIELD_NAMES,
     chrome_trace,
     device_round_events,
+    phase,
     telemetry_valid_rows,
     validate_chrome_trace,
 )
@@ -67,6 +73,7 @@ from minpaxos_tpu.obs.trace import (
     analyze_collections,
     format_stage_table,
     is_sampled,
+    protocol_ring_capacity,
     sampled_mask,
     span_chains,
     span_events,
@@ -88,8 +95,42 @@ from minpaxos_tpu.obs.watch import (
     flatten_cluster_stats,
 )
 
+#: the newest replicas of this process, each ``(replica id, registry,
+#: recorder or None, trace sink)``: registered at construction and kept
+#: after ``stop()``, so a harness in the same process can read what a
+#: run left behind without reaching into a server. Bounded: a process
+#: that builds clusters all day (the test suite) keeps sixteen.
+_PROCESS_REPLICAS: collections.deque = collections.deque(maxlen=16)
+
+
+def register_replica(replica: int, registry: MetricsRegistry,
+                     recorder: FlightRecorder | None,
+                     trace_sink: TraceSink) -> None:
+    _PROCESS_REPLICAS.append((replica, registry, recorder, trace_sink))
+
+
+def process_collection() -> list[dict]:
+    """Per registered replica, oldest first: ``replica``, ``rows`` (the
+    recorder's ``snapshot()``; None under -norecorder) with
+    ``rows_total`` ever recorded and the ring's ``rows_capacity``,
+    ``spans`` (``TraceSink.collect()``) and ``metrics`` (the registry's
+    ``snapshot()``). Everything is a copy taken now."""
+    out = []
+    for replica, registry, recorder, sink in list(_PROCESS_REPLICAS):
+        out.append({
+            "replica": replica,
+            "rows": None if recorder is None else recorder.snapshot(),
+            "rows_total": 0 if recorder is None else recorder.total,
+            "rows_capacity": 0 if recorder is None else recorder.capacity,
+            "spans": sink.collect(),
+            "metrics": registry.snapshot()})
+    return out
+
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
+    "process_collection", "register_replica", "PhaseClock", "phase",
+    "protocol_ring_capacity",
     "TICK_MS_BUCKETS", "FlightRecorder", "KIND_FULL", "KIND_FUSED",
     "KIND_NARROW", "KIND_IDLE_SKIP", "KIND_NAMES", "SCHEMA_VERSION",
     "DEVICE_PID", "TRACE_PID", "N_TEL_FIELDS", "TEL_FIELD_NAMES",

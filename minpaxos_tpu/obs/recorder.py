@@ -33,7 +33,9 @@ share a timeline.
 
 from __future__ import annotations
 
+import sys
 import threading
+import time
 
 import numpy as np
 
@@ -61,8 +63,15 @@ import numpy as np
 #: event-driven ingress front batched into this tick's drain) and
 #: ``coal_wake`` (cumulative condition-variable kicks that woke a
 #: parked tick loop), appended AFTER chaos_faults so pre-v7 field
-#: indices still hold.)
-SCHEMA_VERSION = 7
+#: indices still hold. v8: the tick loop's phases are measured by
+#: ``phase`` (below) — one interval, written both into the row and,
+#: while a profile is being taken, into the xplane as a
+#: ``paxos.tick.*`` span on the device trace's clock — and four fields
+#: are appended after coal_wake: ``wait_us`` (the blocking queue wait,
+#: which earlier rows threw away), ``fsync_us`` / ``fsync_bytes`` (the
+#: ``os.fsync`` inside persist_us and the bytes it made durable) and
+#: ``cpu_us`` (the protocol thread's own CPU time over the row).)
+SCHEMA_VERSION = 8
 
 # dispatch regimes (runtime/replica.py classifies one per tick:
 # narrow > fused > full; idle-skip never reaches the device)
@@ -79,12 +88,134 @@ KIND_NAMES = ("full", "fused", "narrow", "idle_skip")
 (F_T_NS, F_KIND, F_K, F_ROWS_IN, F_ROWS_OUT, F_FRONTIER, F_BACKLOG,
  F_DRAIN_US, F_ENQUEUE_US, F_READBACK_US, F_OVERLAP_US, F_PERSIST_US,
  F_DISPATCH_US, F_REPLY_US, F_T_RB_NS, F_CHAOS, F_COAL_OCC,
- F_COAL_WAKE) = range(18)
-N_FIELDS = 18
+ F_COAL_WAKE, F_WAIT_US, F_FSYNC_US, F_FSYNC_BYTES,
+ F_CPU_US) = range(22)
+N_FIELDS = 22
 FIELD_NAMES = ("t_ns", "kind", "k", "rows_in", "rows_out", "frontier",
                "exec_backlog", "drain_us", "enqueue_us", "readback_us",
                "overlap_us", "persist_us", "dispatch_us", "reply_us",
-               "t_rb_ns", "chaos_faults", "coal_occ", "coal_wake")
+               "t_rb_ns", "chaos_faults", "coal_occ", "coal_wake",
+               "wait_us", "fsync_us", "fsync_bytes", "cpu_us")
+
+# ---------------------------------------------------------------- phases
+# The tick loop's spans (schema v8): constant names, so a reduction of
+# the xplane finds them after any refactor, each feeding one row field.
+# fsync nests inside persist (persist_us includes it); the others tile
+# the protocol thread's wall. The two pod spans feed no row: the
+# resident loop's host does nothing but dispatch and read two scalars.
+PH_WAIT = "paxos.tick.wait"
+PH_DRAIN = "paxos.tick.drain"
+PH_ENQUEUE = "paxos.tick.enqueue"
+PH_READBACK = "paxos.tick.readback"
+PH_PERSIST = "paxos.tick.persist"
+PH_FSYNC = "paxos.tick.fsync"
+PH_EGRESS = "paxos.tick.egress"
+PH_REPLY = "paxos.tick.reply"
+PH_POD_DISPATCH = "paxos.pod.dispatch"
+PH_POD_READBACK = "paxos.pod.readback"
+PHASE_FIELDS = {PH_WAIT: F_WAIT_US, PH_DRAIN: F_DRAIN_US,
+                PH_ENQUEUE: F_ENQUEUE_US, PH_READBACK: F_READBACK_US,
+                PH_PERSIST: F_PERSIST_US, PH_FSYNC: F_FSYNC_US,
+                PH_EGRESS: F_DISPATCH_US, PH_REPLY: F_REPLY_US}
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+
+
+def _annotate(name: str, replica: int | None):
+    """A started ``jax.profiler.TraceAnnotation`` while a profile is
+    being taken, else None. JAX is looked up, never imported: a process
+    that has not loaded it (paxtop, tail, the smoke) cannot be taking a
+    JAX profile, and obs/ stays importable without it."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        if getattr(jax, "profiler", None) is None:
+            return None
+        _annotation_cls = jax.profiler.TraceAnnotation
+    if not _annotation_cls.is_enabled():
+        return None
+    if replica is None:
+        return _annotation_cls(name)
+    return _annotation_cls(name, replica=replica)
+
+
+class PhaseClock:
+    """What one tick loop has spent in each phase since the row that
+    phase belongs to was last cut. Single-writer (the protocol thread).
+
+    The outermost phases TILE the thread's wall: one that opens is
+    charged from the instant the one before it closed, so the glue
+    between two ``with`` blocks (counters, the burn-rate check, the
+    recorder's own write: tens of microseconds) belongs to the phase
+    that follows, and a wakeup that cuts no row leaves its time for
+    the next row. The rows of a recorder therefore add up to the wall
+    they span, exactly. A nested phase (fsync inside persist) is
+    charged its own interval only."""
+
+    __slots__ = ("replica", "ns", "_cpu0", "_depth", "_last_end")
+
+    def __init__(self, replica: int):
+        self.replica = replica
+        self.ns = dict.fromkeys(PHASE_FIELDS, 0)
+        self._cpu0: int | None = None
+        self._depth = 0
+        self._last_end = 0  # when the last outermost phase closed
+
+    def take_us(self, name: str) -> int:
+        us, self.ns[name] = self.ns[name] // 1000, 0
+        return us
+
+    def cpu_us(self) -> int:
+        """The calling thread's CPU time since the last call (0 on the
+        first): a blocked wait burns none, so over a row this is how
+        much of its wall the protocol thread actually ran."""
+        now = time.thread_time_ns()
+        prev, self._cpu0 = self._cpu0, now
+        return 0 if prev is None else (now - prev) // 1000
+
+
+class phase:
+    """``with phase(PH_PERSIST, clock): ...`` — one measurement, two
+    readers: the interval is added to ``clock`` (which the next
+    recorder row drains) and, while a JAX profile is being taken,
+    recorded as a TraceAnnotation of that constant name with
+    ``replica=<id>``, on the device trace's clock. ``clock=None``
+    annotates only. With no profile running the cost is two clock
+    reads and one ``is_enabled`` call. ``ns`` is the block's own
+    interval; what the clock is charged may start earlier (see
+    ``PhaseClock``)."""
+
+    __slots__ = ("name", "clock", "ns", "_t0", "_from", "_ann")
+
+    def __init__(self, name: str, clock: PhaseClock | None = None):
+        self.name = name
+        self.clock = clock
+        self.ns = 0  # the interval, once the block has exited
+
+    def __enter__(self):
+        # the annotation opens and closes INSIDE the measured interval:
+        # what a profile costs the loop shows in the rows, not between
+        self._t0 = self._from = time.perf_counter_ns()
+        clock = self.clock
+        if clock is not None:
+            if clock._depth == 0 and clock._last_end:
+                self._from = clock._last_end
+            clock._depth += 1
+        self._ann = _annotate(
+            self.name, None if clock is None else clock.replica)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        end = time.perf_counter_ns()
+        self.ns = end - self._t0
+        clock = self.clock
+        if clock is not None:
+            clock.ns[self.name] += end - self._from
+            clock._depth -= 1
+            if clock._depth == 0:
+                clock._last_end = end
 
 # dispatch-side phases, laid end-to-end ENDING at t_rb_ns (tid 0),
 # and host-side phases ending at t_ns (tid 1 — their own track, so a
@@ -238,7 +369,9 @@ class FlightRecorder:
                enqueue_us: int, readback_us: int, overlap_us: int,
                persist_us: int, dispatch_us: int, reply_us: int,
                t_rb_ns: int = 0, chaos_faults: int = 0,
-               coal_occ: int = 0, coal_wake: int = 0) -> None:
+               coal_occ: int = 0, coal_wake: int = 0, wait_us: int = 0,
+               fsync_us: int = 0, fsync_bytes: int = 0,
+               cpu_us: int = 0) -> None:
         """``t_ns``: when the tick's host phases completed. ``t_rb_ns``:
         when its readback completed (0 = unknown; to_events then lays
         the dispatch phases contiguously before the host phases, which
@@ -249,13 +382,17 @@ class FlightRecorder:
         this tick's drain (0 = no coalescer / no client rows).
         ``coal_wake``: the coalescer's CUMULATIVE wakeup-kick count at
         this tick (schema v7; both default 0 so pre-v7 call sites are
-        unchanged)."""
+        unchanged). Schema v8: ``wait_us`` the blocking queue wait since
+        the last row, ``fsync_us`` / ``fsync_bytes`` the ``os.fsync``
+        inside ``persist_us`` and the bytes it made durable, ``cpu_us``
+        the protocol thread's CPU time since the last row."""
         with self._lock:
             self._buf[self.total % self.capacity] = (
                 t_ns, kind, k, rows_in, rows_out, frontier, backlog,
                 drain_us, enqueue_us, readback_us, overlap_us,
                 persist_us, dispatch_us, reply_us, t_rb_ns, chaos_faults,
-                coal_occ, coal_wake)
+                coal_occ, coal_wake, wait_us, fsync_us, fsync_bytes,
+                cpu_us)
             self.total += 1
 
     def snapshot(self, last: int | None = None) -> np.ndarray:
@@ -304,7 +441,17 @@ class FlightRecorder:
                          "host_us": host_dur,
                          "overlap_us": int(r[F_OVERLAP_US]),
                          "coal_occ": int(r[F_COAL_OCC]),
-                         "coal_wake": int(r[F_COAL_WAKE])}})
+                         "coal_wake": int(r[F_COAL_WAKE]),
+                         "wait_us": int(r[F_WAIT_US]),
+                         "fsync_bytes": int(r[F_FSYNC_BYTES]),
+                         "cpu_us": int(r[F_CPU_US])}})
+            if r[F_WAIT_US] > 0:
+                # the blocking wait BEFORE the tick slice: an idle wait
+                # is not tick cost, but it is where the wall went
+                events.append({"name": "wait", "cat": "phase", "ph": "X",
+                               "ts": t0 - int(r[F_WAIT_US]),
+                               "dur": int(r[F_WAIT_US]),
+                               "pid": pid, "tid": 0})
             if int(r[F_KIND]) != KIND_IDLE_SKIP:
                 t = t0
                 for name, i in _DISPATCH_PHASES:
@@ -322,6 +469,12 @@ class FlightRecorder:
                                        "ph": "X", "ts": t, "dur": d,
                                        "pid": pid, "tid": 1})
                     t += d
+                    if i == F_PERSIST_US and r[F_FSYNC_US] > 0:
+                        # the fsync closes persist: drawn as its child
+                        fs = min(int(r[F_FSYNC_US]), d)
+                        events.append({"name": "fsync", "cat": "phase",
+                                       "ph": "X", "ts": t - fs, "dur": fs,
+                                       "pid": pid, "tid": 1})
             events.append({"name": "frontier", "ph": "C", "ts": t_end,
                            "pid": pid, "tid": 0,
                            "args": {"frontier": int(r[F_FRONTIER])}})

@@ -207,17 +207,24 @@ class TraceSink:
 
     # -- hot path --
 
-    def ring(self) -> SpanRing:
+    def ring(self, capacity: int | None = None) -> SpanRing:
+        """The calling thread's ring. ``capacity`` counts only on a
+        thread's FIRST call: the protocol thread, which stamps four of
+        a command's stages, asks for a ring that holds a whole
+        benchmark window (``protocol_ring_capacity``); reader threads
+        take the sink's default. A dead thread's ring is adopted only
+        at the same capacity."""
         r = getattr(self._tls, "ring", None)
         if r is None:
+            cap = capacity or self.ring_capacity
             me = threading.current_thread()
             with self._lock:
                 for cand, owner in self._rings.items():
-                    if not owner.is_alive():
+                    if not owner.is_alive() and cand.capacity == cap:
                         r = cand
                         break
                 if r is None:
-                    r = SpanRing(self.ring_capacity)
+                    r = SpanRing(cap)
                 self._rings[r] = me
             self._tls.ring = r
         return r
@@ -284,6 +291,26 @@ class TraceSink:
             "anchor": clock_anchor(),
             "spans": rows.tolist(),
         }
+
+
+#: spans the protocol thread's ring holds per sampled command the
+#: window can have in flight: four stages (drain, commit, exec,
+#: reply_ser) x 64 turns of the window
+_PROTOCOL_RING_TURNS = 4 * 64
+_PROTOCOL_RING_MAX = 1 << 16
+
+
+def protocol_ring_capacity(window: int, sample_pow2: int,
+                           floor: int) -> int:
+    """Rows for the protocol thread's span ring, from what a server
+    knows at boot: 256 spans for every sampled command its window can
+    hold, between ``floor`` (the ``-tracering`` flag, the reader
+    threads' size) and 65,536 rows (2.6 MB). At window 2048 and 1-in-16
+    sampling that is 32,768 spans: the knee's 2,750 req/s / 16 x 4
+    stages x 30 s = 21 k, a whole benchmark window, where 4,096 held
+    7 s of it."""
+    want = _PROTOCOL_RING_TURNS * max(window >> max(sample_pow2, 0), 1)
+    return max(floor, min(want, _PROTOCOL_RING_MAX))
 
 
 def clock_anchor() -> dict:

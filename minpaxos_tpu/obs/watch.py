@@ -930,8 +930,8 @@ class HealthSeries:
 
 def load_series(path: str) -> dict:
     """Parse a HealthSeries file back into {"raw": [flat dicts],
-    "coarse": [bucket dicts]} — tools/paxwatch.py --report and
-    trend.py read artifacts through this."""
+    "coarse": [bucket dicts]} — tools/paxwatch.py --report reads
+    artifacts through this."""
     raw, coarse = [], []
     with open(path, encoding="utf-8") as f:
         for ln in f:

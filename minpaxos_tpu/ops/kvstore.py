@@ -47,6 +47,7 @@ import jax.numpy as jnp
 
 from minpaxos_tpu.ops.packed import pair_hash
 from minpaxos_tpu.ops.scan import exclusive_segmented_scan_max, segmented_scan_max
+from minpaxos_tpu.ops.sections import Sections
 from minpaxos_tpu.wire.messages import Op
 
 # Slot states in the table. Buckets have no probe chains to preserve,
@@ -253,6 +254,14 @@ def kv_apply_batch_lanes(kv: KVState, op, k_hi, k_lo, v, valid):
     returns zeros. RLOCK/WLOCK/NONE are no-ops (the reference parses
     but never implements them, state.go:12-19 vs :86-103).
     """
+    with Sections() as sec:
+        return _kv_apply_sections(sec, kv, op, k_hi, k_lo, v, valid)
+
+
+def _kv_apply_sections(sec, kv, op, k_hi, k_lo, v, valid):
+    """``kv_apply_batch_lanes``'s body; ``sec(name)`` opens the
+    ``px.kv.*`` scope of the section that follows (ops/sections.py)."""
+    sec("px.kv.sort")
     b = op.shape[0]
     rows = jnp.arange(b, dtype=jnp.int32)
     is_put = valid & (op == Op.PUT)
@@ -276,6 +285,7 @@ def kv_apply_batch_lanes(kv: KVState, op, k_hi, k_lo, v, valid):
     seg_start = (pos == 0) | (s_khi != jnp.roll(s_khi, 1)) | (s_klo != jnp.roll(s_klo, 1)) \
         | (s_valid != jnp.roll(s_valid, 1))
 
+    sec("px.kv.scan")
     # last write before me within my segment (sorted position, -1 if none)
     wpos = jnp.where(s_write, pos, -1)
     prev_w = exclusive_segmented_scan_max(wpos, seg_start, jnp.int32(-1))
@@ -284,9 +294,11 @@ def kv_apply_batch_lanes(kv: KVState, op, k_hi, k_lo, v, valid):
     prev_present = has_prev & s_put[pw]
     prev_v = s_v[pw]
 
+    sec("px.kv.lookup")
     # pre-batch table state for rows with no in-batch predecessor
     t_found, t_v = kv_lookup_lanes(kv, s_khi, s_klo, s_valid & ~has_prev)
 
+    sec("px.kv.output")
     eff_present = jnp.where(has_prev, prev_present, t_found)
     eff_v = jnp.where(has_prev[:, None],
                       jnp.where(prev_present[:, None], prev_v, 0), t_v)
@@ -299,6 +311,7 @@ def kv_apply_batch_lanes(kv: KVState, op, k_hi, k_lo, v, valid):
     out = jnp.zeros_like(v).at[order].set(out_s)
     found = jnp.zeros(b, bool).at[order].set(found_s)
 
+    sec("px.kv.insert")
     # final writer per key = max write position in segment
     seg_max_w = segmented_scan_max(wpos, seg_start)
     # propagate the segment total (value at last row of segment) backwards:

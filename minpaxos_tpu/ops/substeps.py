@@ -148,13 +148,15 @@ def scan_ticks(cfg, state, inbox, step_impl, k: int):
     exec_mats [k, 6, E], scals [k, N_SCAL]))."""
     if k == 1:
         state, outbox, execr = step_impl(cfg, state, inbox)
-        o, e, s = pack_outputs(state, outbox, execr)
-        return state, (o[None], e[None], s[None])
+        with jax.named_scope("px.pack"):
+            o, e, s = pack_outputs(state, outbox, execr)
+            return state, (o[None], e[None], s[None])
 
     def body(st, x):
         box, inc = x
         st, outbox, execr = step_impl(cfg, st, box, inc)
-        return st, pack_outputs(st, outbox, execr)
+        with jax.named_scope("px.pack"):
+            return st, pack_outputs(st, outbox, execr)
 
     boxes = jax.tree_util.tree_map(
         lambda col: jnp.concatenate(

@@ -39,7 +39,13 @@ from minpaxos_tpu.models.minpaxos import (
     init_replica,
     replica_step_impl,
 )
-from minpaxos_tpu.obs.recorder import N_TEL_FIELDS, telemetry_valid_rows
+from minpaxos_tpu.obs.recorder import (
+    N_TEL_FIELDS,
+    PH_POD_DISPATCH,
+    PH_POD_READBACK,
+    phase,
+    telemetry_valid_rows,
+)
 from minpaxos_tpu.ops.telemetry import telemetry_row
 from minpaxos_tpu.ops.workload import (
     assemble_batch,
@@ -289,8 +295,9 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     ts = jnp.arange(k_rounds, dtype=jnp.int32)
     tel_on = telemetry.shape[0] > 0  # trace-time: off = PR-8 dispatch
     # all k rounds' PRNG lanes, hoisted out of the scan (see sharded_run)
-    keys, vals = workload_lanes(n_shards, ext_rows, round0 + ts, seed,
-                                key_space)
+    with jax.named_scope("px.workload"):
+        keys, vals = workload_lanes(n_shards, ext_rows, round0 + ts, seed,
+                                    key_space)
     # steady/election flag source: MinPaxos-family states carry
     # ``prepared`` [G, R]; Mencius has no elections (rotating
     # ownership), so every round is steady. Structural, trace-time.
@@ -303,21 +310,24 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         u_prev = ss.states.committed_upto[:, cursor_rep]
         c_prev = ss.states.crt_inst[:, cursor_rep]
         if tel_on:
-            e_prev = ss.states.executed_upto[:, cursor_rep]
-            # routed peer rows awaiting delivery = this round's inbox;
-            # the max per-(shard, replica) DELIVERED rows (routed +
-            # injected — injection has a closed form, see `injected`
-            # below) is the occupancy one inbox must hold: its run
-            # high-water mark feeds adaptive capacity selection
-            # (TEL_INBOX_HWM -> shape_ladder's inbox axis, PR 11)
-            pending_live = (ss.pending.kind != 0).sum(axis=-1)
-            inbox_rows = pending_live.sum()
-            ext_live = jnp.where(
-                (jnp.arange(cfg.n_replicas) == leader) | (leader < 0),
-                n_proposals, 0)
-            inbox_hwm = (pending_live + ext_live[None, :]).max()
-        ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
-                             n_proposals, leader, r, key_t, val_t)
+            with jax.named_scope("px.telemetry"):
+                e_prev = ss.states.executed_upto[:, cursor_rep]
+                # routed peer rows awaiting delivery = this round's
+                # inbox; the max per-(shard, replica) DELIVERED rows
+                # (routed + injected — injection has a closed form, see
+                # `injected` below) is the occupancy one inbox must
+                # hold: its run high-water mark feeds adaptive capacity
+                # selection (TEL_INBOX_HWM -> shape_ladder's inbox
+                # axis, PR 11)
+                pending_live = (ss.pending.kind != 0).sum(axis=-1)
+                inbox_rows = pending_live.sum()
+                ext_live = jnp.where(
+                    (jnp.arange(cfg.n_replicas) == leader) | (leader < 0),
+                    n_proposals, 0)
+                inbox_hwm = (pending_live + ext_live[None, :]).max()
+        with jax.named_scope("px.workload"):
+            ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
+                                 n_proposals, leader, r, key_t, val_t)
         ss, _, _, _ = jax.vmap(cstep)(ss, ext)
         # zero-WIDTH drain sub-steps (see sharded_run): smaller static
         # kernel shape, identical commit stream
@@ -332,42 +342,48 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 inbox_rows = inbox_rows + drain_live.sum()
                 inbox_hwm = jnp.maximum(inbox_hwm, drain_live.max())
             ss, _, _, _ = jax.vmap(cstep)(ss, ext0)
-        u_new = ss.states.committed_upto[:, cursor_rep]
-        c_new = ss.states.crt_inst[:, cursor_rep]
-        # stamp this round on slots assigned this round: [c_prev, c_new)
-        cp = c_prev[:, None]
-        slot = cp + jnp.mod(pos - cp, w)  # abs slot at each ring position
-        inj = jnp.where(slot < c_new[:, None], r, inj)
-        # commit latencies for slots committed this round: [u_prev+1, u_new]
-        up = u_prev[:, None] + 1
-        cslot = up + jnp.mod(pos - up, w)
-        sampled = (cslot <= u_new[:, None]) & (inj >= 0)
-        bins = jnp.clip(r - inj, 0, hist.shape[0] - 1)  # latency-1 rounds
-        hist = hist.at[bins.reshape(-1)].add(
-            sampled.reshape(-1).astype(hist.dtype))
+        with jax.named_scope("px.lat_hist"):
+            u_new = ss.states.committed_upto[:, cursor_rep]
+            c_new = ss.states.crt_inst[:, cursor_rep]
+            # stamp this round on slots assigned this round:
+            # [c_prev, c_new)
+            cp = c_prev[:, None]
+            slot = cp + jnp.mod(pos - cp, w)  # abs slot per ring position
+            inj = jnp.where(slot < c_new[:, None], r, inj)
+            # commit latencies for slots committed this round:
+            # [u_prev+1, u_new]
+            up = u_prev[:, None] + 1
+            cslot = up + jnp.mod(pos - up, w)
+            sampled = (cslot <= u_new[:, None]) & (inj >= 0)
+            bins = jnp.clip(r - inj, 0, hist.shape[0] - 1)  # latency-1
+            hist = hist.at[bins.reshape(-1)].add(
+                sampled.reshape(-1).astype(hist.dtype))
         if tel_on:
-            prep = (ss.states.prepared[:, cursor_rep].sum(dtype=jnp.int32)
-                    if has_prepared else jnp.int32(n_shards))
-            # injected rows have a closed form (assemble_batch masks
-            # col < n_proposals, times G shards, times every owner in
-            # mencius mode) — cheaper than reducing ext.kind [G, R, M]
-            # on XLA-CPU, where per-op thunk cost is what the 2%
-            # obs_smoke overhead gate feels
-            injected = (n_shards * n_proposals
-                        * jnp.where(leader >= 0, 1, cfg.n_replicas))
-            row = telemetry_row(
-                round_idx=r,
-                committed_delta=(u_new - u_prev).sum(),
-                in_flight=(c_new - 1 - u_new).sum(),
-                assigned=(c_new - c_prev).sum(),
-                injected_rows=injected,
-                inbox_rows=inbox_rows,
-                claim_rows=(ss.states.executed_upto[:, cursor_rep]
-                            - e_prev).sum(),
-                prepared_shards=prep,
-                inbox_hwm=inbox_hwm)
-            tel = jax.lax.dynamic_update_index_in_dim(
-                tel, row, jnp.mod(r - tel_base, telemetry.shape[0]), 0)
+            with jax.named_scope("px.telemetry"):
+                prep = (ss.states.prepared[:, cursor_rep].sum(
+                            dtype=jnp.int32)
+                        if has_prepared else jnp.int32(n_shards))
+                # injected rows have a closed form (assemble_batch
+                # masks col < n_proposals, times G shards, times every
+                # owner in mencius mode) — cheaper than reducing
+                # ext.kind [G, R, M] on XLA-CPU, where per-op thunk
+                # cost is what the 2% obs_smoke overhead gate feels
+                injected = (n_shards * n_proposals
+                            * jnp.where(leader >= 0, 1, cfg.n_replicas))
+                row = telemetry_row(
+                    round_idx=r,
+                    committed_delta=(u_new - u_prev).sum(),
+                    in_flight=(c_new - 1 - u_new).sum(),
+                    assigned=(c_new - c_prev).sum(),
+                    injected_rows=injected,
+                    inbox_rows=inbox_rows,
+                    claim_rows=(ss.states.executed_upto[:, cursor_rep]
+                                - e_prev).sum(),
+                    prepared_shards=prep,
+                    inbox_hwm=inbox_hwm)
+                tel = jax.lax.dynamic_update_index_in_dim(
+                    tel, row,
+                    jnp.mod(r - tel_base, telemetry.shape[0]), 0)
         return (ss, inj, hist, tel), None
 
     (ss, inject_round, lat_hist, telemetry), _ = jax.lax.scan(
@@ -526,20 +542,22 @@ class ShardedCluster:
         scalar readbacks (progress cursor + drain check). Everything
         else (state, inject ring, latency histogram, telemetry ring)
         stays on device in donated buffers until ``end_resident``."""
-        (self.ss, self._inject_round, self._lat_hist, self._telemetry,
-         committed, in_flight) = sharded_run_resident(
-            self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
-            self._inject_round, self._lat_hist, self._telemetry,
-            jnp.int32(min(n_proposals, self.ext_rows)),
-            jnp.int32(self.leader), jnp.int32(self._seed),
-            jnp.int32(self.seed), self._step_impl, self.key_space, substeps,
-            jnp.int32(self._tel_base))
+        with phase(PH_POD_DISPATCH):
+            (self.ss, self._inject_round, self._lat_hist, self._telemetry,
+             committed, in_flight) = sharded_run_resident(
+                self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
+                self._inject_round, self._lat_hist, self._telemetry,
+                jnp.int32(min(n_proposals, self.ext_rows)),
+                jnp.int32(self.leader), jnp.int32(self._seed),
+                jnp.int32(self.seed), self._step_impl, self.key_space,
+                substeps, jnp.int32(self._tel_base))
         self._seed += k_rounds
         # the per-dispatch scalar readback — the ONLY host sync in the
         # measured steady state (paxlint's resident-loop rule keeps it
         # that way; this suppression marks the sanctioned boundary)
-        # paxlint: disable=resident-loop -- sanctioned scalar readback
-        return int(committed), int(in_flight)
+        with phase(PH_POD_READBACK):
+            # paxlint: disable=resident-loop -- sanctioned scalar readback
+            return int(committed), int(in_flight)
 
     def resident_hist(self) -> np.ndarray:
         """Snapshot the device histogram WITHOUT disarming — the

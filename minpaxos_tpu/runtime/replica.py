@@ -46,12 +46,23 @@ from minpaxos_tpu.models.minpaxos import (
     replica_step_impl,
 )
 from minpaxos_tpu.obs.metrics import MetricsRegistry, TICK_MS_BUCKETS
+from minpaxos_tpu.obs import register_replica
 from minpaxos_tpu.obs.recorder import (
     KIND_FULL,
     KIND_FUSED,
     KIND_IDLE_SKIP,
     KIND_NARROW,
+    PH_DRAIN,
+    PH_EGRESS,
+    PH_ENQUEUE,
+    PH_FSYNC,
+    PH_PERSIST,
+    PH_READBACK,
+    PH_REPLY,
+    PH_WAIT,
     FlightRecorder,
+    PhaseClock,
+    phase,
 )
 from minpaxos_tpu.obs.trace import (
     ST_COMMIT,
@@ -60,6 +71,7 @@ from minpaxos_tpu.obs.trace import (
     ST_ORIGIN,
     ST_REPLY_SER,
     TraceSink,
+    protocol_ring_capacity,
     trace_id_for,
 )
 from minpaxos_tpu.obs.watch import (
@@ -122,9 +134,9 @@ def _packed_step(cfg, state, inbox, step_impl, k=1, narrow=0, off=0):
     """k protocol substeps + device-side packing of everything the
     host reads per dispatch into THREE stacked arrays: the per-tick
     host cost used to be ~30 per-column/per-scalar ``np.asarray``
-    device reads (~1 s of the leader's CPU over a 50k-op run,
-    tools/profile_tcp_leader.py); one [k, 14, M] outbox stack, one
-    [k, 6, E] exec stack and one [k, N_SCAL] scalar matrix make it
+    device reads (~1 s of the leader's CPU over a 50k-op run); one
+    [k, 14, M] outbox stack, one [k, 6, E] exec stack and one
+    [k, N_SCAL] scalar matrix make it
     three transfers for ALL k substeps (ops/substeps.py). Module-level
     jit: every replica in the process shares one compile cache (see
     ReplicaServer.step note).
@@ -188,6 +200,7 @@ class _InflightTick:
     rows_out: int
     peer_commits: np.ndarray | None  # state's [R] vector (non-mencius)
     snap: dict            # the snapshot published at this readback
+    wait_us: int
     drain_us: int
     enqueue_us: int
     readback_us: int
@@ -426,6 +439,10 @@ class ReplicaServer:
             "host phases does not appear here)", TICK_MS_BUCKETS)
         self.recorder = (FlightRecorder(self.flags.recorder_ring)
                          if self.flags.recorder else None)
+        # the tick loop's phase clock (obs/recorder.py phase): every
+        # interval the loop measures goes through it, into the row and,
+        # under a profile, into the xplane as a paxos.tick.* span
+        self._clock = PhaseClock(me)
         # paxtrace sink: one per replica, shared with the transport's
         # reader threads (each thread gets its own ring inside). The
         # sink exists even when disabled so every touch point stays
@@ -435,6 +452,8 @@ class ReplicaServer:
                                     ring_capacity=self.flags.trace_ring)
         m.fn_gauge("trace_spans", self.trace_sink.spans_total)
         m.fn_gauge("trace_dropped", self.trace_sink.spans_dropped)
+        # readable by a harness in this process, also after stop()
+        register_replica(me, m, self.recorder, self.trace_sink)
         # paxwatch journal: one per replica, shared with the
         # transport's reader threads (each writer thread gets its own
         # ring inside) — the journal exists even when disabled so
@@ -452,8 +471,6 @@ class ReplicaServer:
         # window; heap so the per-dispatch pop is O(covered), never a
         # scan of everything still above the frontier)
         self._trace_slots: list[tuple[int, int]] = []
-        self._drain_wait_s = 0.0  # blocking queue wait (idle pacing)
-        self._drain_work_s = 0.0  # frame-decode/dedup work in _drain
         self._last_scals = None  # newest published scalar vector
         # ingress admission state — written by the protocol thread
         # (_update_burn), read lock-free by the coalescer's gate on
@@ -503,7 +520,8 @@ class ReplicaServer:
             lambda x: x.copy(), init_fn(self.cfg, me))
         self.store = StableStore(
             f"{self.flags.store_dir}/stable-store-replica{me}",
-            sync=self.flags.durable)
+            sync=self.flags.durable, metrics=m, clock=self._clock)
+        self._flushed_seen = 0  # store_flushed_bytes at the last row
         # CRC-rejected log records (stable.py replay): nonzero after a
         # recovery that skipped flipped-byte records — the holes self-
         # heal via peers, but the operator must see the disk went bad
@@ -757,7 +775,7 @@ class ReplicaServer:
                                 value=self.store.corrupt_records)
         # EV_RECOVERY: the replica rebuilt serving state from durable
         # artifacts — value = the recovered frontier, aux = recovery
-        # wall ms (trend.py's recovery-cost row reads this)
+        # wall ms
         self.journal.record(
             EV_RECOVERY, subject=self.me, value=frontier,
             aux=int((time.perf_counter() - t_rec0) * 1e3))
@@ -1043,6 +1061,11 @@ class ReplicaServer:
         if prof is not None:
             prof.enable()
         try:
+            # this thread stamps four of a command's stages: its ring
+            # is sized to hold a whole benchmark window of them
+            self.trace_sink.ring(protocol_ring_capacity(
+                self.cfg.window, self.flags.trace_pow2,
+                self.flags.trace_ring))
             if self.flags.warm_variants:
                 self._warm_step_variants()
             if (not self._recovered and self.me == 0
@@ -1162,12 +1185,7 @@ class ReplicaServer:
         # (paxlint wall-honesty — a k-advance here would age the tick
         # counter k times faster than wall time)
         tick_inc = 1
-        t0 = time.perf_counter()
         elect = self._drain(timeout)
-        # drain WORK (decode/dedup/registration), with the blocking
-        # queue wait subtracted — idle pacing is not drain cost
-        self._drain_work_s = (time.perf_counter() - t0
-                              - self._drain_wait_s)
         self._update_burn(time.monotonic())
         if (self._boot_pending is not None
                 and time.monotonic() >= self._boot_pending):
@@ -1203,13 +1221,16 @@ class ReplicaServer:
             self._c_idle_skips.inc()
             self._c_ticks.inc(tick_inc)
             if self.recorder is not None:
+                clock = self._clock
                 self.recorder.record(
                     monotonic_ns(), KIND_IDLE_SKIP, 0, 0, 0,
                     self.snapshot["frontier"], 0,
-                    int(self._drain_work_s * 1e6), 0, 0, 0, 0, 0, 0,
+                    clock.take_us(PH_DRAIN), 0, 0, 0, 0, 0, 0,
                     chaos_faults=self.transport.chaos_faults_total(),
                     coal_wake=(self.coalescer._c_wakeups.value
-                               if self.coalescer is not None else 0))
+                               if self.coalescer is not None else 0),
+                    wait_us=clock.take_us(PH_WAIT),
+                    cpu_us=clock.cpu_us())
             # skipping IS being idle: without this the next poll waits
             # only tick_s (2 ms) and a quiet replica spins the skip
             # check at 500 Hz instead of idle_s pacing
@@ -1285,7 +1306,10 @@ class ReplicaServer:
         time_due = (fl.snap_interval_s > 0
                     and now - self._snap_last_s >= fl.snap_interval_s)
         if size_due or time_due:
-            self._take_snapshot(exec_upto)
+            # checkpointing is persistence work: a snapshot's pause
+            # reads as persist (and its fsyncs as fsync), not as wait
+            with phase(PH_PERSIST, self._clock):
+                self._take_snapshot(exec_upto)
 
     def _take_snapshot(self, exec_upto: int) -> None:
         """Checkpoint the applied KV state at ``exec_upto`` into the
@@ -1327,14 +1351,21 @@ class ReplicaServer:
     def _drain(self, timeout_s: float) -> bool:
         """Pull queued frames into the inbox buffer; returns whether a
         be_the_leader control event arrived."""
-        elect = False
-        t0 = time.perf_counter()
         try:
-            item = self.queue.get(timeout=timeout_s)
+            # the blocking wait is its own phase: idle pacing is not
+            # drain cost, but it is where a loaded tick's wall can go
+            with phase(PH_WAIT, self._clock):
+                item = self.queue.get(timeout=timeout_s)
         except queue.Empty:
-            self._drain_wait_s = time.perf_counter() - t0
             return False
-        self._drain_wait_s = time.perf_counter() - t0
+        with phase(PH_DRAIN, self._clock):
+            return self._drain_frames(item)
+
+    def _drain_frames(self, item) -> bool:
+        """The work of a drain — decode, dedup, registration — from the
+        first dequeued item until the queue or the inbox's room runs
+        out."""
+        elect = False
         while True:
             src_kind, conn_id, kind, rows = item
             if src_kind == CONTROL:
@@ -1696,28 +1727,50 @@ class ReplicaServer:
         exactly (-nopipeline forces that always)."""
         if DLOG and buf.fill:
             dlog(f"replica {self.me}: tick start fill={buf.fill}")
-        t0 = time.perf_counter()
-        cols, n_rows = buf.drain()
-        inbox = MsgBatch(**{c: np.asarray(cols[c]) for c in batches.COLS})
-        k = self._choose_fuse(n_rows)
-        narrow, off = self._choose_narrow(cols, n_rows)
-        view_lo = self.snapshot.get("window_base", 0) + off
-        # enqueue: on an async backend the call returns with the
-        # outputs still in flight; everything until the np.asarray
-        # below overlaps device compute
-        self.state, out_mats_d, exec_mats_d, scals_d = self.step(
-            self.state, inbox, k, narrow, off)
-        t_enq = time.perf_counter()
+        clock = self._clock
+        with phase(PH_ENQUEUE, clock):
+            cols, n_rows = buf.drain()
+            inbox = MsgBatch(**{c: np.asarray(cols[c])
+                                for c in batches.COLS})
+            k = self._choose_fuse(n_rows)
+            narrow, off = self._choose_narrow(cols, n_rows)
+            view_lo = self.snapshot.get("window_base", 0) + off
+            # enqueue: on an async backend the call returns with the
+            # outputs still in flight; everything until the np.asarray
+            # below overlaps device compute
+            self.state, out_mats_d, exec_mats_d, scals_d = self.step(
+                self.state, inbox, k, narrow, off)
         # the previous tick's host phases, hidden under this compute
         self._flush_inflight(overlapped=True)
-        t_host = time.perf_counter()
+        with phase(PH_READBACK, clock):
+            self._inflight = rec = self._read_back(
+                cols, n_rows, k, narrow, view_lo,
+                (out_mats_d, exec_mats_d, scals_d), persist, dispatch)
+        rec.enqueue_us = clock.take_us(PH_ENQUEUE)
+        rec.readback_us = clock.take_us(PH_READBACK)
+        # defer only when the next dispatch is imminent (traffic
+        # already queued): its enqueue is what the host phases hide
+        # under. With an empty queue the next wakeup may be a full
+        # idle interval away — a serial op's reply must not wait for
+        # it, so complete in place (this IS the pre-pipeline order).
+        if not (self.flags.pipeline and persist and dispatch
+                and not self.queue.empty()):
+            self._flush_inflight()
+
+    def _read_back(self, cols: dict, n_rows: int, k: int, narrow: int,
+                   view_lo: int, outs: tuple, persist: bool,
+                   dispatch: bool) -> _InflightTick:
+        """The readback of one dispatch and the publication of what it
+        taught — snapshot, counters, leader change, narrow-anchor
+        validation, commit stamps — as the tick's host-phase record.
+        One ``paxos.tick.readback`` span: the three blocking reads at
+        its start are where the host waits for the device."""
         # THREE device reads per dispatch, covering ALL k substeps
         # (stacked outbox/exec/scalar matrices) — see _packed_step;
         # np.asarray blocks until the device finishes: the readback
-        out_mats = np.asarray(out_mats_d)
-        exec_mats = np.asarray(exec_mats_d)
-        scals = np.asarray(scals_d)
-        t_rb = time.perf_counter()
+        out_mats = np.asarray(outs[0])
+        exec_mats = np.asarray(outs[1])
+        scals = np.asarray(outs[2])
         t_rb_ns = monotonic_ns()  # trace anchor for the dispatch phases
         self._c_dispatches.inc()
         self._c_fused_substeps.inc(k)
@@ -1732,8 +1785,7 @@ class ReplicaServer:
         self._last_dispatch = time.monotonic()
         self._check_kv_load()
         if DLOG and n_rows:
-            dlog(f"replica {self.me}: enqueue+readback k={k} "
-                 f"narrow={narrow} {(t_rb - t0) * 1e3:.2f}ms")
+            dlog(f"replica {self.me}: readback k={k} narrow={narrow}")
         mencius = self.protocol == "mencius"
         last = scals[-1]
         self._last_scals = last  # STATS verb surfaces the full vector
@@ -1821,7 +1873,6 @@ class ReplicaServer:
             self.journal.record(EV_FATAL, subject=self.me,
                                 value=dropped)
             raise FatalReplicaError(self.fatal)
-        drain_s, self._drain_work_s = self._drain_work_s, 0.0
         # coalescer telemetry for the recorder row (schema v7): the
         # rows the ingress front batched into this tick's drain, and
         # the cumulative wakeup kicks. A chased dispatch (overlap_exec)
@@ -1839,19 +1890,13 @@ class ReplicaServer:
             persist=persist, dispatch=dispatch, frontier=frontier_last,
             backlog=frontier_last - int(last[SCAL_EXECUTED]),
             rows_out=rows_out, peer_commits=pc, snap=self.snapshot,
-            drain_us=int(drain_s * 1e6),
-            enqueue_us=int((t_enq - t0) * 1e6),
-            readback_us=int((t_rb - t_host) * 1e6),
+            wait_us=self._clock.take_us(PH_WAIT),
+            drain_us=self._clock.take_us(PH_DRAIN),
+            enqueue_us=0, readback_us=0,  # the caller's, once this ends
             t_rb_ns=t_rb_ns, coal_occ=coal_occ, coal_wake=coal_wake)
-        self._inflight = rec
-        # defer only when the next dispatch is imminent (traffic
-        # already queued): its enqueue is what the host phases hide
-        # under. With an empty queue the next wakeup may be a full
-        # idle interval away — a serial op's reply must not wait for
-        # it, so complete in place (this IS the pre-pipeline order).
-        if not (self.flags.pipeline and persist and dispatch
-                and not self.queue.empty()):
-            self._flush_inflight()
+        if self.trace_sink.enabled:
+            self._trace_commits(rec)
+        return rec
 
     def _flush_inflight(self, overlapped: bool = False) -> None:
         """Complete the deferred tick's host phases, if any.
@@ -1870,77 +1915,82 @@ class ReplicaServer:
         contract preserved: the store flush (fsync under -durable)
         happens before any buffered reply frame reaches a socket
         (flush_all is last)."""
-        t_f0 = time.perf_counter()
+        clock = self._clock
         cols, n_rows, k = rec.cols, rec.n_rows, rec.k
         out_mats, exec_mats, scals = rec.out_mats, rec.exec_mats, rec.scals
         ncols = len(batches.COLS)
-        if self.trace_sink.enabled:
-            self._trace_commits(rec)
-        persist_s = dispatch_s = reply_s = 0.0
         if rec.persist:
             # always maintained (in-memory mirror feeds beyond-window
             # catch-up); -durable additionally fsyncs before replies
-            tp = time.perf_counter()
-            out0 = {c: out_mats[0][j] for j, c in enumerate(batches.COLS)}
-            acked0 = out_mats[0][ncols + 1].astype(bool)
-            wrote = self._persist(cols, n_rows, out0, acked0,
-                                  int(scals[0][SCAL_FRONTIER]))
-            if k > 1:
-                # substeps 1..k-1 ran empty inboxes, so every
-                # persistable row of theirs is an outbox tail row
-                # (retry/noop/catch-up ACCEPTs + mencius SKIPs): one
-                # concatenated pass over all of them at once,
-                # substep-major order preserved by the reshape
-                big = {c: out_mats[1:, j, :].reshape(-1)
-                       for j, c in enumerate(batches.COLS)}
-                wrote |= self._persist(cols, 0, big,
-                                       np.zeros(0, bool), rec.frontier)
-            if wrote:
-                # ONE store flush (fsync under -durable) covers all k
-                # substeps: outbound frames only hit the sockets at
-                # flush_all below (FrameWriter buffers, wire/codec.py),
-                # so the fsync-before-acks-leave ordering holds without
-                # paying k fsyncs per fused dispatch
-                self.store.flush()
-            persist_s = time.perf_counter() - tp
-        if rec.dispatch:
-            td = time.perf_counter()
-            if rec.rows_out:
-                # the reshapes COPY (strided slices), so build them
-                # only when there are live rows to scatter — backlog-
-                # drain ticks execute commands without emitting any
-                flat = {c: out_mats[:, j, :].reshape(-1)
+            with phase(PH_PERSIST, clock):
+                out0 = {c: out_mats[0][j]
                         for j, c in enumerate(batches.COLS)}
-                self._dispatch(flat, out_mats[:, ncols, :].reshape(-1))
-            tr = time.perf_counter()
-            self._reply_stacked(exec_mats, scals, k, rec.frontier)
-            t_cu = time.perf_counter()
-            self._host_catchup(rec.peer_commits, rec.snap)
-            self.transport.flush_all()
-            t_de = time.perf_counter()
-            dispatch_s = (tr - td) + (t_de - t_cu)
-            reply_s = t_cu - tr
+                acked0 = out_mats[0][ncols + 1].astype(bool)
+                wrote = self._persist(cols, n_rows, out0, acked0,
+                                      int(scals[0][SCAL_FRONTIER]))
+                if k > 1:
+                    # substeps 1..k-1 ran empty inboxes, so every
+                    # persistable row of theirs is an outbox tail row
+                    # (retry/noop/catch-up ACCEPTs + mencius SKIPs):
+                    # one concatenated pass over all of them at once,
+                    # substep-major order preserved by the reshape
+                    big = {c: out_mats[1:, j, :].reshape(-1)
+                           for j, c in enumerate(batches.COLS)}
+                    wrote |= self._persist(cols, 0, big,
+                                           np.zeros(0, bool), rec.frontier)
+                if wrote:
+                    # ONE store flush (fsync under -durable, its own
+                    # paxos.tick.fsync span inside this one) covers
+                    # all k substeps: outbound frames only hit the
+                    # sockets at flush_all below (FrameWriter buffers,
+                    # wire/codec.py), so the fsync-before-acks-leave
+                    # ordering holds without paying k fsyncs per fused
+                    # dispatch
+                    self.store.flush()
+        if rec.dispatch:
+            with phase(PH_EGRESS, clock):
+                if rec.rows_out:
+                    # the reshapes COPY (strided slices), so build
+                    # them only when there are live rows to scatter —
+                    # backlog-drain ticks execute commands without
+                    # emitting any
+                    flat = {c: out_mats[:, j, :].reshape(-1)
+                            for j, c in enumerate(batches.COLS)}
+                    self._dispatch(flat,
+                                   out_mats[:, ncols, :].reshape(-1))
+            with phase(PH_REPLY, clock):
+                self._reply_stacked(exec_mats, scals, k, rec.frontier)
+            with phase(PH_EGRESS, clock):
+                self._host_catchup(rec.peer_commits, rec.snap)
+                self.transport.flush_all()
         # flight-recorder row + latency histograms: the per-phase wall
         # decomposition for THIS dispatch, wall-honest under fusion
         # (one row per dispatch, carrying k — a fused burst is one
         # wall tick; consumers divide by k for per-substep cost).
         # overlap_us = this tick's host-phase wall executed while the
         # NEXT dispatch was in flight on the device (0 when serial).
-        host_s = time.perf_counter() - t_f0
+        persist_us = clock.take_us(PH_PERSIST)
+        egress_us = clock.take_us(PH_EGRESS)
+        reply_us = clock.take_us(PH_REPLY)
+        host_us = persist_us + egress_us + reply_us
         if overlapped:
             self._c_pipelined.inc()
-        step_s = (rec.enqueue_us + rec.readback_us) / 1e6
-        self._h_tick.observe((rec.drain_us / 1e6 + step_s + host_s) * 1e3)
-        self._h_step.observe(step_s * 1e3)
+        step_us = rec.enqueue_us + rec.readback_us
+        self._h_tick.observe((rec.drain_us + step_us + host_us) / 1e3)
+        self._h_step.observe(step_us / 1e3)
         if self.recorder is not None:
+            flushed = self.store.flushed_bytes
+            fsync_bytes, self._flushed_seen = (
+                flushed - self._flushed_seen, flushed)
             self.recorder.record(
                 monotonic_ns(), rec.kind, k, n_rows, rec.rows_out,
                 rec.frontier, rec.backlog, rec.drain_us, rec.enqueue_us,
-                rec.readback_us, int(host_s * 1e6) if overlapped else 0,
-                int(persist_s * 1e6), int(dispatch_s * 1e6),
-                int(reply_s * 1e6), rec.t_rb_ns,
+                rec.readback_us, host_us if overlapped else 0,
+                persist_us, egress_us, reply_us, rec.t_rb_ns,
                 chaos_faults=self.transport.chaos_faults_total(),
-                coal_occ=rec.coal_occ, coal_wake=rec.coal_wake)
+                coal_occ=rec.coal_occ, coal_wake=rec.coal_wake,
+                wait_us=rec.wait_us, fsync_us=clock.take_us(PH_FSYNC),
+                fsync_bytes=fsync_bytes, cpu_us=clock.cpu_us())
 
     # -- paxtrace: slot assignment + commit stamps (protocol thread) --
 

@@ -29,6 +29,9 @@ import zlib
 
 import numpy as np
 
+from minpaxos_tpu.obs.metrics import MetricsRegistry
+from minpaxos_tpu.obs.recorder import PH_FSYNC, PhaseClock, phase
+
 #: v1 framing: [type u8][len u32][payload] — no integrity check; a
 #: flipped payload byte replayed as protocol state (silent divergence)
 MAGIC_V1 = b"MPXL0001"
@@ -82,11 +85,31 @@ class StableStore:
     [payload] (the crc field only under the v2 magic — see MAGIC_V1).
     ``sync=False`` trades durability for speed (the reference's
     non--durable mode skips persistence entirely).
+
+    ``metrics``: the owner's registry; the store counts its own
+    ``os.fsync`` calls, their time and the bytes they made durable
+    there, where the work happens. ``clock``: the owner's tick-loop
+    phase clock, so that an fsync is a ``paxos.tick.fsync`` span of
+    that replica and lands in its recorder row.
     """
 
-    def __init__(self, path: str, sync: bool = True):
+    def __init__(self, path: str, sync: bool = True,
+                 metrics: MetricsRegistry | None = None,
+                 clock: PhaseClock | None = None):
         self.path = path
         self.sync = sync
+        m = metrics if metrics is not None else MetricsRegistry()
+        self._c_fsyncs = m.counter(
+            "store_fsyncs", "os.fsync calls the stable store made (one "
+            "a durable flush; a snapshot adds its segment's and the "
+            "directory's)")
+        self._c_fsync_us = m.counter(
+            "store_fsync_us", "microseconds inside those calls")
+        self._c_flushed_bytes = m.counter(
+            "store_flushed_bytes", "log bytes appended and then made "
+            "durable by a flush's fsync (divide by committed)")
+        self._clock = clock
+        self._unsynced = 0  # bytes appended since the last durable flush
         # a stale .tmp is a segment swap that died before its
         # os.replace: the original file is still authoritative
         try:
@@ -241,7 +264,20 @@ class StableStore:
     def flush(self) -> None:
         self._f.flush()
         if self.sync:
-            os.fsync(self._f.fileno())
+            self._fsync(self._f.fileno())
+            self._c_flushed_bytes.inc(self._unsynced)
+            self._unsynced = 0
+
+    @property
+    def flushed_bytes(self) -> int:
+        """Cumulative log bytes a flush's fsync made durable."""
+        return self._c_flushed_bytes.value
+
+    def _fsync(self, fd: int) -> None:
+        with phase(PH_FSYNC, self._clock) as took:
+            os.fsync(fd)
+        self._c_fsyncs.inc()
+        self._c_fsync_us.inc(took.ns // 1000)
 
     def close(self) -> None:
         try:
@@ -348,15 +384,18 @@ class StableStore:
                 self._write_record_to(tf, REC_FRONTIER,
                                       _FRONTIER.pack(self.frontier))
             tf.flush()
-            os.fsync(tf.fileno())
+            self._fsync(tf.fileno())
         # the swap: old file stays authoritative until the replace
         # lands (a crash between fsync and replace leaves a stale .tmp
         # that __init__ discards)
         self._f.close()
         os.replace(tmp, self.path)
+        # what was appended but not yet synced went into the segment
+        self._c_flushed_bytes.inc(self._unsynced)
+        self._unsynced = 0
         try:
             dfd = os.open(os.path.dirname(self.path) or ".", os.O_RDONLY)
-            os.fsync(dfd)
+            self._fsync(dfd)
             os.close(dfd)
         except OSError:
             pass
@@ -383,6 +422,9 @@ class StableStore:
         if self.crc_framing:
             f.write(_CRC.pack(zlib.crc32(payload, zlib.crc32(hdr))))
         f.write(payload)
+        if f is self._f:
+            self._unsynced += len(hdr) + len(payload) + (
+                _CRC.size if self.crc_framing else 0)
 
     # -- read --
 

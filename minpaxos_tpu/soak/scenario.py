@@ -22,7 +22,7 @@ window — partition-under-load). The driver:
   the ground-truth fault/phase timeline, per-phase traced stage
   tables (the tools/tail.py math over client + cluster span
   collections), exactly-once totals, and a criteria stanza the
-  acceptance gate and ``tools/trend.py`` read directly.
+  acceptance gate reads directly.
 
 The JAX-heavy imports (ChaosCluster -> replica) happen inside
 ``run_scenario``; the manifest/scorecard helpers stay importable by

@@ -77,47 +77,6 @@ def test_latency_from_hist_empty_and_overflow():
     assert p50 == 4.0  # clipped AT the last bin, never dropped
 
 
-def test_trend_reads_committed_artifacts():
-    """tools/trend.py (report-only): the cross-PR trajectory view
-    parses every committed BENCH_r*.json driver capture — none is a
-    legal state (the records of the old access route were deleted, PR
-    21) — and renders a markdown table; nothing is silently skipped."""
-    import json as _json
-    import subprocess
-    import sys as _sys
-    from pathlib import Path
-
-    repo = Path(__file__).resolve().parents[1]
-    out = subprocess.run(
-        [_sys.executable, str(repo / "tools/trend.py"), "--json"],
-        capture_output=True, text=True, timeout=60)
-    assert out.returncode == 0, out.stderr
-    rows = _json.loads(out.stdout)["bench"]
-    names = {r["artifact"] for r in rows}
-    committed = {p.name for p in repo.glob("BENCH_r*.json")}
-    assert committed <= names  # nothing silently skipped
-    assert all("provenance" in r for r in rows)
-    out = subprocess.run(
-        [_sys.executable, str(repo / "tools/trend.py")],
-        capture_output=True, text=True, timeout=60)
-    assert out.returncode == 0
-    assert "| artifact |" in out.stdout
-
-
-def test_trend_with_zero_bench_captures(tmp_path):
-    """No BENCH_r*.json at all (and a capture with no parseable
-    record) must not break the report."""
-    import json as _json
-
-    from tools.trend import collect_bench_rows
-
-    assert collect_bench_rows(tmp_path) == []
-    (tmp_path / "BENCH_r09.json").write_text(
-        _json.dumps({"rc": 1, "tail": "Traceback ...", "parsed": None}))
-    (row,) = collect_bench_rows(tmp_path)
-    assert row["provenance"] == "unparseable" and "rc=1" in row["error"]
-
-
 def test_overflow_warning_is_loud_and_parser_safe():
     """A saturated histogram must warn on STDOUT (the artifact stamp
     alone was missable) without corrupting the one-JSON-line contract:

@@ -9,7 +9,8 @@ shape-ladder smoke has paid the backend init):
 1. **Recorder-overhead guard** — the observability layer is
    default-ON in the runtime, so its hot-path cost is a standing
    contract: one fully-instrumented tick body (counter advances +
-   two histogram observes + one flight-recorder ring write) is
+   two histogram observes + the eight tick-loop phases + one
+   thread-CPU read + one flight-recorder ring write, schema v8) is
    microbenchmarked against the same body with instrumentation off.
    The delta must stay in the noise next to the runtime's 300-900 us
    device-dispatch floor; the gate fails at 30 us/tick — an order of
@@ -54,8 +55,14 @@ sys.path.insert(0, str(REPO))
 from minpaxos_tpu.obs.metrics import MetricsRegistry  # noqa: E402
 from minpaxos_tpu.obs.recorder import (  # noqa: E402
     KIND_NAMES,
+    PH_DRAIN,
+    PH_FSYNC,
+    PH_WAIT,
+    PHASE_FIELDS,
     FlightRecorder,
+    PhaseClock,
     chrome_trace,
+    phase,
     validate_chrome_trace,
 )
 from minpaxos_tpu.obs.trace import (  # noqa: E402
@@ -133,6 +140,11 @@ def overhead_guard() -> bool:
         x = _tick_body(x)
     base_s = time.perf_counter() - t0
 
+    # what one wakeup of the runtime pays with no profile running:
+    # every phase of the tick loop entered and left once (each an
+    # inactive annotation check + two clock reads), the row's fields
+    # drained from the phase clock, one thread-CPU read
+    clock = PhaseClock(0)
     x = 1.0
     t0 = time.perf_counter()
     for i in range(N_ITERS):
@@ -141,7 +153,14 @@ def overhead_guard() -> bool:
         c_disp.inc()
         h_tick.observe(0.7)
         h_step.observe(0.4)
-        rec.record(i, i % 4, 1, 8, 8, i, 0, 5, 30, 270, 60, 20, 30, 10, i)
+        for name in PHASE_FIELDS:
+            with phase(name, clock):
+                pass
+        rec.record(i, i % 4, 1, 8, 8, i, 0, clock.take_us(PH_DRAIN), 30,
+                   270, 60, 20, 30, 10, i,
+                   wait_us=clock.take_us(PH_WAIT),
+                   fsync_us=clock.take_us(PH_FSYNC), fsync_bytes=70,
+                   cpu_us=clock.cpu_us())
     inst_s = time.perf_counter() - t0
 
     per_tick = (inst_s - base_s) / N_ITERS

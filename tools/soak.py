@@ -15,7 +15,7 @@ The scorecard joins, per phase: client-side acked/shed/retransmit
 counts and p50/p99/p999, the paxwatch detector raise->clear timeline
 classified against the ground-truth fault/phase timeline, per-phase
 traced stage tables (tools/tail.py math), and the admission gate's
-counters. ``tools/trend.py`` renders it as a markdown table.
+counters.
 
 Smoke pass criteria (the tier-1 wiring): every phase ran, EV_PHASE
 landed on every replica's journal, exactly-once held across shards
