@@ -316,8 +316,7 @@ CPU_SHAPE = (8, 512, 64, 8)
 
 
 def headline_config(on_tpu: bool, w: int, p: int, do_fault: bool = True,
-                    inbox: int = 0, compact: int = 0, q1: int = 0,
-                    q2: int = 0):
+                    inbox: int = 0, q1: int = 0, q2: int = 0):
     """(cfg, key_space) of the headline MinPaxos N=5 run at window
     ``w`` and ``p`` proposals per round — the ONE definition bench.py
     and chip_smoke.py share.
@@ -350,17 +349,16 @@ def headline_config(on_tpu: bool, w: int, p: int, do_fault: bool = True,
     the fault leg is OFF (ladder-chosen throughput shapes), cu drops
     to economy sizing instead.
 
-    ``inbox``/``compact`` (PR 11) and ``q1``/``q2`` (PR 16): a
-    --ladder winner may carry an occupancy-derived inbox capacity and
-    a non-default quorum pair; 0 = the default sizing / majority."""
+    ``inbox`` (PR 11) and ``q1``/``q2`` (PR 16): a --ladder winner
+    may carry an occupancy-derived inbox capacity and a non-default
+    quorum pair; 0 = the default sizing / majority."""
     from minpaxos_tpu.models.minpaxos import MinPaxosConfig
 
     cu_rows = max(512, 2 * p) if on_tpu else cpu_catchup_rows(p, do_fault)
     cfg = MinPaxosConfig(
         n_replicas=5, window=w, inbox=inbox or (p + 2 * cu_rows + 64 + 64),
         exec_batch=p, kv_pow2=16 if on_tpu else cpu_kv_pow2(p),
-        catchup_rows=cu_rows, recovery_rows=64,
-        compact_inbox=compact, q1=q1, q2=q2)
+        catchup_rows=cu_rows, recovery_rows=64, q1=q1, q2=q2)
     return cfg, (1 << 14) if on_tpu else cpu_key_space(p)
 
 
@@ -405,7 +403,6 @@ def measure(shape: tuple[int, int, int, int] | None = None,
     cfg, key_space = headline_config(
         on_tpu, w, p, do_fault,
         inbox=int(os.environ.get("MP_BENCH_INBOX", "0") or 0),
-        compact=int(os.environ.get("MP_BENCH_COMPACT", "0") or 0),
         q1=int(os.environ.get("MP_BENCH_Q1", "0") or 0),
         q2=int(os.environ.get("MP_BENCH_Q2", "0") or 0))
     cu_rows = cfg.catchup_rows
@@ -722,7 +719,6 @@ def measure(shape: tuple[int, int, int, int] | None = None,
         "shape": {"n_shards": g, "window": w, "proposals": p,
                   "rounds_per_dispatch": k, "catchup_rows": cu_rows,
                   "inbox": cfg.inbox,
-                  "compact_inbox": cfg.compact_inbox,
                   "route_fabric": cfg.route_fabric,
                   "shard_devices": shard_devices,
                   "ladder_chosen": ladder is not None},
@@ -874,10 +870,9 @@ def _run_ladder_mode() -> None:
                     MP_BENCH_LADDER_FILE=sweep_path,
                     MP_BENCH_SHARD_DEVICES=str(win["shard_devices"]),
                     # occupancy-adaptive capacity rides along: the
-                    # measured record must run the winner's inbox /
-                    # compaction, not re-derive the default sizing
+                    # measured record must run the winner's inbox,
+                    # not re-derive the default sizing
                     MP_BENCH_INBOX=str(win.get("inbox") or 0),
-                    MP_BENCH_COMPACT=str(win.get("compact_inbox") or 0),
                     # flexible quorums: a quorum-sweep winner carries
                     # its (q1, q2); the record re-runs the pair that
                     # won (resolved majority == explicit majority)

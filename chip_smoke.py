@@ -236,6 +236,8 @@ def phase_a(meter: _CompileMeter, on_tpu: bool, seed: int) -> dict:
     out["after_setup"] = meter.take()
 
     injected = sum(d["k"] * d["proposals"] for d in dispatches) * g
+    # which tier the rounds of all the legs above took (sharded_round)
+    out["tiers"] = sc.resident_tiers()
     hist = sc.end_resident()
     dropped = int(np.asarray(sc.ss.states.kv.dropped).sum())
     checks["victim_fell_behind"] = bool(

@@ -44,7 +44,7 @@ from minpaxos_tpu.models.minpaxos import (
     replica_step_impl,
 )
 from minpaxos_tpu.ops.packed import join_i64, split_i64
-from minpaxos_tpu.ops.segscatter import gather_rows, prefix_pack_plan, route_plan
+from minpaxos_tpu.ops.segscatter import gather_rows, plan_slots, route_counts
 from minpaxos_tpu.ops.winner import gather_row, slot_winner
 from minpaxos_tpu.wire.messages import MsgKind, Op
 
@@ -107,6 +107,36 @@ def _route(cfg: MinPaxosConfig, out_msgs: MsgBatch, dst: jnp.ndarray,
     return jax.vmap(inbox_for)(jnp.arange(r))
 
 
+def _pool_counts(out_msgs: MsgBatch, dst: jnp.ndarray,
+                 alive: jnp.ndarray) -> tuple[MsgBatch, jnp.ndarray]:
+    """First half of the segmented fabric: the replicas' outboxes
+    pooled to [R*M] rows, and the segment-prefix-sum over them
+    (ops/segscatter.py ``route_counts``: cnt[d, -1] is the number of
+    rows destination d is sent)."""
+    r, m = out_msgs.kind.shape
+    with jax.named_scope("px.route.plan"):
+        flat = jax.tree_util.tree_map(lambda x: x.reshape(-1), out_msgs)
+        src_rep = jnp.repeat(jnp.arange(r, dtype=jnp.int32), m)
+        return flat, route_counts(flat.kind, src_rep, dst.reshape(-1),
+                                  alive)
+
+
+def _fill_inboxes(flat: MsgBatch, cnt: jnp.ndarray, slots: int,
+                  capacity: int) -> MsgBatch:
+    """Second half: the winner of each of the first ``slots`` slots of
+    every inbox and the 12 gathers that fill them, zero-padded to
+    ``capacity`` rows. Filled slots are a prefix, so with every count
+    <= ``slots`` this is the ``capacity``-slot inbox byte for byte."""
+    with jax.named_scope("px.route.plan"):
+        win, hit = plan_slots(cnt, slots)
+    with jax.named_scope("px.route.gather"):
+        rows = gather_rows(flat, win, hit)
+        if slots < capacity:
+            rows = jax.tree_util.tree_map(
+                lambda x: jnp.pad(x, ((0, 0), (0, capacity - slots))), rows)
+        return rows
+
+
 def _route_segmented(cfg: MinPaxosConfig, out_msgs: MsgBatch,
                      dst: jnp.ndarray, alive: jnp.ndarray,
                      capacity: int) -> MsgBatch:
@@ -119,47 +149,48 @@ def _route_segmented(cfg: MinPaxosConfig, out_msgs: MsgBatch,
     (ops/segscatter.py rationale). Byte-identical to ``_route``
     including row order and overflow-drop semantics — pinned by
     tests/test_route_fabric.py and the golden kernel fixtures."""
-    r = cfg.n_replicas
-    m = out_msgs.kind.shape[1]
-    with jax.named_scope("px.route.plan"):
-        flat = jax.tree_util.tree_map(lambda x: x.reshape(-1),
-                                      out_msgs)  # [R*M]
-        src_rep = jnp.repeat(jnp.arange(r, dtype=jnp.int32), m)
-        win, hit = route_plan(flat.kind, src_rep, dst.reshape(-1), alive,
-                              capacity)
-    with jax.named_scope("px.route.gather"):
-        return gather_rows(flat, win, hit)
+    flat, cnt = _pool_counts(out_msgs, dst, alive)
+    return _fill_inboxes(flat, cnt, capacity, capacity)
 
 
-def _deliver_inbox(cfg: MinPaxosConfig, pending: MsgBatch, ext: MsgBatch,
-                   alive: jnp.ndarray) -> MsgBatch:
+def _deliver_inbox(pending: MsgBatch, ext: MsgBatch, alive: jnp.ndarray,
+                   rows: int | None = None) -> MsgBatch:
     """Merge routed pending rows + host-injected ext rows into the
     inbox the protocol kernel consumes; dead replicas see silence.
 
-    With ``cfg.compact_inbox`` > 0 the merged rows are COMPACTED: live
-    rows pack to a prefix (order preserved) of a ``compact_inbox``-row
-    buffer, so every [M]-shaped kernel computation runs at that
-    smaller static shape instead of inbox+ext_rows. Rows beyond the
-    compacted capacity drop (legal message loss) — capacity is sized
-    from the measured occupancy high-water mark (paxray
-    TEL_INBOX_HWM), and the shape ladder only crowns lossless points.
-    Compaction preserves the commit stream byte-for-byte (delivery
-    content/order are unchanged; only padding gaps vanish) but may
-    merge ack runs across removed gaps — protocol-equivalent, pinned
-    by tests/test_route_fabric.py."""
+    ``rows`` (static) delivers only the first ``rows`` pending slots:
+    routing packs each inbox to a prefix, so when no live row lies at
+    or beyond slot ``rows`` the cut drops padding alone and every
+    [M]-shaped kernel computation runs at rows + ext instead of
+    inbox + ext. The caller holds that condition (parallel/sharded.py's
+    two-tier round)."""
+    if rows is not None and rows < pending.kind.shape[-1]:
+        pending = jax.tree_util.tree_map(lambda x: x[..., :rows], pending)
     inbox = _concat_rows(pending, ext)
-    inbox = inbox._replace(
+    return inbox._replace(
         kind=jnp.where(alive[:, None], inbox.kind, 0))
-    cap = cfg.compact_inbox
-    if cap and inbox.kind.shape[-1] > cap:
-        live = inbox.kind != 0
-        win, hit = jax.vmap(
-            functools.partial(prefix_pack_plan, capacity=cap))(live)
-        winc = jnp.where(hit, win, 0)
-        inbox = jax.tree_util.tree_map(
-            lambda col: jnp.where(
-                hit, jnp.take_along_axis(col, winc, axis=-1), 0), inbox)
-    return inbox
+
+
+def step_replicas(cfg: MinPaxosConfig, cs: ClusterState, ext: MsgBatch,
+                  step_impl=replica_step_impl, rows: int | None = None):
+    """Deliver (the first ``rows`` slots of) pending + ext and step all
+    replicas of one group: (states', outbox, exec results). ``cfg``
+    comes with ``gate_exec`` already off (see ``cluster_step_impl``)."""
+    with jax.named_scope("px.deliver"):
+        inbox = _deliver_inbox(cs.pending, ext, cs.alive, rows)
+    return jax.vmap(functools.partial(step_impl, cfg))(cs.states, inbox)
+
+
+def route_outbox(cfg: MinPaxosConfig, outbox, alive: jnp.ndarray) -> MsgBatch:
+    """One group's next pending inboxes from its replicas' outboxes, at
+    the configured capacity, through the fabric ``cfg`` selects."""
+    route = _route if cfg.route_fabric == "dense" else _route_segmented
+    return route(cfg, outbox.msgs, outbox.dst, alive, cfg.inbox)
+
+
+def client_rows_of(outbox) -> tuple[MsgBatch, jnp.ndarray]:
+    """Client-bound rows of an outbox: (rows [R, M_total], mask)."""
+    return outbox.msgs, (outbox.dst == -2) & (outbox.msgs.kind != 0)
 
 
 def cluster_step_impl(
@@ -183,14 +214,9 @@ def cluster_step_impl(
     # strip the gate at this choke point so callers don't each have to
     # remember to pass gate_exec=False
     cfg = cfg._replace(gate_exec=False)
-    with jax.named_scope("px.deliver"):
-        inbox = _deliver_inbox(cfg, cs.pending, ext, cs.alive)
-    states, outbox, execr = jax.vmap(
-        functools.partial(step_impl, cfg))(cs.states, inbox)
-    route = _route if cfg.route_fabric == "dense" else _route_segmented
-    pending = route(cfg, outbox.msgs, outbox.dst, cs.alive, cfg.inbox)
-    client_rows = outbox.msgs
-    client_mask = (outbox.dst == -2) & (outbox.msgs.kind != 0)
+    states, outbox, execr = step_replicas(cfg, cs, ext, step_impl)
+    pending = route_outbox(cfg, outbox, cs.alive)
+    client_rows, client_mask = client_rows_of(outbox)
     return ClusterState(states, pending, cs.alive), execr, client_rows, client_mask
 
 

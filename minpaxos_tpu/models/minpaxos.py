@@ -114,15 +114,6 @@ class MinPaxosConfig(NamedTuple):
     # byte-identical inboxes (tests/test_route_fabric.py); segmented
     # measures 2.5-3.5x faster at bench capacities on the CPU host.
     route_fabric: str = "segmented"
-    # Inbox compaction (static, 0 = off): deliver the merged
-    # pending+ext inbox COMPACTED to this many rows — live rows pack to
-    # a prefix (order preserved, ops/segscatter.py prefix_pack_plan)
-    # and every [M]-shaped kernel computation runs at this smaller
-    # static shape instead of inbox+ext_rows. Overflow beyond the
-    # compacted capacity drops (legal message loss) — size it from the
-    # measured occupancy high-water mark (paxray TEL_INBOX_HWM; the
-    # shape ladder sweeps this axis and requires lossless points).
-    compact_inbox: int = 0
     # Protocol selector: False = MinPaxos (global ballot, commits learned
     # from the LastCommitted piggyback on Accepts — bareminpaxos.go hot
     # path, SURVEY.md 3.2); True = classic per-instance Multi-Paxos

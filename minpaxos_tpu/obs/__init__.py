@@ -29,7 +29,9 @@ Consumers:
 * ``bench.py`` / ``bench_tcp.py`` — embed end-of-run snapshots in
   their artifacts.
 * a harness that holds the servers in its own process (the
-  benchmark's ``served`` runner) — ``process_collection()`` below.
+  benchmark's ``served`` runner) — ``process_collection()`` below;
+  one that holds a sharded pod (its ``pod`` runner) —
+  ``process_pods()``.
 
 See OBSERVABILITY.md at the repo root for the metric catalogue and
 the trace field glossary.
@@ -127,9 +129,35 @@ def process_collection() -> list[dict]:
     return out
 
 
+#: the newest sharded pods of this process (parallel/sharded.py
+#: ``ShardedCluster``), each the dict it registered at construction:
+#: its shape and, under ``tiers``, the resident loop's tier counts as of
+#: the pod's last post-window read (None before one). Apart from
+#: ``_PROCESS_REPLICAS``, whose entries are replicas with recorder rows.
+_PROCESS_PODS: collections.deque = collections.deque(maxlen=16)
+
+
+def register_pod(info: dict) -> dict:
+    """Keep ``info`` for ``process_pods()``; the pod updates it in
+    place when it reads its device-resident counts back."""
+    _PROCESS_PODS.append(info)
+    return info
+
+
+def process_pods() -> list[dict]:
+    """Per registered pod, oldest first: ``protocol``, ``n_shards``,
+    ``n_replicas``, ``inbox``, ``working_capacity`` and ``tiers``
+    (``kernel_small_rounds``, ``route_small_rounds``, ``rounds`` from
+    the last ``begin_resident`` to the last post-window read, or None).
+    Copies taken now."""
+    return [dict(p, tiers=p["tiers"] and dict(p["tiers"]))
+            for p in list(_PROCESS_PODS)]
+
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "process_collection", "register_replica", "PhaseClock", "phase",
+    "process_collection", "register_replica", "process_pods",
+    "register_pod", "PhaseClock", "phase",
     "protocol_ring_capacity",
     "TICK_MS_BUCKETS", "FlightRecorder", "KIND_FULL", "KIND_FUSED",
     "KIND_NARROW", "KIND_IDLE_SKIP", "KIND_NAMES", "SCHEMA_VERSION",

@@ -271,11 +271,12 @@ WATCH_PID = 9997
 #   is in flight);
 # inbox_hwm — the round's max per-(shard, replica) DELIVERED inbox
 #   rows, routed + injected (inbox_rows is the routed cross-cluster
-#   SUM; the per-inbox max is what a single inbox — and a compacted
-#   kernel inbox — must hold). Its high-water mark over a run is the
-#   measured occupancy that feeds adaptive capacity selection: the
-#   shape ladder's inbox axis and the compact_inbox sizing read it
-#   (tools/shape_ladder.py, PR 11).
+#   SUM; the per-inbox max is what a single inbox must hold). On the
+#   device the same reduction over the pending rows picks each
+#   round's tier (parallel/sharded.py sharded_round: rows at or
+#   beyond the working capacity send the round to the full one); on
+#   the host its high-water mark over a run feeds the shape ladder's
+#   inbox axis (tools/shape_ladder.py, PR 11).
 (TEL_ROUND, TEL_COMMITTED, TEL_IN_FLIGHT, TEL_ASSIGNED, TEL_INJECTED,
  TEL_INBOX_ROWS, TEL_CLAIM_ROWS, TEL_PREPARED, TEL_INBOX_HWM) = range(9)
 N_TEL_FIELDS = 9

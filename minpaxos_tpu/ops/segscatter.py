@@ -1,4 +1,4 @@
-"""One-pass segmented routing/compaction plans (shared kernels).
+"""One-pass segmented routing plans (shared kernels).
 
 The pod-mode routing fabric compacts every replica's addressed outbox
 rows into per-destination inboxes. The original fabric
@@ -29,9 +29,14 @@ old fabric (tests/test_route_fabric.py pins it, and the golden kernel
 fixtures pin it through whole cluster scenarios), including the
 overflow-drop-beyond-capacity semantics (legal message loss).
 
-``prefix_pack_plan`` is the 1-destination special case used by the
-inbox compaction step (models/cluster.py ``compact_inbox``): pack live
-rows to a prefix at a smaller static capacity.
+The plan comes in two halves so that a caller can look at the counts
+before it chooses how many slots to fill: ``route_counts`` is the
+prefix sum (its last column is each destination's row count), and
+``plan_slots`` the winner search for a static number of slots. Filled
+slots are a PREFIX of each inbox, so a plan for fewer slots than the
+capacity is the capacity's plan cut short: nothing moves, and nothing
+drops while every count fits (parallel/sharded.py's two-tier round
+chooses the slots on the device each round from exactly that).
 """
 
 from __future__ import annotations
@@ -39,13 +44,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route_plan", "gather_rows", "prefix_pack_plan"]
+__all__ = ["route_counts", "plan_slots", "gather_rows"]
 
 
-def route_plan(kind_flat: jnp.ndarray, src_rep: jnp.ndarray,
-               fdst: jnp.ndarray, alive: jnp.ndarray,
-               capacity: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Routing plan over the pooled outbox rows.
+def route_counts(kind_flat: jnp.ndarray, src_rep: jnp.ndarray,
+                 fdst: jnp.ndarray, alive: jnp.ndarray) -> jnp.ndarray:
+    """The segment-prefix-sum over the pooled outbox rows.
 
     kind_flat/src_rep/fdst: [N] pooled rows (N = R·M, row i's sender is
     src_rep[i]); fdst semantics: -1 broadcast to all other live
@@ -53,13 +57,11 @@ def route_plan(kind_flat: jnp.ndarray, src_rep: jnp.ndarray,
     excluded. alive: bool[R] — dead senders' rows drop, dead
     destinations receive nothing.
 
-    Returns (win, hit): win[d, s] = pooled-row index filling slot s of
-    destination d's inbox (rows keep pooled order; slots beyond the
-    destination's row count, and rows beyond ``capacity``, are unfilled
-    / dropped), hit[d, s] = slot filled.
+    Returns cnt[d, i] = rows destined to d among pooled rows 0..i
+    (inclusive): each destined row's inbox offset is its own cnt - 1,
+    and cnt[d, -1] is how many rows destination d is sent this round.
     """
     r = alive.shape[0]
-    n = kind_flat.shape[0]
     live = (kind_flat != 0) & alive[src_rep]
     isbc = live & (fdst == -1)
     isun = live & (fdst >= 0) & (fdst < r) & (fdst != src_rep)
@@ -70,16 +72,23 @@ def route_plan(kind_flat: jnp.ndarray, src_rep: jnp.ndarray,
     destined = ((isbc[None, :] & (src_rep[None, :] != dests))
                 | (isun[None, :] & (fdst[None, :] == dests))
                 ) & alive[:, None]
-    # the single segment-prefix-sum: cnt[d, i] = rows destined to d
-    # among pooled rows 0..i (inclusive) — each destined row's inbox
-    # offset is its own cnt - 1
-    cnt = jnp.cumsum(destined.astype(jnp.int32), axis=1)
-    # winner WITHOUT a scatter: cnt[d] is nondecreasing, so the row
-    # landing at slot s is the first with cnt == s + 1
-    want = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+    return jnp.cumsum(destined.astype(jnp.int32), axis=1)
+
+
+def plan_slots(cnt: jnp.ndarray,
+               slots: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Winner per inbox slot WITHOUT a scatter: cnt[d] is nondecreasing,
+    so the row landing at slot s is the first with cnt == s + 1.
+
+    Returns (win, hit): win[d, s] = pooled-row index filling slot s of
+    destination d's inbox (rows keep pooled order; slots beyond the
+    destination's row count are unfilled, rows beyond ``slots`` are
+    dropped), hit[d, s] = slot filled.
+    """
+    want = jnp.arange(1, slots + 1, dtype=jnp.int32)
     win = jax.vmap(lambda c: jnp.searchsorted(c, want))(cnt)
     win = win.astype(jnp.int32)
-    return win, win < n
+    return win, win < cnt.shape[1]
 
 
 def gather_rows(flat_tree, win: jnp.ndarray, hit: jnp.ndarray):
@@ -95,18 +104,3 @@ def gather_rows(flat_tree, win: jnp.ndarray, hit: jnp.ndarray):
         return jnp.where(hit, picked, z)
 
     return jax.tree_util.tree_map(one, flat_tree)
-
-
-def prefix_pack_plan(live: jnp.ndarray,
-                     capacity: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """1-D compaction plan: pack rows where ``live`` to a prefix of a
-    ``capacity``-row buffer (order preserved, overflow dropped).
-
-    Returns (win, hit) exactly like ``route_plan`` but for one
-    destination: win[s] = source row of packed slot s.
-    """
-    n = live.shape[0]
-    cnt = jnp.cumsum(live.astype(jnp.int32))
-    want = jnp.arange(1, capacity + 1, dtype=jnp.int32)
-    win = jnp.searchsorted(cnt, want).astype(jnp.int32)
-    return win, win < n

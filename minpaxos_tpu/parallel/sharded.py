@@ -27,8 +27,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from minpaxos_tpu.models.cluster import (
     ClusterState,
+    _fill_inboxes,
+    _pool_counts,
     _tree_stack,
-    cluster_step_impl,
+    client_rows_of,
+    route_outbox,
+    step_replicas,
     tree_slice,
     tree_set,
 )
@@ -39,6 +43,7 @@ from minpaxos_tpu.models.minpaxos import (
     init_replica,
     replica_step_impl,
 )
+from minpaxos_tpu.obs import register_pod
 from minpaxos_tpu.obs.recorder import (
     N_TEL_FIELDS,
     PH_POD_DISPATCH,
@@ -112,6 +117,131 @@ def init_sharded(cfg: MinPaxosConfig, n_shards: int, mesh=None,
                    out_shardings=out_sharding)(cfg, n_shards, init_fn)
 
 
+def working_capacity(cfg: MinPaxosConfig, ext_rows: int) -> int:
+    """Pending slots per inbox that the small tier of a round delivers
+    and routes: a static function of shapes the step already has.
+
+    A healthy round sends the follower being caught up p ACCEPTs, the
+    2p committed slots by which its reported frontier trails
+    (models/minpaxos.py section 7c) and one frontier row, the other
+    followers p + 1, and the leader a handful of run-compressed acks:
+    3p + 1 at most, with p <= ``ext_rows``. Four times ``ext_rows`` is
+    that with a round's proposals to spare, rounded up to the 128 lanes
+    of a vector register. Bursts beyond it (a revived follower's
+    ``cfg.catchup_rows`` of catch-up and as many retries) take the
+    configured capacity. Where this is not below ``cfg.inbox`` the
+    round has one tier."""
+    return min(cfg.inbox, 128 * max(1, -(-4 * ext_rows // 128)))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _step_groups(cfg: MinPaxosConfig, step, rows: int, ss: ClusterState,
+                 ext: MsgBatch):
+    """Every group's replicas delivered ``rows`` pending slots + ext and
+    stepped: (states', outboxes, exec results), leading axes [G, R].
+
+    A jit of its own so that the kernel is TRACED once per shape in a
+    process, not once per program that holds it: ``sharded_step`` (the
+    election's two rounds) and the full tier of the fused dispatches
+    call it with the same arguments, and tracing the kernel is seconds
+    of every warm set-up (the compile cache spares the compile, never
+    the trace). It is inlined where it is called."""
+    return jax.vmap(functools.partial(
+        step_replicas, cfg, step_impl=step, rows=rows))(ss, ext)
+
+
+def _one_tier_round(cfg: MinPaxosConfig, step, ss: ClusterState,
+                    ext: MsgBatch):
+    """``jax.vmap(cluster_step_impl)`` with the outboxes kept:
+    (ss', exec results, outboxes)."""
+    states, outbox, execr = _step_groups(cfg, step, cfg.inbox, ss, ext)
+    pending = jax.vmap(functools.partial(route_outbox, cfg))(outbox,
+                                                             ss.alive)
+    return ClusterState(states, pending, ss.alive), execr, outbox
+
+
+def _pretrace_kernels(cfg: MinPaxosConfig, step, ss: ClusterState,
+                      ext_rows: int) -> None:
+    """Trace the kernel variants of a fused dispatch at the TOP of its
+    jit, before its scan; the scan's ``cond`` branches then find them
+    in jit's trace cache. On the chip's host a kernel trace costs
+    2.1 s here, 4.3 s inside the scan's body and 7.3 s inside a
+    ``cond`` branch there (trace only, one process; my chip runs,
+    PR 27; the sandbox shows no such difference), and every warm
+    set-up pays for it, whatever the compile cache holds."""
+    cfg = cfg._replace(gate_exec=False)
+    ext = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(ss.alive.shape + (ext_rows,), x.dtype),
+        MsgBatch.empty(1))
+    tiers = {cfg.inbox} if cfg.route_fabric == "dense" else {
+        working_capacity(cfg, ext_rows), cfg.inbox}
+    for rows in tiers:
+        _step_groups.trace(cfg, step, rows, ss, ext)
+
+
+def sharded_round(cfg: MinPaxosConfig, step, work_rows: int,
+                  ss: ClusterState, ext: MsgBatch):
+    """One synchronous round for every shard, [G, R, ...] in and out,
+    at the rows that are live: what ``jax.vmap(cluster_step_impl)``
+    computes, byte for byte, from kernels and a route called at a
+    smaller static shape whenever this round's rows fit it.
+
+    Routing packs each destination's rows to a PREFIX of its inbox, so
+    the padding is a suffix that can be cut without moving a row. Both
+    choices are whole-chip scalars taken OUTSIDE the vmap over groups
+    (inside it a ``lax.cond`` lowers to a select and runs both sides):
+
+    * kernel tier: when no live row lies at or beyond slot
+      ``work_rows`` of any pending inbox, deliver
+      ``pending[..., :work_rows] ++ ext`` and step at
+      M = work_rows + ext, else at M = cfg.inbox + ext. (The cut can
+      bring the last pending row next to the first ext row, which only
+      matters to ack-run compression if ext carried an ACCEPT that
+      continues a peer's run; ext rows are client PROPOSEs and
+      election PREPAREs.)
+    * route tier: the route's prefix sum is computed once; its last
+      column is each destination's row count. When the largest over
+      [G, R] is <= ``work_rows``, search and gather ``work_rows`` slots
+      and zero-pad to ``cfg.inbox`` so the carried state keeps its
+      shape, else ``cfg.inbox`` slots. Overflow beyond ``cfg.inbox``
+      drops as it always did.
+
+    Both tiers are in the one compiled program: a round that falls
+    back compiles nothing. With ``work_rows >= cfg.inbox`` (or the
+    dense fabric, which has no counts to look at) there is one tier and
+    the program is ``jax.vmap(cluster_step_impl)``.
+
+    Returns (ss', exec results, small); ``small`` is bool[2]: this
+    round's kernel, and its route, ran at ``work_rows``.
+    """
+    cfg = cfg._replace(gate_exec=False)  # see cluster_step_impl
+    full = cfg.inbox
+    if work_rows >= full or cfg.route_fabric == "dense":
+        ss, execr, _ = _one_tier_round(cfg, step, ss, ext)
+        return ss, execr, jnp.zeros(2, bool)
+
+    def fill(slots):
+        return jax.vmap(functools.partial(
+            _fill_inboxes, slots=slots, capacity=full))
+
+    def kernel(rows):
+        def run(ss, ext):
+            states, outbox, execr = _step_groups(cfg, step, rows, ss, ext)
+            flat, cnt = jax.vmap(_pool_counts)(outbox.msgs, outbox.dst,
+                                               ss.alive)
+            route_small = cnt[..., -1].max() <= work_rows
+            pending = jax.lax.cond(route_small, fill(work_rows), fill(full),
+                                   flat, cnt)
+            return (ClusterState(states, pending, ss.alive), execr,
+                    route_small)
+        return run
+
+    kernel_small = ~(ss.pending.kind[..., work_rows:] != 0).any()
+    ss, execr, route_small = jax.lax.cond(
+        kernel_small, kernel(work_rows), kernel(full), ss, ext)
+    return ss, execr, jnp.stack([kernel_small, route_small])
+
+
 @functools.partial(jax.jit, static_argnums=(0, 3), donate_argnums=1)
 def sharded_step(cfg: MinPaxosConfig, ss: ClusterState, ext: MsgBatch,
                  step_impl=None):
@@ -120,11 +250,15 @@ def sharded_step(cfg: MinPaxosConfig, ss: ClusterState, ext: MsgBatch,
     ext is [G, R, Mext]. Returns (ss', exec results, client rows,
     client mask) with a leading G axis. Input shardings propagate: with
     ss/ext sharded on 'shard', XLA partitions the whole step with no
-    communication.
+    communication. One tier, at the configured capacity: this is the
+    host-in-the-loop entry (elections, tests, the multi-host worker),
+    and a second kernel variant here is seconds of every set-up; the
+    fused dispatches below take ``sharded_round``.
     """
     step = replica_step_impl if step_impl is None else step_impl
-    return jax.vmap(
-        functools.partial(cluster_step_impl, cfg, step_impl=step))(ss, ext)
+    ss, execr, outbox = _one_tier_round(
+        cfg._replace(gate_exec=False), step, ss, ext)
+    return (ss, execr, *client_rows_of(outbox))
 
 
 @functools.partial(jax.jit, static_argnums=(0, 2), donate_argnums=1)
@@ -211,7 +345,9 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
 
     step = replica_step_impl if step_impl is None else step_impl
     cursor_rep = jnp.maximum(leader, 0)  # mencius (-1): replica 0's view
-    cstep = functools.partial(cluster_step_impl, cfg, step_impl=step)
+    cstep = functools.partial(sharded_round, cfg, step,
+                              working_capacity(cfg, ext_rows))
+    _pretrace_kernels(cfg, step, ss, ext_rows)
     ts = jnp.arange(k_rounds, dtype=jnp.int32)
     # PRNG lanes for ALL k rounds in one batched call, hoisted out of
     # the scan body (ops/workload.py workload_lanes: per-round tracing
@@ -223,7 +359,7 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         t, key_t, val_t = xs
         ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
                              n_proposals, leader, round0 + t, key_t, val_t)
-        ss, _, _, _ = jax.vmap(cstep)(ss, ext)
+        ss, _, _ = cstep(ss, ext)
         # drain-only sub-steps: deliver queued traffic, no new work —
         # the ext batch is ZERO-WIDTH, not zero-filled, so the kernel
         # (and the routed pool behind it) runs at the inbox capacity
@@ -231,7 +367,7 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         # was inert anyway, so the commit stream is unchanged (PR 11)
         ext0 = jax.tree_util.tree_map(lambda x: x[..., :0], ext)
         for _ in range(substeps - 1):
-            ss, _, _, _ = jax.vmap(cstep)(ss, ext0)
+            ss, _, _ = cstep(ss, ext0)
         return ss, (ss.states.committed_upto[:, cursor_rep],
                     ss.states.crt_inst[:, cursor_rep])
 
@@ -240,13 +376,14 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
 
 
 # paxlint: resident-loop
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 12, 13, 14),
-                   donate_argnums=(4, 5, 6, 7))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 13, 14, 15),
+                   donate_argnums=(4, 5, 6, 7, 8))
 def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                          k_rounds: int, ss: ClusterState, inject_round,
-                         lat_hist, telemetry, n_proposals, leader, round0,
-                         seed=0, step_impl=None, key_space: int = 1 << 20,
-                         substeps: int = 1, tel_base=0):
+                         lat_hist, telemetry, tiers, n_proposals, leader,
+                         round0, seed=0, step_impl=None,
+                         key_space: int = 1 << 20, substeps: int = 1,
+                         tel_base=0):
     """k rounds in ONE dispatch with nothing read back but two scalars.
 
     The fully device-resident measured loop (ISSUE 8): workload rows
@@ -280,8 +417,13 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
       drop out of the trace at compile time, so ``BENCH_TELEMETRY=0``
       runs the exact PR-8 dispatch. Telemetry never touches protocol
       state — state is byte-identical on/off (tests/test_paxray.py).
+    * ``tiers`` int32[3] — how often the two-tier round
+      (``sharded_round``) engaged: rounds whose kernel ran at the
+      working capacity, rounds whose route did, rounds in all (a round
+      of several sub-steps counts as small when each of them was).
+      Read back after the window like the histogram.
 
-    Returns (ss', inject_round', lat_hist', telemetry',
+    Returns (ss', inject_round', lat_hist', telemetry', tiers',
     committed_total, in_flight) — the final two are the per-dispatch
     scalar cursors (committed frontier for throughput progress,
     assigned-but-uncommitted count for the drain loop's exactness
@@ -289,7 +431,9 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     """
     step = replica_step_impl if step_impl is None else step_impl
     cursor_rep = jnp.maximum(leader, 0)
-    cstep = functools.partial(cluster_step_impl, cfg, step_impl=step)
+    cstep = functools.partial(sharded_round, cfg, step,
+                              working_capacity(cfg, ext_rows))
+    _pretrace_kernels(cfg, step, ss, ext_rows)
     w = cfg.window
     pos = jnp.arange(w, dtype=jnp.int32)[None, :]  # [1, W] ring positions
     ts = jnp.arange(k_rounds, dtype=jnp.int32)
@@ -304,7 +448,7 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     has_prepared = getattr(ss.states, "prepared", None) is not None
 
     def body(carry, xs):
-        ss, inj, hist, tel = carry
+        ss, inj, hist, tel, tiers = carry
         t, key_t, val_t = xs
         r = round0 + t
         u_prev = ss.states.committed_upto[:, cursor_rep]
@@ -328,7 +472,7 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         with jax.named_scope("px.workload"):
             ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
                                  n_proposals, leader, r, key_t, val_t)
-        ss, _, _, _ = jax.vmap(cstep)(ss, ext)
+        ss, _, small = cstep(ss, ext)
         # zero-WIDTH drain sub-steps (see sharded_run): smaller static
         # kernel shape, identical commit stream
         ext0 = jax.tree_util.tree_map(lambda x: x[..., :0], ext)
@@ -341,7 +485,9 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 drain_live = (ss.pending.kind != 0).sum(axis=-1)
                 inbox_rows = inbox_rows + drain_live.sum()
                 inbox_hwm = jnp.maximum(inbox_hwm, drain_live.max())
-            ss, _, _, _ = jax.vmap(cstep)(ss, ext0)
+            ss, _, small_d = cstep(ss, ext0)
+            small = small & small_d
+        tiers = tiers + jnp.append(small, True).astype(tiers.dtype)
         with jax.named_scope("px.lat_hist"):
             u_new = ss.states.committed_upto[:, cursor_rep]
             c_new = ss.states.crt_inst[:, cursor_rep]
@@ -384,13 +530,14 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 tel = jax.lax.dynamic_update_index_in_dim(
                     tel, row,
                     jnp.mod(r - tel_base, telemetry.shape[0]), 0)
-        return (ss, inj, hist, tel), None
+        return (ss, inj, hist, tel, tiers), None
 
-    (ss, inject_round, lat_hist, telemetry), _ = jax.lax.scan(
-        body, (ss, inject_round, lat_hist, telemetry), (ts, keys, vals))
+    (ss, inject_round, lat_hist, telemetry, tiers), _ = jax.lax.scan(
+        body, (ss, inject_round, lat_hist, telemetry, tiers),
+        (ts, keys, vals))
     upto = ss.states.committed_upto[:, cursor_rep]
     crt = ss.states.crt_inst[:, cursor_rep]
-    return (ss, inject_round, lat_hist, telemetry,
+    return (ss, inject_round, lat_hist, telemetry, tiers,
             (upto + 1).sum(), (crt - 1 - upto).sum())
 
 
@@ -457,6 +604,13 @@ class ShardedCluster:
             self.leader = 0
         self.ss = init_sharded(cfg, n_shards, mesh, self._init_fn)
         self._seed = 0
+        # what the resident loop leaves for a reader after the run
+        # (obs.process_pods()): filled on the post-window path only
+        self._pod = register_pod({
+            "protocol": protocol, "n_shards": n_shards,
+            "n_replicas": cfg.n_replicas, "inbox": cfg.inbox,
+            "working_capacity": working_capacity(cfg, ext_rows),
+            "tiers": None})
 
     def elect(self, leader: int = 0) -> None:
         if self.protocol == "mencius":
@@ -505,23 +659,25 @@ class ShardedCluster:
         """Arm the resident loop's device-side bookkeeping: a fresh
         inject-round ring (all -1: slots already in flight are excluded
         from the latency sample, mirroring the host path's pre-phase
-        cursor row), a zeroed latency histogram and — when
-        ``telemetry_rounds`` > 0 — the paxray telemetry ring (one row
-        per round, round column -1 = never written; 0 rows compiles
-        the telemetry-free PR-8 dispatch)."""
+        cursor row), a zeroed latency histogram, zeroed tier counts
+        and — when ``telemetry_rounds`` > 0 — the paxray telemetry
+        ring (one row per round, round column -1 = never written; 0
+        rows compiles the telemetry-free PR-8 dispatch)."""
         self._inject_round = jnp.full(
             (self.n_shards, self.cfg.window), -1, jnp.int32)
         self._lat_hist = jnp.zeros(lat_bins, jnp.int32)
         self._telemetry = jnp.full((telemetry_rounds, N_TEL_FIELDS), -1,
                                    jnp.int32)
+        self._tiers = jnp.zeros(3, jnp.int32)
         # ring indices are relative to the round counter at arming
         # time, so re-arming (bench: warmup, then measured phase)
         # restarts the ring at row 0
         self._tel_base = int(self._seed)
         if self.mesh is not None:
-            # ring rides the shard axis like the state; the histogram
-            # and telemetry rows are cross-shard reductions and are
-            # REPLICATED on the mesh — all placed up front to match
+            # ring rides the shard axis like the state; the histogram,
+            # the telemetry rows and the tier counts are cross-shard
+            # reductions and are REPLICATED on the mesh — all placed up
+            # front to match
             # the dispatch's output shardings exactly, or the second
             # dispatch recompiles (~9 s observed: arm-time
             # SingleDeviceSharding vs XLA's NamedSharding(P()) output
@@ -533,6 +689,8 @@ class ShardedCluster:
                 self._lat_hist, NamedSharding(self.mesh, P()))
             self._telemetry = jax.device_put(
                 self._telemetry, NamedSharding(self.mesh, P()))
+            self._tiers = jax.device_put(
+                self._tiers, NamedSharding(self.mesh, P()))
 
     # paxlint: resident-loop
     def run_resident(self, k_rounds: int, n_proposals: int,
@@ -540,14 +698,15 @@ class ShardedCluster:
         """k rounds in one dispatch, fully device-resident; returns
         (committed_total, in_flight) — the sanctioned per-dispatch
         scalar readbacks (progress cursor + drain check). Everything
-        else (state, inject ring, latency histogram, telemetry ring)
-        stays on device in donated buffers until ``end_resident``."""
+        else (state, inject ring, latency histogram, telemetry ring,
+        tier counts) stays on device in donated buffers until
+        ``end_resident``."""
         with phase(PH_POD_DISPATCH):
             (self.ss, self._inject_round, self._lat_hist, self._telemetry,
-             committed, in_flight) = sharded_run_resident(
+             self._tiers, committed, in_flight) = sharded_run_resident(
                 self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
                 self._inject_round, self._lat_hist, self._telemetry,
-                jnp.int32(min(n_proposals, self.ext_rows)),
+                self._tiers, jnp.int32(min(n_proposals, self.ext_rows)),
                 jnp.int32(self.leader), jnp.int32(self._seed),
                 jnp.int32(self.seed), self._step_impl, self.key_space,
                 substeps, jnp.int32(self._tel_base))
@@ -562,8 +721,26 @@ class ShardedCluster:
     def resident_hist(self) -> np.ndarray:
         """Snapshot the device histogram WITHOUT disarming — the
         bench's early-emit path after a measured window whose fault leg
-        hasn't run yet (still a post-window read, never per-dispatch)."""
+        hasn't run yet (still a post-window read, never per-dispatch).
+        Takes the tier counts with it (``resident_tiers``)."""
+        self.resident_tiers()
         return np.asarray(self._lat_hist)
+
+    def resident_tiers(self) -> dict:
+        """How often the two-tier round engaged since
+        ``begin_resident``: ``kernel_small_rounds`` and
+        ``route_small_rounds`` (rounds whose kernel, and whose route,
+        ran at ``working_capacity`` rows), ``rounds`` in all, and the
+        two capacities. A post-window read by the same discipline as
+        ``resident_telemetry``; the reading is kept, as of this call,
+        in ``obs.process_pods()``."""
+        kernel_small, route_small, rounds = np.asarray(self._tiers).tolist()
+        self._pod["tiers"] = {"kernel_small_rounds": kernel_small,
+                              "route_small_rounds": route_small,
+                              "rounds": rounds}
+        return {**self._pod["tiers"],
+                "working_capacity": self._pod["working_capacity"],
+                "inbox": self._pod["inbox"]}
 
     def resident_telemetry(self) -> np.ndarray:
         """The paxray post-window telemetry readback: written rows
@@ -580,10 +757,11 @@ class ShardedCluster:
         the latency histogram (numpy [LATENCY_BINS], exact integer
         round latencies) and disarms the resident bookkeeping
         (telemetry included — read ``resident_telemetry`` first)."""
-        hist = np.asarray(self._lat_hist)
+        hist = self.resident_hist()
         self._inject_round = None
         self._lat_hist = None
         self._telemetry = None
+        self._tiers = None
         return hist
 
     def kill(self, replica: int) -> None:

@@ -1060,6 +1060,21 @@ def test_resident_loop_real_suppression_is_load_bearing():
                       and "scalar readback" in v.msg for v in vs), vs
 
 
+def test_resident_loop_flags_a_mid_window_tier_counter_read():
+    """The tier counts (PR 27) ride the donated carry of the real
+    resident dispatch; their readback, ``resident_tiers``, is
+    post-window host code like the histogram's. A dispatch root that
+    peeked at it would be a third per-dispatch readback: flagged."""
+    path = "minpaxos_tpu/parallel/sharded.py"
+    src = (REPO / path).read_text()
+    assert run_passes(Project({path: src}), ("resident-loop",)) == []
+    hook = "        self._seed += k_rounds\n        # the per-dispatch scalar"
+    assert src.count(hook) == 1
+    peek = src.replace(hook, "        self.resident_tiers()\n" + hook)
+    vs = run_passes(Project({path: peek}), ("resident-loop",))
+    assert vs and all("run_resident" in v.msg for v in vs), vs
+
+
 # ----------------------------------------------------------- spec-sync
 
 

@@ -10,13 +10,21 @@ enough. The old fabric stays in-tree behind
 golden kernel fixtures (tests/test_kernel_golden.py) extend the pin
 through whole multi-protocol cluster scenarios.
 
-Also here: the inbox-compaction step (``compact_inbox``) — NOT
-byte-equal at the frame level by design (padding gaps vanish, ack
-runs may merge) — must leave the protocol STATE byte-identical when
-capacity covers occupancy, across all three protocols.
+Also here: the two-tier round (parallel/sharded.py ``sharded_round``,
+PR 27), which calls the kernels and the route at a smaller static
+shape whenever a round's rows fit the working capacity. It must leave
+the whole ``ClusterState``, the latency histogram and the telemetry
+ring byte-identical to the one-tier program after EVERY round, across
+all three protocols and through a fault leg whose recovery overflows
+the working capacity (these cases took the place of PR 11's
+``compact_inbox`` ones, which pinned the same property for the static,
+lossy knob the tier replaced).
 """
 
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -24,12 +32,18 @@ import numpy as np
 import pytest
 
 from minpaxos_tpu.models.cluster import (
-    Cluster,
+    ClusterState,
     _route,
     _route_segmented,
 )
-from minpaxos_tpu.models.minpaxos import MinPaxosConfig, MsgBatch
-from minpaxos_tpu.wire.messages import MsgKind, Op
+from minpaxos_tpu.models.minpaxos import (
+    MinPaxosConfig,
+    MsgBatch,
+    Outbox,
+    replica_step_impl,
+)
+from minpaxos_tpu.parallel import sharded
+from minpaxos_tpu.wire.messages import MsgKind
 
 R = 5
 
@@ -149,55 +163,219 @@ def test_overflow_drops_beyond_capacity():
     _assert_tree_equal(got, _route(cfg, msgs, jnp.asarray(dst), alive, 4))
 
 
-@pytest.mark.parametrize("protocol", ["minpaxos", "classic", "mencius"])
-def test_compaction_state_equivalence(protocol):
-    """compact_inbox at adequate capacity: the protocol STATE (and so
-    the commit stream) stays byte-identical to the uncompacted run;
-    only the inbox frame layout differs. Exercises kill/revive so
-    dead-replica zeroing composes with the pack.
+# ------------------------------------------------- the two-tier round
 
-    Deliberately reuses test_kernel_golden's exact config + ext width:
-    the uncompacted legs then share the golden scenarios' compiled
-    ``cluster_step`` (same static cfg, same shapes — one in-process
-    jit cache), so this test only pays the 3 compacted-variant
-    compiles (tier-1 budget discipline)."""
+#: a toy shape with two tiers: 16 proposals a round give a working
+#: capacity of 128 rows against an inbox of 384; a follower revived
+#: after ten dead rounds is sent 16 ACCEPTs + 128 catch-up rows + 1,
+#: which overflows it
+_TIER_KW = dict(n_replicas=5, window=512, inbox=384, exec_batch=32,
+                kv_pow2=10, catchup_rows=128, recovery_rows=16)
+_TIER_EXT = 16
+_TIER_ROUNDS, _KILL_AT, _REVIVE_AT, _DRAIN_FROM = 34, 6, 16, 26
+
+_STATICS = {"sharded_run": (0, 1, 2, 3, 9, 10, 11),
+            "sharded_run_resident": (0, 1, 2, 3, 13, 14, 15)}
+
+
+def _one_tier(mp):
+    """The one-tier program for comparison, with no switch in the
+    program to ask for it: the working capacity is patched up to the
+    inbox and the entry points re-jitted from their plain functions
+    (behind a fresh lambda each: jit's trace cache is keyed by the
+    function, and the tiered trace is in it)."""
+    mp.setattr(sharded, "working_capacity", lambda cfg, ext_rows: cfg.inbox)
+    for name, statics in _STATICS.items():
+        plain = getattr(sharded, name).__wrapped__
+        mp.setattr(sharded, name, jax.jit(
+            (lambda f: lambda *a: f(*a))(plain), static_argnums=statics))
+
+
+def _tier_cfg(protocol):
     from minpaxos_tpu.models.paxos import classic_config
 
-    from tests.test_kernel_golden import _KW
+    return (classic_config(**_TIER_KW) if protocol == "classic"
+            else MinPaxosConfig(**_TIER_KW))
 
-    def build(compact):
-        kw = dict(_KW, compact_inbox=compact) if compact else dict(_KW)
-        cfg = (classic_config(**kw) if protocol == "classic"
-               else MinPaxosConfig(**kw))
-        if protocol == "mencius":
-            from minpaxos_tpu.models.mencius import MenciusCluster
 
-            return MenciusCluster(cfg, ext_rows=8)
-        return Cluster(cfg, ext_rows=8)
-
-    def drive(cl):
-        rng = np.random.default_rng(11)
+@functools.lru_cache(maxsize=None)
+def _tier_run(protocol: str, one_tier: bool):
+    """Healthy rounds, kill, dead rounds, revive, recovery, drain, one
+    round a dispatch; every round's whole state, the counter after
+    every round, and the window's histogram and telemetry ring."""
+    with pytest.MonkeyPatch.context() as mp:
+        if one_tier:
+            _one_tier(mp)
+        sc = sharded.ShardedCluster(
+            _tier_cfg(protocol), 2, ext_rows=_TIER_EXT, key_space=256,
+            protocol="mencius" if protocol == "mencius" else "minpaxos")
         if protocol != "mencius":
-            cl.elect(0)
-            cl.step()
-            cl.step()
-        for i in range(10):
-            if i == 4:
-                cl.kill(2)
-            if i == 7:
-                cl.revive(2)
-            n = 5
-            cl.propose(np.full(n, int(Op.PUT)), rng.integers(0, 30, n),
-                       rng.integers(0, 99, n), np.arange(n) + i * 10,
-                       client_id=1, to=0)
-            cl.step()
-        for _ in range(6):
-            cl.step()
-        return cl
+            # the election's two deliveries through the resident
+            # dispatch too (ShardedCluster.elect would compile the
+            # one-tier sharded_step for them)
+            sc.ss = sharded.elect_all(sc.cfg, sc.ss, 0)
+        sc.begin_resident(telemetry_rounds=64)
+        if protocol != "mencius":
+            sc.run_resident(2, 0)
+            sc.begin_resident(telemetry_rounds=64)
+        states, tiers = [], []
+        for i in range(_TIER_ROUNDS):
+            if i == _KILL_AT:
+                sc.kill(2)
+            if i == _REVIVE_AT:
+                sc.revive(2)
+            sc.run_resident(1, _TIER_EXT if i < _DRAIN_FROM else 0)
+            states.append([np.asarray(x)
+                           for x in jax.tree_util.tree_leaves(sc.ss)])
+            tiers.append(sc.resident_tiers())
+        tel = sc.resident_telemetry()
+        return states, tiers, tel, sc.end_resident(), sc.committed()
 
-    # compacted capacity 36 < inbox + ext = 40, >= this load's occupancy
-    a = drive(build(0))
-    b = drive(build(36))
-    _assert_tree_equal(a.cs.states, b.cs.states,
-                       ctx=f"{protocol}: state diverged under compaction")
-    assert a.replies == b.replies
+
+@pytest.mark.parametrize("protocol", ["minpaxos", "classic", "mencius"])
+def test_tiered_round_state_equivalence(protocol):
+    """After every round the whole ClusterState (states, pending,
+    alive) equals the one-tier program's, byte for byte; so do the
+    latency histogram and the telemetry ring. The leg covers healthy
+    rounds, a dead follower, its revival (whose catch-up burst takes
+    the full tier) and the drain."""
+    got, tiers, tel, hist, committed = _tier_run(protocol, False)
+    want, ref_tiers, ref_tel, ref_hist, ref_committed = _tier_run(
+        protocol, True)
+    assert tiers[-1]["working_capacity"] == 128 < tiers[-1]["inbox"]
+    assert ref_tiers[-1]["kernel_small_rounds"] == 0  # one tier: none
+    for i, (a, b) in enumerate(zip(got, want)):
+        for j, (x, y) in enumerate(zip(a, b)):
+            np.testing.assert_array_equal(
+                x, y, err_msg=f"{protocol}: round {i}, leaf {j}")
+    np.testing.assert_array_equal(tel, ref_tel)
+    np.testing.assert_array_equal(hist, ref_hist)
+    assert committed == ref_committed and hist.sum() > 0
+    # both tiers really ran, or the comparison showed nothing
+    last = tiers[-1]
+    assert 0 < last["kernel_small_rounds"] < last["rounds"] == _TIER_ROUNDS
+    assert 0 < last["route_small_rounds"] < last["rounds"]
+
+
+def test_tier_counter_small_when_healthy_full_after_revive():
+    """Healthy and dead rounds count as small in both tiers; the round
+    that routes the revived follower's catch-up burst (145 rows at a
+    working capacity of 128) counts a full route, the round that
+    delivers it a full kernel; small + full = rounds; and the reading
+    is left in ``obs.process_pods()`` as of the last read."""
+    from minpaxos_tpu import obs
+    from minpaxos_tpu.obs.recorder import TEL_INBOX_HWM
+
+    _, tiers, tel, _, _ = _tier_run("minpaxos", False)
+    k = np.diff([0] + [t["kernel_small_rounds"] for t in tiers])
+    r = np.diff([0] + [t["route_small_rounds"] for t in tiers])
+    assert [t["rounds"] for t in tiers] == list(range(1, _TIER_ROUNDS + 1))
+    assert k[:_REVIVE_AT].all() and r[:_REVIVE_AT].all()
+    # the kernel is small exactly when the delivered high-water mark
+    # (pending + the leader's ext) shows no inbox above 128 + ext
+    hwm = tel[:, TEL_INBOX_HWM]
+    assert (hwm > 128).any()
+    np.testing.assert_array_equal(k == 0, hwm > 128)
+    # a route that overflowed is the NEXT round's full kernel
+    np.testing.assert_array_equal(r[:-1] == 0, k[1:] == 0)
+    assert k[_DRAIN_FROM + 2:].all() and r[_DRAIN_FROM + 2:].all()
+    pod = [p for p in obs.process_pods()
+           if p["tiers"] and p["working_capacity"] == 128
+           and p["protocol"] == "minpaxos"][-1]
+    assert pod["tiers"] == {f: tiers[-1][f] for f in (
+        "kernel_small_rounds", "route_small_rounds", "rounds")}
+
+
+class _Canned(NamedTuple):
+    """The state of ``_canned_step``: the outbox it will emit."""
+    msgs: MsgBatch
+    dst: jnp.ndarray
+
+
+def _canned_step(cfg, state, inbox):
+    """A stand-in kernel that emits its state as its outbox, whatever
+    it is delivered: puts the route tier of ``sharded_round`` alone
+    under test."""
+    acked = jnp.zeros(state.dst.shape, bool)
+    return state, Outbox(state.msgs, state.dst, acked), inbox.kind.sum()
+
+
+@pytest.mark.parametrize("count,small", [(128, True), (129, False)])
+def test_route_tier_boundary_exact_capacity_small_one_more_full(count,
+                                                                small):
+    """A destination sent exactly ``working_capacity`` rows is routed
+    at the small tier, one row more at the full one, and either way
+    the inboxes are the one-tier fabric's: no row lost, pooled-row
+    order kept."""
+    cfg = MinPaxosConfig(n_replicas=R, window=64, inbox=384)
+    g, m = 2, 160
+    cols = {f: np.zeros((g, R, m), np.int32) for f in MsgBatch._fields}
+    dst = np.full((g, R, m), -2, np.int32)
+    rng = np.random.default_rng(count)
+    # group 1, replica 0 unicasts `count` rows to replica 3, the rest
+    # of the pool is a sparse mix (far fewer rows per destination)
+    for gi in range(g):
+        for r in range(R):
+            pos = np.sort(rng.choice(m, size=12, replace=False))
+            cols["kind"][gi, r, pos] = rng.integers(1, 10, 12)
+            cols["cmd_id"][gi, r, pos] = rng.integers(1, 1 << 20, 12)
+            dst[gi, r, pos] = rng.integers(-1, R, 12)
+    cols["kind"][1, 0, :] = 0
+    cols["kind"][1, 0, :count] = int(MsgKind.ACCEPT)
+    cols["cmd_id"][1, 0, :count] = np.arange(count) + 1
+    dst[1, 0, :] = 3
+    dst[1, 1:, :][dst[1, 1:, :] == 3] = -2  # only replica 0 sends to 3
+    dst[1, 1:, :][dst[1, 1:, :] == -1] = -2
+    msgs = MsgBatch(**{f: jnp.asarray(v) for f, v in cols.items()})
+    alive = jnp.ones((g, R), bool)
+    ss = ClusterState(
+        states=_Canned(msgs, jnp.asarray(dst)),
+        pending=jax.tree_util.tree_map(
+            lambda x: jnp.zeros((g, R, cfg.inbox), x.dtype),
+            MsgBatch.empty(1)),
+        alive=alive)
+    ext = jax.tree_util.tree_map(lambda x: x[..., :0], ss.pending)
+    out, _, flags = jax.jit(functools.partial(
+        sharded.sharded_round, cfg, _canned_step, 128))(ss, ext)
+    assert flags.tolist() == [True, small]  # empty pending: small kernel
+    want = jax.vmap(lambda o, d, a: _route_segmented(cfg, o, d, a,
+                                                     cfg.inbox))(
+        msgs, jnp.asarray(dst), alive)
+    _assert_tree_equal(out.pending, want)
+    got = np.asarray(out.pending.cmd_id)[1, 3]
+    assert list(got[:count]) == list(range(1, count + 1))
+    assert not got[count:].any()
+
+
+def _count_conds(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "cond"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _count_conds(sub)
+    return n
+
+
+def test_working_capacity_at_or_above_inbox_compiles_one_tier():
+    """Where the working capacity does not lie below the inbox there
+    is nothing to choose: the traced round holds no ``cond`` and is
+    the one-tier program; below it, the kernel's choice and one route
+    choice inside each of its sides."""
+    cfg = MinPaxosConfig(n_replicas=3, window=64, inbox=256, exec_batch=8,
+                         kv_pow2=6, catchup_rows=8, recovery_rows=8)
+    ss = sharded.init_sharded(cfg, 2)
+
+    def conds(ext_rows):
+        ext = jax.tree_util.tree_map(
+            lambda x: jnp.zeros((2, 3, ext_rows), x.dtype),
+            MsgBatch.empty(1))
+        rows = sharded.working_capacity(cfg, ext_rows)
+        return rows, _count_conds(jax.make_jaxpr(functools.partial(
+            sharded.sharded_round, cfg, replica_step_impl, rows))(
+                ss, ext).jaxpr)
+
+    assert conds(64) == (256, 0)
+    assert conds(16) == (128, 3)
+    assert sharded.working_capacity(cfg, 0) == 128
+    assert sharded.working_capacity(cfg._replace(inbox=1280), 128) == 512
+    assert sharded.working_capacity(cfg._replace(inbox=2688), 512) == 2048

@@ -162,3 +162,45 @@ def test_fused_substeps_cut_commit_rounds():
     r2, u2 = first_round_reaching(2)
     assert r1 < 99 and r2 < 99, (u1, u2)
     assert r2 < r1, (r1, r2, u1[:, 0], u2[:, 0])
+
+
+def test_tiered_fused_run_on_the_mesh_matches_one_tier_steps():
+    """``sharded_run`` at a shape with two tiers (working capacity 128
+    of an inbox of 384), its groups spread over the 8-device mesh so
+    that each tier choice is a cross-shard reduction: after every round
+    the state equals that of the one-tier ``sharded_step`` fed the same
+    (seed, round) stream off the mesh, through a dead follower and the
+    revival whose catch-up burst takes the full tier."""
+    from minpaxos_tpu.parallel.sharded import (
+        make_propose_ext,
+        set_alive,
+        sharded_run,
+        working_capacity,
+    )
+
+    cfg = MinPaxosConfig(n_replicas=5, window=512, inbox=384, exec_batch=32,
+                         kv_pow2=10, catchup_rows=128, recovery_rows=16)
+    g, p, seed = 8, 16, 5
+    assert working_capacity(cfg, p) == 128
+    got = elect_all(cfg, init_sharded(cfg, g, make_mesh()), 0)
+    want = elect_all(cfg, init_sharded(cfg, g), 0)
+    full_rounds = 0
+    for i in range(24):
+        if i in (6, 16):  # kill, then revive ten rounds behind
+            got = set_alive(cfg, got, jnp.int32(2), i == 16)
+            want = set_alive(cfg, want, jnp.int32(2), i == 16)
+        n = jnp.int32(p if 2 <= i < 20 else 0)  # two election rounds first
+        full_rounds += int((np.asarray(got.pending.kind)[..., 128:] != 0)
+                           .any())
+        got, uptos, _ = sharded_run(cfg, g, p, 1, got, n, jnp.int32(0),
+                                    jnp.int32(i), jnp.int32(seed), None, 256)
+        ext = make_propose_ext(cfg, g, p, n, jnp.int32(0), jnp.int32(i),
+                               jnp.int32(seed), 256)
+        want, _, _, _ = sharded_step(cfg, want, ext)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"round {i}")
+    assert 0 < full_rounds < 24  # both kernel tiers ran
+    assert len(got.states.ballot.sharding.device_set) == len(jax.devices())
+    assert (np.asarray(uptos) >= 18 * p - 1).all()

@@ -330,6 +330,7 @@ def test_lowered_pod_round_carries_step_route_and_scan_scopes():
     args = (cfg, g, 8, 2, ss, jax.numpy.full((g, cfg.window), -1, "int32"),
             jax.numpy.zeros(sharded.LATENCY_BINS, "int32"),
             jax.numpy.full((4, N_TEL_FIELDS), -1, "int32"),
+            jax.numpy.zeros(3, "int32"),
             *(jax.numpy.int32(x) for x in (8, 0, 2, 1)))
     text = sharded.sharded_run_resident.lower(*args).as_text(debug_info=True)
     # the pod drops the step's exec results, so the sections that only

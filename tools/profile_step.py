@@ -292,13 +292,13 @@ def resident_decompose(g: int = 2, w: int = 1024, p: int = 256,
     def dispatch_async():
         out = sharded_run_resident(
             sc.cfg, sc.n_shards, sc.ext_rows, k, sc.ss, sc._inject_round,
-            sc._lat_hist, sc._telemetry, jnp.int32(p), jnp.int32(sc.leader),
-            jnp.int32(sc._seed), jnp.int32(sc.seed), sc._step_impl,
-            sc.key_space, 1, jnp.int32(sc._tel_base))
-        (sc.ss, sc._inject_round, sc._lat_hist,
-         sc._telemetry) = out[0], out[1], out[2], out[3]
+            sc._lat_hist, sc._telemetry, sc._tiers, jnp.int32(p),
+            jnp.int32(sc.leader), jnp.int32(sc._seed), jnp.int32(sc.seed),
+            sc._step_impl, sc.key_space, 1, jnp.int32(sc._tel_base))
+        (sc.ss, sc._inject_round, sc._lat_hist, sc._telemetry,
+         sc._tiers) = out[:5]
         sc._seed += k
-        return out[4], out[5]
+        return out[5], out[6]
 
     legs = []
     for _ in range(iters):

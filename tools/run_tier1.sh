@@ -46,9 +46,8 @@ env JAX_PLATFORMS=cpu python tools/mc.py --smoke || exit 1
 # autotuner picks a winner (PERF.md resident-loop section). The second
 # point runs with OCCUPANCY-ADAPTIVE capacity on (PR 11): its inbox is
 # derived from the first point's measured occupancy high-water mark
-# (paxray TEL_INBOX_HWM, read on the sanctioned post-window path) with
-# the kernel inbox compacted to it, and must additionally be LOSSLESS
-# (no proposal dropped) — still exactly two compiled dispatch
+# (paxray TEL_INBOX_HWM, read on the sanctioned post-window path), and
+# must additionally be LOSSLESS (no proposal dropped) — still exactly two compiled dispatch
 # variants. Budgeted <= 60 s including the jit compile of both.
 echo "== shape-ladder smoke (2-point resident-loop sweep, drain-exact) =="
 env JAX_PLATFORMS=cpu python tools/shape_ladder.py --smoke || exit 1

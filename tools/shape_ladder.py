@@ -61,11 +61,11 @@ from minpaxos_tpu.parallel.sharded import ShardedCluster  # noqa: E402
 
 
 def point_config(protocol: str, w: int, p: int, inbox: int | None = None,
-                 compact: int = 0, q1: int = 0, q2: int = 0) -> MinPaxosConfig:
+                 q1: int = 0, q2: int = 0) -> MinPaxosConfig:
     cu = cpu_catchup_rows(p, fault=False)
     kw = dict(n_replicas=5, window=w, inbox=p + 2 * cu + 64 + 64,
               exec_batch=p, kv_pow2=cpu_kv_pow2(p), catchup_rows=cu,
-              recovery_rows=64, compact_inbox=compact, q1=q1, q2=q2)
+              recovery_rows=64, q1=q1, q2=q2)
     if protocol == "classic":
         if inbox is not None:
             kw["inbox"] = inbox
@@ -84,17 +84,17 @@ def point_config(protocol: str, w: int, p: int, inbox: int | None = None,
 def adaptive_capacity(hwm: int) -> int:
     """Occupancy-derived inbox capacity: the measured delivered-rows
     high-water mark (paxray TEL_INBOX_HWM) plus 25% headroom, rounded
-    up to 32 rows. Both the routing capacity (cfg.inbox) and the
-    compacted kernel inbox (cfg.compact_inbox) take this one number —
-    below it a point LOSES proposals, which the lossless check
-    rejects."""
+    up to 32 rows. The routing capacity (cfg.inbox) takes this number
+    — below it a point LOSES proposals, which the lossless check
+    rejects. (The kernel's rows are chosen on the device each round:
+    parallel/sharded.py ``sharded_round``.)"""
     return max(64, ((hwm + hwm // 4 + 8 + 31) // 32) * 32)
 
 
 def measure_point(protocol: str, g: int, w: int, p: int, k: int,
                   dispatches: int = 3, key_space: int | None = None,
                   shard_devices: int = 1, seed: int = 0,
-                  inbox: int | None = None, compact: int = 0,
+                  inbox: int | None = None,
                   q1: int = 0, q2: int = 0) -> dict:
     """Time the resident loop at one (g, w, p, k) point: warm one
     dispatch, run ``dispatches`` back-to-back (two-scalar readbacks
@@ -105,13 +105,12 @@ def measure_point(protocol: str, g: int, w: int, p: int, k: int,
     readback (the sanctioned once-after-the-measured-window path)
     yields the point's delivered-occupancy high-water mark
     (``occupancy_hwm``), which seeds the adaptive-capacity axis —
-    ``inbox``/``compact`` override the default capacity with an
+    ``inbox`` overrides the default capacity with an
     occupancy-derived one. ``lossless`` pins that no proposal was
     dropped (total commits == total injected; minpaxos/classic only —
     Mencius frontiers count SKIP no-op slots, so drained_exact is its
     contract)."""
-    cfg = point_config(protocol, w, p, inbox=inbox, compact=compact,
-                       q1=q1, q2=q2)
+    cfg = point_config(protocol, w, p, inbox=inbox, q1=q1, q2=q2)
     if key_space is None:
         key_space = cpu_key_space(p)
     mesh = None
@@ -159,8 +158,7 @@ def measure_point(protocol: str, g: int, w: int, p: int, k: int,
         "q2": cfg.quorum2,
         "catchup_rows": cfg.catchup_rows,
         "inbox": cfg.inbox,
-        "compact_inbox": cfg.compact_inbox,
-        "adaptive": inbox is not None or compact > 0,
+        "adaptive": inbox is not None,
         "inst_per_sec": round(measured / wall, 1),
         "ms_per_round": round(wall / (dispatches * k) * 1e3, 3),
         "committed": int(measured),
@@ -220,8 +218,7 @@ def sweep(protocol: str = "minpaxos", budget_s: float = 900.0,
     """Measure the grid, then — ``adaptive`` — re-measure the best
     base point with its inbox capacity derived from the MEASURED
     occupancy high-water mark (telemetry TEL_INBOX_HWM ->
-    ``adaptive_capacity``) and the kernel inbox compacted to the same
-    rows (cfg.compact_inbox). The swept axis the PR-11 tentpole adds:
+    ``adaptive_capacity``). The swept axis the PR-11 tentpole adds:
     branch-free kernels cost ∝ capacity, so occupancy-fit capacity is
     a direct throughput lever; a lossy point (dropped proposals) is
     rejected by ``_legal``."""
@@ -230,13 +227,11 @@ def sweep(protocol: str = "minpaxos", budget_s: float = 900.0,
         protocol, jax.device_count())
     results, dropped = [], []
 
-    def run_point(g, w, p, k, sd, inbox=None, compact=0, derived=None,
-                  q1=0, q2=0):
+    def run_point(g, w, p, k, sd, inbox=None, derived=None, q1=0, q2=0):
         try:
             rec = measure_point(protocol, g, w, p, k,
                                 dispatches=dispatches, shard_devices=sd,
-                                seed=seed, inbox=inbox, compact=compact,
-                                q1=q1, q2=q2)
+                                seed=seed, inbox=inbox, q1=q1, q2=q2)
         except Exception as e:  # noqa: BLE001 — a too-big point must
             # not kill the sweep; the failure is recorded, not hidden
             rec = {"protocol": protocol, "g": g, "w": w, "p": p, "k": k,
@@ -263,8 +258,7 @@ def sweep(protocol: str = "minpaxos", budget_s: float = 900.0,
             if cap < best["inbox"] + best["p"]:  # else nothing to gain
                 rec = run_point(best["g"], best["w"], best["p"],
                                 best["k"], best["shard_devices"],
-                                inbox=cap, compact=cap,
-                                derived=best["occupancy_hwm"])
+                                inbox=cap, derived=best["occupancy_hwm"])
                 # capacity-attributable loss check: same workload
                 # schedule as the base run, so equal committed totals
                 # mean the tighter capacity dropped nothing even on
@@ -301,7 +295,6 @@ def sweep(protocol: str = "minpaxos", budget_s: float = 900.0,
             rec = run_point(
                 sw["g"], sw["w"], sw["p"], sw["k"], sw["shard_devices"],
                 inbox=sw["inbox"] if sw.get("adaptive") else None,
-                compact=sw.get("compact_inbox", 0),
                 q1=pair[0], q2=pair[1])
             # same workload schedule as the winner's run: equal
             # committed totals mean the pair dropped nothing
@@ -333,8 +326,7 @@ def smoke() -> int:
     """CI gate (tools/run_tier1.sh): two tiny points through the full
     resident path — a fixed base point, then a g=2 point whose inbox
     capacity is DERIVED from the base point's measured occupancy
-    high-water mark with the kernel inbox compacted to it (the PR-11
-    adaptive-capacity path). Contract: commits flow, every point
+    high-water mark (the PR-11 adaptive-capacity path). Contract: commits flow, every point
     drains exactly, the adaptive point is LOSSLESS (occupancy-fit
     capacity dropped nothing), and the latency sample is complete.
     Still exactly two compiled dispatch variants; budget <=60s after
@@ -358,8 +350,7 @@ def smoke() -> int:
     if not base.get("error") and base.get("occupancy_hwm", 0) > 0:
         cap = adaptive_capacity(base["occupancy_hwm"])
         points.append(_point("minpaxos", 2, w, p, k, dispatches=2,
-                             shard_devices=sd, inbox=cap,
-                             compact=cap))
+                             shard_devices=sd, inbox=cap))
     else:
         print(f"FAIL: base point unusable (no occupancy readback): {base}")
         ok = False
@@ -388,8 +379,8 @@ def smoke() -> int:
               f"g={winner['g']} w={winner['w']} p={winner['p']} "
               f"k={winner['k']} ({winner['inst_per_sec']:.0f} inst/s); "
               f"adaptive point: hwm={base['occupancy_hwm']} -> "
-              f"inbox={adapt['inbox']} (compacted, was "
-              f"{base['inbox']}+{p} ext), lossless+drain-exact; "
+              f"inbox={adapt['inbox']} (was {base['inbox']}), "
+              f"lossless+drain-exact; "
               f"{wall:.1f}s wall ({post_compile:.1f}s post-compile)")
     else:
         print("shape-ladder smoke: FAILED")
