@@ -315,6 +315,44 @@ TPU_SHAPE = (256, 4096, 512, 32)
 CPU_SHAPE = (8, 512, 64, 8)
 
 
+def side_shapes(on_tpu: bool) -> dict:
+    """BASELINE side configs 2-4 as ``name -> (cfg, shards, proposals
+    per round [per owner under mencius], rounds per dispatch,
+    protocol)`` — the one statement of them the program has (the
+    benchmark's ``mencius5_pod_64k`` is held to the last)."""
+    from minpaxos_tpu.models.minpaxos import MinPaxosConfig
+    from minpaxos_tpu.models.paxos import classic_config
+
+    return {
+        # cfg2: classic paxos, 1 client, sequential instances
+        # (1 proposal per round — pipelined-sequential)
+        "paxos_sequential": (
+            classic_config(n_replicas=5, window=1024, inbox=256,
+                           exec_batch=32, kv_pow2=12,
+                           catchup_rows=32, recovery_rows=32),
+            1, 1, 128 if on_tpu else 32, "classic"),
+        # cfg3: classic paxos, 16 clients (=16 shards), 64k
+        # concurrent instances (inbox: p + appendices — acks are
+        # run-length compressed)
+        "paxos_64k": (
+            classic_config(n_replicas=5, window=4096,
+                           inbox=256 + 2 * 64 + 128, exec_batch=256,
+                           kv_pow2=14, catchup_rows=64,
+                           recovery_rows=64),
+            16, 256, 32 if on_tpu else 8, "classic"),
+        # cfg4: mencius, 5 rotating owners, 64k instances
+        # catchup_rows = the per-step COMMIT-broadcast chunk in the
+        # mencius kernel; must exceed the per-owner proposal rate
+        # (64/round) or the frontier can never drain its backlog
+        "mencius_64k": (
+            MinPaxosConfig(n_replicas=5, window=4096,
+                           inbox=2048, exec_batch=320,
+                           kv_pow2=14, catchup_rows=128,
+                           recovery_rows=64, noop_delay=8),
+            16, 64, 32 if on_tpu else 8, "mencius"),
+    }
+
+
 def headline_config(on_tpu: bool, w: int, p: int, do_fault: bool = True,
                     inbox: int = 0, q1: int = 0, q2: int = 0):
     """(cfg, key_space) of the headline MinPaxos N=5 run at window
@@ -787,38 +825,8 @@ def measure(shape: tuple[int, int, int, int] | None = None,
 
     # -- BASELINE side configs 2-4 (config 1, the TCP runtime, is
     # measured separately: bench_tcp.py writes BENCH_TCP.json) --
-    from minpaxos_tpu.models.paxos import classic_config
-
-    side_shapes = {
-        # cfg2: classic paxos, 1 client, sequential instances
-        # (1 proposal per round — pipelined-sequential)
-        "paxos_sequential": (
-            classic_config(n_replicas=5, window=1024, inbox=256,
-                           exec_batch=32, kv_pow2=12,
-                           catchup_rows=32, recovery_rows=32),
-            1, 1, 128 if on_tpu else 32, "classic"),
-        # cfg3: classic paxos, 16 clients (=16 shards), 64k
-        # concurrent instances (inbox: p + appendices — acks are
-        # run-length compressed)
-        "paxos_64k": (
-            classic_config(n_replicas=5, window=4096,
-                           inbox=256 + 2 * 64 + 128, exec_batch=256,
-                           kv_pow2=14, catchup_rows=64,
-                           recovery_rows=64),
-            16, 256, 32 if on_tpu else 8, "classic"),
-        # cfg4: mencius, 5 rotating owners, 64k instances
-        # catchup_rows = the per-step COMMIT-broadcast chunk in the
-        # mencius kernel; must exceed the per-owner proposal rate
-        # (64/round) or the frontier can never drain its backlog
-        "mencius_64k": (
-            MinPaxosConfig(n_replicas=5, window=4096,
-                           inbox=2048, exec_batch=320,
-                           kv_pow2=14, catchup_rows=128,
-                           recovery_rows=64, noop_delay=8),
-            16, 64, 32 if on_tpu else 8, "mencius"),
-    }
     result["configs"] = {}
-    for name, (scfg, sg, sp, sk, proto) in side_shapes.items():
+    for name, (scfg, sg, sp, sk, proto) in side_shapes(on_tpu).items():
         t0 = time.perf_counter()
         result["configs"][name] = _side_config(scfg, sg, sp, sk, proto)
         _progress(f"config {name} {time.perf_counter() - t0:.0f}s")

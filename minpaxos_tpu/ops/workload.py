@@ -35,6 +35,19 @@ the bench shape when ~9% of a round's keys collided — PERF.md), and a
 workload generator must not smuggle a kernel pathology into the
 headline number; key-conflict behavior is a knob for the TCP client's
 ``gen_workload(conflict_pct=...)``, not an accident of the PRNG.
+
+Multi-owner stream (Mencius, ``owners`` below): every replica owns
+slots and serves its OWN clients (the upstream client's ``-e`` mode,
+round-robin over all replicas), so owner o of a shard is proposed rows
+that are a pure function of (seed, round, shard, owner, row). The
+Threefry counter gains the owner (``shard * R + owner``, row); keys
+walk the owner's own range ``owner * keys_per_owner + walk`` with
+``keys_per_owner = owner_key_range(key_space, R)``, a power of two —
+owner-disjoint, the upstream client's default of 0 % conflicts, so a
+key's final value is the last PUT of its one owner's stream whatever
+interleaving the protocol picks; ``cmd_id = (round * rows + row) * R +
+owner`` is unique across owners and ``client_id = shard * R + owner``.
+The single-leader stream above is unchanged.
 """
 
 from __future__ import annotations
@@ -54,6 +67,14 @@ _PARITY = 0x1BD11BDA  # key-schedule parity constant
 # masked walk is a bijection on the power-of-two key space, so a
 # round's keys are distinct whenever ext_rows <= key_space
 _KEY_STRIDE = 2654435761
+
+
+def owner_key_range(key_space: int, n_owners: int) -> int:
+    """``keys_per_owner`` of the multi-owner stream: the largest power
+    of two that gives each of ``n_owners`` owners a range of its own
+    inside the power-of-two ``key_space`` (8,192 over 5 owners: 1,024
+    each, keys 0..5,119)."""
+    return key_space >> (n_owners - 1).bit_length()
 
 
 def threefry2x32(k0, k1, c0, c1):
@@ -105,7 +126,7 @@ def threefry2x32_host(k0, k1, c0, c1):
 
 def workload_lanes(n_shards: int, ext_rows: int, round_idx, seed,
                    key_space: int = 1 << 20, hot_pct: int = 0,
-                   hot_keys: int = 8):
+                   hot_keys: int = 8, owners: int = 0):
     """(key, val) int32 lanes for ``round_idx`` — a scalar (one round,
     [G, M]) or a [k] vector (all of a fused dispatch's rounds at once,
     [k, G, M]). The fused runners pass the VECTOR form and hoist this
@@ -125,7 +146,15 @@ def workload_lanes(n_shards: int, ext_rows: int, round_idx, seed,
     from an INDEPENDENT Threefry counter block (shard + n_shards) so
     the redirect decision never correlates with the value lane. The
     knob is Python-gated: at the default 0 the traced graph and the
-    emitted stream are byte-identical to the pinned golden digests."""
+    emitted stream are byte-identical to the pinned golden digests.
+
+    ``owners`` (static; the replica count R, or 0): the multi-owner
+    stream of the module docstring — lanes gain an owner axis,
+    [G, R, M] or [k, G, R, M]."""
+    # paxlint: disable=trace-hazard -- `owners` is a static int
+    if owners:
+        return _owner_lanes(n_shards, ext_rows, round_idx, seed, key_space,
+                            hot_pct, owners)
     r = jnp.asarray(round_idx, jnp.int32)[..., None, None]
     b0, b1 = threefry2x32(seed, r,
                           jnp.arange(n_shards, dtype=jnp.int32)[:, None],
@@ -145,18 +174,66 @@ def workload_lanes(n_shards: int, ext_rows: int, round_idx, seed,
     return key, b1.astype(jnp.int32)
 
 
+def _owner_lanes(n_shards, ext_rows, round_idx, seed, key_space, hot_pct,
+                 owners):
+    """``workload_lanes`` of the multi-owner stream: [..., G, R, M]."""
+    if hot_pct:
+        raise ValueError("the multi-owner stream has no hot-key knob")
+    r = jnp.asarray(round_idx, jnp.int32)[..., None, None, None]
+    own = jnp.arange(owners, dtype=jnp.int32)[:, None]
+    b0, b1 = threefry2x32(
+        seed, r,
+        jnp.arange(n_shards, dtype=jnp.int32)[:, None, None] * owners + own,
+        jnp.arange(ext_rows, dtype=jnp.int32))
+    per_owner = owner_key_range(key_space, owners)
+    walk = ((b0[..., :1]
+             + jnp.arange(ext_rows, dtype=jnp.uint32) * jnp.uint32(_KEY_STRIDE))
+            & jnp.uint32(per_owner - 1)).astype(jnp.int32)
+    return own * per_owner + walk, b1.astype(jnp.int32)
+
+
+def _propose_rows(active, key, val, cmd_id, client_id) -> MsgBatch:
+    """PUT PROPOSE rows where ``active``; every argument [G, R, M] or
+    broadcastable to it."""
+    z = jnp.zeros(active.shape, jnp.int32)
+    return MsgBatch(
+        kind=jnp.where(active, int(MsgKind.PROPOSE), 0).astype(jnp.int32),
+        src=jnp.full(active.shape, -1, jnp.int32),
+        ballot=z,
+        inst=z,
+        last_committed=z,
+        op=jnp.where(active, int(Op.PUT), 0).astype(jnp.int32),
+        key_hi=z,
+        key_lo=jnp.where(active, key, 0),
+        val_hi=z,
+        val_lo=jnp.where(active, val, 0),
+        cmd_id=jnp.where(active, cmd_id, 0),
+        client_id=jnp.where(active, client_id, 0),
+    )
+
+
 def assemble_batch(n_replicas: int, n_shards: int, ext_rows: int,
                    count, leader, round_idx, key, val) -> MsgBatch:
-    """One round's [G, R, M] PROPOSE rows from precomputed [G, M]
-    key/val lanes. ``count`` rows per shard are live, addressed to
-    ``leader`` (or to EVERY replica when leader < 0 — the Mencius
-    multi-owner workload, each owner serving its own clients). Cheap
-    by construction (~10 broadcast selects), so it is the only
-    workload code traced inside the scan body."""
+    """One round's [G, R, M] PROPOSE rows from precomputed key/val
+    lanes. [G, M] lanes are the single-leader stream: ``count`` rows
+    per shard are live, addressed to ``leader``. [G, R, M] lanes are
+    the multi-owner stream (Mencius; a trace-time choice by the lanes'
+    rank): each owner is proposed its own rows, ``count`` of them
+    (a scalar, or [R] with one count per owner — an owner at 0 idles),
+    every owner when leader < 0, else owner ``leader`` alone. Cheap by
+    construction (~10 broadcast selects), so it is the only workload
+    code traced inside the scan body."""
     g, r, m = n_shards, n_replicas, ext_rows
     shard = jnp.arange(g, dtype=jnp.int32)[:, None, None]
     rep = jnp.arange(r, dtype=jnp.int32)[None, :, None]
     col = jnp.arange(m, dtype=jnp.int32)[None, None, :]
+    if key.ndim == 3:
+        active = jnp.broadcast_to(
+            ((rep == leader) | (leader < 0))
+            & (col < jnp.reshape(count, (1, -1, 1))), (g, r, m))
+        return _propose_rows(active, key, val,
+                             (round_idx * m + col) * r + rep,
+                             shard * r + rep)
     active = jnp.broadcast_to(
         ((rep == leader) | (leader < 0)) & (col < count), (g, r, m))
     z = jnp.zeros((g, r, m), jnp.int32)
@@ -179,18 +256,24 @@ def assemble_batch(n_replicas: int, n_shards: int, ext_rows: int,
 def propose_batch(n_replicas: int, n_shards: int, ext_rows: int,
                   count, leader, round_idx, seed,
                   key_space: int = 1 << 20, hot_pct: int = 0,
-                  hot_keys: int = 8) -> MsgBatch:
+                  hot_keys: int = 8, owners: bool | None = None) -> MsgBatch:
     """[G, R, M] PROPOSE rows for one protocol round, generated on
     device (``workload_lanes`` + ``assemble_batch``). ``key_space``
     must be a power of two and at or below half the KV capacity so
     long runs don't saturate the table. ``hot_pct``/``hot_keys``:
     the Python-gated hot-key-skew knob (see ``workload_lanes``).
+    ``owners`` (static): the multi-owner stream; None reads it off a
+    concrete ``leader < 0`` (a caller that traces ``leader`` says).
 
     Pure jnp: callers jit it directly (parallel/sharded.py
     ``make_propose_ext``) or trace it inside a fused scan."""
+    if owners is None:
+        # paxlint: disable=trace-hazard -- a concrete leader only (a traced one raises: its caller passes `owners`)
+        owners = bool(leader < 0)
     key, val = workload_lanes(n_shards, ext_rows, round_idx, seed,
                               key_space, hot_pct=hot_pct,
-                              hot_keys=hot_keys)
+                              hot_keys=hot_keys,
+                              owners=n_replicas if owners else 0)
     return assemble_batch(n_replicas, n_shards, ext_rows, count, leader,
                           round_idx, key, val)
 
@@ -198,12 +281,18 @@ def propose_batch(n_replicas: int, n_shards: int, ext_rows: int,
 def propose_batch_host(n_replicas: int, n_shards: int, ext_rows: int,
                        count: int, leader: int, round_idx: int, seed: int,
                        key_space: int = 1 << 20, hot_pct: int = 0,
-                       hot_keys: int = 8) -> MsgBatch:
+                       hot_keys: int = 8,
+                       owners: bool | None = None) -> MsgBatch:
     """The host injector: NumPy twin of ``propose_batch``, row-for-row
-    and byte-for-byte identical from the same (seed, round). This is
-    what ``BENCH_RESIDENT=0`` feeds the cluster from the host, and the
-    reference the on-device generator is proven against."""
+    and byte-for-byte identical from the same (seed, round), in both
+    streams (``owners`` as there; ``count`` may be [R] in the
+    multi-owner one). This is what ``BENCH_RESIDENT=0`` feeds the
+    cluster from the host, and the reference the on-device generator
+    is proven against."""
     g, r, m = n_shards, n_replicas, ext_rows
+    if leader < 0 if owners is None else owners:
+        return _propose_batch_owners_host(g, r, m, count, leader, round_idx,
+                                          seed, key_space, hot_pct)
     shard = np.arange(g, dtype=np.int32)[:, None, None]
     rep = np.arange(r, dtype=np.int32)[None, :, None]
     col = np.arange(m, dtype=np.int32)[None, None, :]
@@ -241,4 +330,41 @@ def propose_batch_host(n_replicas: int, n_shards: int, ext_rows: int,
         val_lo=np.where(active, np.broadcast_to(val, (g, r, m)), z),
         cmd_id=np.where(active, np.broadcast_to(cmd, (g, r, m)), z),
         client_id=np.where(active, np.broadcast_to(shard, (g, r, m)), z),
+    )
+
+
+def _propose_batch_owners_host(g, r, m, count, leader, round_idx, seed,
+                               key_space, hot_pct) -> MsgBatch:
+    """``propose_batch_host`` of the multi-owner stream (the twin of
+    ``_owner_lanes`` + ``assemble_batch``'s [G, R, M] branch)."""
+    if hot_pct:
+        raise ValueError("the multi-owner stream has no hot-key knob")
+    shard = np.arange(g, dtype=np.int32)[:, None, None]
+    rep = np.arange(r, dtype=np.int32)[None, :, None]
+    col = np.arange(m, dtype=np.int32)[None, None, :]
+    active = np.broadcast_to(
+        ((rep == leader) | (leader < 0))
+        # paxlint: disable=trace-hazard -- host-side twin (NumPy by design)
+        & (col < np.reshape(np.asarray(count, np.int32), (1, -1, 1))),
+        (g, r, m))
+    b0, b1 = threefry2x32_host(seed, round_idx, shard * r + rep, col)
+    per_owner = owner_key_range(key_space, r)
+    with np.errstate(over="ignore"):
+        walk = ((b0[..., :1] + col.astype(np.uint32) * np.uint32(_KEY_STRIDE))
+                & np.uint32(per_owner - 1)).astype(np.int32)
+        cmd = (np.int32(round_idx) * np.int32(m) + col) * np.int32(r) + rep
+    z = np.zeros((g, r, m), np.int32)
+    return MsgBatch(
+        kind=np.where(active, np.int32(int(MsgKind.PROPOSE)), z),
+        src=np.full((g, r, m), -1, np.int32),
+        ballot=z,
+        inst=z,
+        last_committed=z,
+        op=np.where(active, np.int32(int(Op.PUT)), z),
+        key_hi=z,
+        key_lo=np.where(active, rep * per_owner + walk, z),
+        val_hi=z,
+        val_lo=np.where(active, b1.astype(np.int32), z),
+        cmd_id=np.where(active, cmd, z),
+        client_id=np.where(active, shard * r + rep, z),
     )

@@ -57,6 +57,7 @@ from minpaxos_tpu.ops.workload import (
     propose_batch,
     workload_lanes,
 )
+from minpaxos_tpu.wire.messages import Op
 
 #: round-latency histogram resolution for the resident runner: bins are
 #: exact integer round latencies 1..LATENCY_BINS-1, last bin = overflow
@@ -78,7 +79,14 @@ DONATION = {
     # read-only probes — donating would consume live state:
     "commit_totals": False,
     "shard_cursors": False,
+    "count_commands": False,
 }
+
+#: the command counts of a multi-owner (Mencius) pod, int32[3],
+#: cumulative from boot: client commands the cursor replica's merged
+#: frontier has passed, no-op slots (ceded, skipped or taken over) it
+#: has passed, client commands their owners have assigned a slot
+N_COUNTS = 3
 
 
 def _init_sharded(cfg: MinPaxosConfig, n_shards: int,
@@ -134,6 +142,33 @@ def working_capacity(cfg: MinPaxosConfig, ext_rows: int) -> int:
     return min(cfg.inbox, 128 * max(1, -(-4 * ext_rows // 128)))
 
 
+def small_tier_rows(cfg: MinPaxosConfig, ext_rows: int, owners: bool) -> int:
+    """The small tier of a round for this protocol: ``working_capacity``
+    and, for a multi-owner pod (Mencius), no less than its own healthy
+    occupancy — a static function of shapes and protocol.
+
+    Under rotating ownership an inbox holds four peers' traffic at
+    once: each of the R-1 peers sends p ACCEPTs, the p slots it
+    committed last round as COMMIT rows, one run-compressed ack and at
+    most one SKIP row, and ``cfg.catchup_rows`` committed slots to the
+    peer whose reported frontier trails most (models/mencius.py
+    section 9d: every peer picks the same one, every round). That is
+    (R-1) x (2p + catchup_rows + 2), with p <= ``ext_rows`` per owner,
+    rounded up to 128 lanes: 1,152 of 2,048 at BASELINE config 4, where
+    the fullest inbox reads 1,028 in every loaded round (PERF.md, PR
+    28) and 3p + 1 = 256 never held it. Where even that does not fit
+    under ``cfg.inbox`` the configured inbox is below the protocol's
+    healthy traffic and no capacity lies clear of it: the single-leader
+    guess stays (it still engages in part of such a run's rounds)."""
+    rows = working_capacity(cfg, ext_rows)
+    if owners:
+        healthy = (cfg.n_replicas - 1) * (2 * ext_rows + cfg.catchup_rows + 2)
+        healthy = 128 * -(-healthy // 128)
+        if healthy < cfg.inbox:
+            rows = max(rows, healthy)
+    return rows
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def _step_groups(cfg: MinPaxosConfig, step, rows: int, ss: ClusterState,
                  ext: MsgBatch):
@@ -174,7 +209,7 @@ def _pretrace_kernels(cfg: MinPaxosConfig, step, ss: ClusterState,
         lambda x: jax.ShapeDtypeStruct(ss.alive.shape + (ext_rows,), x.dtype),
         MsgBatch.empty(1))
     tiers = {cfg.inbox} if cfg.route_fabric == "dense" else {
-        working_capacity(cfg, ext_rows), cfg.inbox}
+        small_tier_rows(cfg, ext_rows, _has_owners(ss.states)), cfg.inbox}
     for rows in tiers:
         _step_groups.trace(cfg, step, rows, ss, ext)
 
@@ -289,7 +324,7 @@ def elect_all(cfg: MinPaxosConfig, ss: ClusterState, leader: int):
     return jax.vmap(one)(ss)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 7))
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 7, 8))
 def make_propose_ext(
     cfg: MinPaxosConfig,
     n_shards: int,
@@ -299,6 +334,7 @@ def make_propose_ext(
     round_idx,
     seed=0,
     key_space: int = 1 << 20,
+    owners: bool = False,
 ) -> MsgBatch:
     """Device-generated client workload: `count` PUT rows per shard,
     addressed to the leader replica — the TPU equivalent of the
@@ -307,17 +343,93 @@ def make_propose_ext(
     ops/workload.py (Threefry-2x32 keyed on (seed, round), countered
     on (shard, row)) so the resident scan, this jitted entry point,
     and the NumPy host injector all draw the same byte-identical
-    stream."""
+    stream. ``owners``: the multi-owner stream (Mencius), every owner
+    its own rows."""
     return propose_batch(cfg.n_replicas, n_shards, ext_rows, count,
-                         leader, round_idx, seed, key_space)
+                         leader, round_idx, seed, key_space, owners=owners)
+
+
+def _has_owners(states) -> bool:
+    """Every replica owns slots and proposes (Mencius): read off the
+    state's structure at trace time, as ``has_prepared`` is, so the
+    single-leader programs are what they were."""
+    return getattr(states, "crt_own", None) is not None
+
+
+def _owner_cursors(states, cursor_rep):
+    """What ``_count_round`` needs of the state BEFORE a round."""
+    return states.committed_upto[:, cursor_rep], states.crt_own
+
+
+def _count_round(cfg: MinPaxosConfig, cursor_rep, before, states, counts,
+                 r=None, inj=None, hist=None):
+    """One round of a multi-owner pod, in COMMANDS: (counts', inj',
+    hist') — ``counts`` is ``N_COUNTS``; ``inj`` and ``hist`` are the
+    latency ring and histogram of ``sharded_run_resident``, or None.
+
+    Under rotating ownership the merged log interleaves five owners'
+    slots and holds no-ops (slots an owner ceded, a peer's SKIP
+    covered, a takeover filled), so the frontier's slot number counts
+    no commands and no one cursor says what was assigned. Read instead:
+
+    * assigned: owner o's own slots in [crt_own before, crt_own after)
+      that hold a command in o's OWN log (what it proposed into; the
+      rest of that span it ceded);
+    * committed: the slots (u_prev, u_new] the cursor replica's
+      frontier passed this round, split by the op its log holds there
+      into commands and no-op slots. They are still in its window: a
+      round's frontier moves by at most window - retention slots and
+      the window keeps ``retention`` = window / 2 behind the executed
+      prefix (models/mencius.py section 12);
+    * latency, per command: rounds from the round its owner assigned
+      its slot (stamped on the ring for every slot of the span above)
+      to the round the cursor's MERGED frontier passed it, which waits
+      on every owner's earlier slots. -1 stamps (assigned before the
+      ring was armed) stay out of the sample.
+
+    The ring is anchored at the cursor's u_prev + 1 and cannot alias
+    while every owner's cursor stays within a window of it."""
+    u_prev, co_prev = before
+    w, n = cfg.window, cfg.n_replicas
+    idx = jnp.arange(w, dtype=jnp.int32)
+    co_new = states.crt_own
+    noop = jnp.uint8(int(Op.NONE))
+    slot_own = states.window_base[..., None] + idx           # [G, R, W]
+    assigned = ((slot_own >= co_prev[..., None])
+                & (slot_own < co_new[..., None])
+                & (jnp.mod(slot_own, n)
+                   == jnp.arange(n, dtype=jnp.int32)[:, None])
+                & (states.op != noop)).sum(dtype=jnp.int32)
+    # ring position -> the one slot of [u_prev + 1, u_prev + 1 + W) there
+    a = (u_prev + 1)[:, None]
+    slot = a + jnp.mod(idx[None, :] - a, w)                  # [G, W]
+    u_new = states.committed_upto[:, cursor_rep]
+    passed = slot <= u_new[:, None]
+    rel = slot - states.window_base[:, cursor_rep][:, None]
+    op_there = jnp.take_along_axis(states.op[:, cursor_rep],
+                                   jnp.clip(rel, 0, w - 1), axis=1)
+    is_cmd = passed & (rel >= 0) & (rel < w) & (op_there != noop)
+    commands = is_cmd.sum(dtype=jnp.int32)
+    counts = counts + jnp.stack(
+        [commands, passed.sum(dtype=jnp.int32) - commands, assigned])
+    if inj is None:
+        return counts, None, None
+    owner = jnp.mod(slot, n)
+    inj = jnp.where(
+        (slot >= jnp.take_along_axis(co_prev, owner, axis=1))
+        & (slot < jnp.take_along_axis(co_new, owner, axis=1)), r, inj)
+    bins = jnp.clip(r - inj, 0, hist.shape[0] - 1)  # latency - 1
+    hist = hist.at[bins.reshape(-1)].add(
+        (is_cmd & (inj >= 0)).reshape(-1).astype(hist.dtype))
+    return counts, inj, hist
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 9, 10, 11),
-                   donate_argnums=4)
+                   donate_argnums=(4, 12))
 def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 k_rounds: int, ss: ClusterState, n_proposals, leader, round0,
                 seed=0, step_impl=None, key_space: int = 1 << 20,
-                substeps: int = 1):
+                substeps: int = 1, counts=None):
     """k protocol rounds in ONE dispatch via ``lax.scan``.
 
     The per-round host round-trip (dispatch + cursor reads) dominated
@@ -340,23 +452,34 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     measures whether wall-clock p50 wins at a given shape and reports
     whichever it measured (VERDICT round-4 item 5).
 
-    Returns (ss', uptos [k, G], crts [k, G]).
+    A multi-owner pod (Mencius; trace-time, ``_has_owners``) draws the
+    per-owner stream and carries ``counts`` (``N_COUNTS``, donated;
+    ``_count_round``); the cursor histories stay SLOTS of replica 0's
+    merged log, no-op slots included.
+
+    Returns (ss', uptos [k, G], crts [k, G]), and counts' after them
+    for a multi-owner pod.
     """
 
     step = replica_step_impl if step_impl is None else step_impl
     cursor_rep = jnp.maximum(leader, 0)  # mencius (-1): replica 0's view
+    owners = _has_owners(ss.states)
     cstep = functools.partial(sharded_round, cfg, step,
-                              working_capacity(cfg, ext_rows))
+                              small_tier_rows(cfg, ext_rows, owners))
     _pretrace_kernels(cfg, step, ss, ext_rows)
     ts = jnp.arange(k_rounds, dtype=jnp.int32)
     # PRNG lanes for ALL k rounds in one batched call, hoisted out of
     # the scan body (ops/workload.py workload_lanes: per-round tracing
     # of Threefry cost ~40 ms/dispatch in XLA-CPU op overhead)
     keys, vals = workload_lanes(n_shards, ext_rows, round0 + ts, seed,
-                                key_space)
+                                key_space,
+                                owners=cfg.n_replicas if owners else 0)
 
-    def body(ss, xs):
+    def body(carry, xs):
+        ss, counts = carry
         t, key_t, val_t = xs
+        if owners:
+            before = _owner_cursors(ss.states, cursor_rep)
         ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
                              n_proposals, leader, round0 + t, key_t, val_t)
         ss, _, _ = cstep(ss, ext)
@@ -368,22 +491,26 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         ext0 = jax.tree_util.tree_map(lambda x: x[..., :0], ext)
         for _ in range(substeps - 1):
             ss, _, _ = cstep(ss, ext0)
-        return ss, (ss.states.committed_upto[:, cursor_rep],
-                    ss.states.crt_inst[:, cursor_rep])
+        if owners:
+            counts, _, _ = _count_round(cfg, cursor_rep, before, ss.states,
+                                        counts)
+        return (ss, counts), (ss.states.committed_upto[:, cursor_rep],
+                              ss.states.crt_inst[:, cursor_rep])
 
-    ss, (uptos, crts) = jax.lax.scan(body, ss, (ts, keys, vals))
-    return ss, uptos, crts
+    (ss, counts), (uptos, crts) = jax.lax.scan(body, (ss, counts),
+                                               (ts, keys, vals))
+    return (ss, uptos, crts, counts) if owners else (ss, uptos, crts)
 
 
 # paxlint: resident-loop
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 13, 14, 15),
-                   donate_argnums=(4, 5, 6, 7, 8))
+                   donate_argnums=(4, 5, 6, 7, 8, 17))
 def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                          k_rounds: int, ss: ClusterState, inject_round,
                          lat_hist, telemetry, tiers, n_proposals, leader,
                          round0, seed=0, step_impl=None,
                          key_space: int = 1 << 20, substeps: int = 1,
-                         tel_base=0):
+                         tel_base=0, counts=None):
     """k rounds in ONE dispatch with nothing read back but two scalars.
 
     The fully device-resident measured loop (ISSUE 8): workload rows
@@ -423,16 +550,28 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
       of several sub-steps counts as small when each of them was).
       Read back after the window like the histogram.
 
+    A multi-owner pod (Mencius; a trace-time choice on the state's
+    structure, ``_has_owners``, so the single-leader program is
+    untouched) differs in three things. Each owner is proposed its OWN
+    rows (ops/workload.py, the multi-owner stream), ``n_proposals`` of
+    them, one count per owner ([R]; an owner at 0 idles and cedes).
+    ``counts`` (``N_COUNTS``, donated, cumulative from boot) is carried
+    and the two scalars are COMMANDS: committed (no-op slots apart)
+    and assigned but not committed. And ring and histogram are per
+    command, from its owner's assignment to the cursor replica's
+    merged frontier (``_count_round`` has the definitions).
+
     Returns (ss', inject_round', lat_hist', telemetry', tiers',
-    committed_total, in_flight) — the final two are the per-dispatch
-    scalar cursors (committed frontier for throughput progress,
-    assigned-but-uncommitted count for the drain loop's exactness
-    check).
+    committed_total, in_flight, counts' or None) — the two scalars are
+    the per-dispatch cursors (committed frontier for throughput
+    progress, assigned-but-uncommitted count for the drain loop's
+    exactness check).
     """
     step = replica_step_impl if step_impl is None else step_impl
     cursor_rep = jnp.maximum(leader, 0)
+    owners = _has_owners(ss.states)
     cstep = functools.partial(sharded_round, cfg, step,
-                              working_capacity(cfg, ext_rows))
+                              small_tier_rows(cfg, ext_rows, owners))
     _pretrace_kernels(cfg, step, ss, ext_rows)
     w = cfg.window
     pos = jnp.arange(w, dtype=jnp.int32)[None, :]  # [1, W] ring positions
@@ -441,18 +580,21 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     # all k rounds' PRNG lanes, hoisted out of the scan (see sharded_run)
     with jax.named_scope("px.workload"):
         keys, vals = workload_lanes(n_shards, ext_rows, round0 + ts, seed,
-                                    key_space)
+                                    key_space,
+                                    owners=cfg.n_replicas if owners else 0)
     # steady/election flag source: MinPaxos-family states carry
     # ``prepared`` [G, R]; Mencius has no elections (rotating
     # ownership), so every round is steady. Structural, trace-time.
     has_prepared = getattr(ss.states, "prepared", None) is not None
 
     def body(carry, xs):
-        ss, inj, hist, tel, tiers = carry
+        ss, inj, hist, tel, tiers, counts = carry
         t, key_t, val_t = xs
         r = round0 + t
         u_prev = ss.states.committed_upto[:, cursor_rep]
         c_prev = ss.states.crt_inst[:, cursor_rep]
+        if owners:
+            before = _owner_cursors(ss.states, cursor_rep)
         if tel_on:
             with jax.named_scope("px.telemetry"):
                 e_prev = ss.states.executed_upto[:, cursor_rep]
@@ -491,19 +633,23 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         with jax.named_scope("px.lat_hist"):
             u_new = ss.states.committed_upto[:, cursor_rep]
             c_new = ss.states.crt_inst[:, cursor_rep]
-            # stamp this round on slots assigned this round:
-            # [c_prev, c_new)
-            cp = c_prev[:, None]
-            slot = cp + jnp.mod(pos - cp, w)  # abs slot per ring position
-            inj = jnp.where(slot < c_new[:, None], r, inj)
-            # commit latencies for slots committed this round:
-            # [u_prev+1, u_new]
-            up = u_prev[:, None] + 1
-            cslot = up + jnp.mod(pos - up, w)
-            sampled = (cslot <= u_new[:, None]) & (inj >= 0)
-            bins = jnp.clip(r - inj, 0, hist.shape[0] - 1)  # latency-1
-            hist = hist.at[bins.reshape(-1)].add(
-                sampled.reshape(-1).astype(hist.dtype))
+            if owners:
+                counts, inj, hist = _count_round(
+                    cfg, cursor_rep, before, ss.states, counts, r, inj, hist)
+            else:
+                # stamp this round on slots assigned this round:
+                # [c_prev, c_new)
+                cp = c_prev[:, None]
+                slot = cp + jnp.mod(pos - cp, w)  # abs slot per ring pos
+                inj = jnp.where(slot < c_new[:, None], r, inj)
+                # commit latencies for slots committed this round:
+                # [u_prev+1, u_new]
+                up = u_prev[:, None] + 1
+                cslot = up + jnp.mod(pos - up, w)
+                sampled = (cslot <= u_new[:, None]) & (inj >= 0)
+                bins = jnp.clip(r - inj, 0, hist.shape[0] - 1)  # latency-1
+                hist = hist.at[bins.reshape(-1)].add(
+                    sampled.reshape(-1).astype(hist.dtype))
         if tel_on:
             with jax.named_scope("px.telemetry"):
                 prep = (ss.states.prepared[:, cursor_rep].sum(
@@ -514,8 +660,9 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 # owner in mencius mode) — cheaper than reducing
                 # ext.kind [G, R, M] on XLA-CPU, where per-op thunk
                 # cost is what the 2% obs_smoke overhead gate feels
-                injected = (n_shards * n_proposals
-                            * jnp.where(leader >= 0, 1, cfg.n_replicas))
+                injected = n_shards * ext_live.sum() if owners else (
+                    n_shards * n_proposals
+                    * jnp.where(leader >= 0, 1, cfg.n_replicas))
                 row = telemetry_row(
                     round_idx=r,
                     committed_delta=(u_new - u_prev).sum(),
@@ -530,15 +677,18 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 tel = jax.lax.dynamic_update_index_in_dim(
                     tel, row,
                     jnp.mod(r - tel_base, telemetry.shape[0]), 0)
-        return (ss, inj, hist, tel, tiers), None
+        return (ss, inj, hist, tel, tiers, counts), None
 
-    (ss, inject_round, lat_hist, telemetry, tiers), _ = jax.lax.scan(
-        body, (ss, inject_round, lat_hist, telemetry, tiers),
+    (ss, inject_round, lat_hist, telemetry, tiers, counts), _ = jax.lax.scan(
+        body, (ss, inject_round, lat_hist, telemetry, tiers, counts),
         (ts, keys, vals))
+    if owners:
+        return (ss, inject_round, lat_hist, telemetry, tiers,
+                counts[0], counts[2] - counts[0], counts)
     upto = ss.states.committed_upto[:, cursor_rep]
     crt = ss.states.crt_inst[:, cursor_rep]
     return (ss, inject_round, lat_hist, telemetry, tiers,
-            (upto + 1).sum(), (crt - 1 - upto).sum())
+            (upto + 1).sum(), (crt - 1 - upto).sum(), None)
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
@@ -553,9 +703,19 @@ def set_alive(cfg: MinPaxosConfig, ss: ClusterState, replica, value):
 def commit_totals(cfg: MinPaxosConfig, ss: ClusterState):
     """(total committed instances across shards at the leader-0 view,
     min committed_upto, max committed_upto) — the bench's progress
-    probe, one scalar transfer each."""
+    probe, one scalar transfer each. SLOTS of the log: under Mencius
+    they include no-op slots (``ShardedCluster.committed`` reports
+    commands there)."""
     upto = ss.states.committed_upto[:, 0]
     return (upto + 1).sum(), upto.min(), upto.max()
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def count_commands(cfg: MinPaxosConfig, before, ss: ClusterState, counts):
+    """``_count_round`` for a round stepped from the host
+    (``ShardedCluster.step``): counts' from the cursors read before it
+    (``_owner_cursors`` at replica 0) and the state after."""
+    return _count_round(cfg, 0, before, ss.states, counts)[0]
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
@@ -604,13 +764,26 @@ class ShardedCluster:
             self.leader = 0
         self.ss = init_sharded(cfg, n_shards, mesh, self._init_fn)
         self._seed = 0
+        # a multi-owner pod counts COMMANDS beside the log's slots
+        # (``N_COUNTS``), on the device, in every path that steps it
+        self._counts = self._counts_armed = None
+        if _has_owners(self.ss.states):
+            self._counts = self._replicated(jnp.zeros(N_COUNTS, jnp.int32))
         # what the resident loop leaves for a reader after the run
         # (obs.process_pods()): filled on the post-window path only
         self._pod = register_pod({
             "protocol": protocol, "n_shards": n_shards,
             "n_replicas": cfg.n_replicas, "inbox": cfg.inbox,
-            "working_capacity": working_capacity(cfg, ext_rows),
-            "tiers": None})
+            "working_capacity": small_tier_rows(
+                cfg, ext_rows, _has_owners(self.ss.states)),
+            "tiers": None, "command_commits": None, "noop_slots": None})
+
+    def _replicated(self, x):
+        """A cross-shard reduction's buffer: replicated on the mesh, to
+        match the dispatch's output sharding (``begin_resident``)."""
+        if self.mesh is None:
+            return x
+        return jax.device_put(x, NamedSharding(self.mesh, P()))
 
     def elect(self, leader: int = 0) -> None:
         if self.protocol == "mencius":
@@ -621,11 +794,15 @@ class ShardedCluster:
         self.step(0)  # deliver replies -> leader prepared
 
     def step(self, n_proposals: int) -> None:
+        owners = self._counts is not None
         ext = make_propose_ext(
             self.cfg, self.n_shards, self.ext_rows,
             jnp.int32(min(n_proposals, self.ext_rows)),
             jnp.int32(self.leader), jnp.int32(self._seed),
-            jnp.int32(self.seed), self.key_space)
+            jnp.int32(self.seed), self.key_space, owners)
+        if owners:  # copies: the step donates the state they are read off
+            before = jax.tree_util.tree_map(
+                jnp.copy, _owner_cursors(self.ss.states, 0))
         if self.mesh is not None:
             ext = jax.tree_util.tree_map(
                 lambda x: jax.lax.with_sharding_constraint(
@@ -633,10 +810,28 @@ class ShardedCluster:
         self._seed += 1
         self.ss, _, _, _ = sharded_step(self.cfg, self.ss, ext,
                                         self._step_impl)
+        if owners:
+            self._counts = count_commands(self.cfg, before, self.ss,
+                                          self._counts)
 
     def committed(self) -> tuple[int, int, int]:
+        """(committed in all, lowest and highest committed slot over
+        the shards) at replica 0. The first is log instances, which a
+        single-leader log fills with commands only; for a multi-owner
+        pod it is client COMMANDS, its no-op slots left out."""
         tot, lo, hi = commit_totals(self.cfg, self.ss)
+        if self._counts is not None:
+            tot = self._counts[0]
         return int(tot), int(lo), int(hi)
+
+    def _owner_counts(self, n_proposals):
+        """The resident loop's ``n_proposals`` for a multi-owner pod:
+        one count per owner, from one for all or a sequence of R."""
+        # paxlint: disable=resident-loop -- host ints in, nothing read back
+        n = np.minimum(np.broadcast_to(np.asarray(n_proposals, np.int32),
+                                       (self.cfg.n_replicas,)),
+                       self.ext_rows)
+        return jnp.asarray(n, jnp.int32)
 
     def run_fused(self, k_rounds: int, n_proposals: int,
                   substeps: int = 1):
@@ -644,11 +839,15 @@ class ShardedCluster:
         (numpy [k, G] committed_upto and crt_inst at the leader).
         Host-in-the-loop readback per dispatch — the pre-resident
         measured loop, kept as the ``BENCH_RESIDENT=0`` A/B leg."""
-        self.ss, uptos, crts = sharded_run(
+        out = sharded_run(
             self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
             jnp.int32(min(n_proposals, self.ext_rows)),
             jnp.int32(self.leader), jnp.int32(self._seed),
-            jnp.int32(self.seed), self._step_impl, self.key_space, substeps)
+            jnp.int32(self.seed), self._step_impl, self.key_space, substeps,
+            self._counts)
+        self.ss, uptos, crts = out[:3]
+        if self._counts is not None:
+            self._counts = out[3]
         self._seed += k_rounds
         return np.asarray(uptos), np.asarray(crts)
 
@@ -669,6 +868,10 @@ class ShardedCluster:
         self._telemetry = jnp.full((telemetry_rounds, N_TEL_FIELDS), -1,
                                    jnp.int32)
         self._tiers = jnp.zeros(3, jnp.int32)
+        # the window's command counts are those since this arming (a
+        # copy: the dispatches donate the live buffer)
+        self._counts_armed = (None if self._counts is None
+                              else jnp.copy(self._counts))
         # ring indices are relative to the round counter at arming
         # time, so re-arming (bench: warmup, then measured phase)
         # restarts the ring at row 0
@@ -685,31 +888,35 @@ class ShardedCluster:
             self._inject_round = jax.device_put(
                 self._inject_round,
                 NamedSharding(self.mesh, P("shard")))
-            self._lat_hist = jax.device_put(
-                self._lat_hist, NamedSharding(self.mesh, P()))
-            self._telemetry = jax.device_put(
-                self._telemetry, NamedSharding(self.mesh, P()))
-            self._tiers = jax.device_put(
-                self._tiers, NamedSharding(self.mesh, P()))
+            self._lat_hist = self._replicated(self._lat_hist)
+            self._telemetry = self._replicated(self._telemetry)
+            self._tiers = self._replicated(self._tiers)
 
     # paxlint: resident-loop
-    def run_resident(self, k_rounds: int, n_proposals: int,
+    def run_resident(self, k_rounds: int, n_proposals,
                      substeps: int = 1) -> tuple[int, int]:
         """k rounds in one dispatch, fully device-resident; returns
         (committed_total, in_flight) — the sanctioned per-dispatch
         scalar readbacks (progress cursor + drain check). Everything
         else (state, inject ring, latency histogram, telemetry ring,
         tier counts) stays on device in donated buffers until
-        ``end_resident``."""
+        ``end_resident``. For a multi-owner pod ``n_proposals`` is per
+        OWNER (one number for all, or a sequence of R) and the scalars
+        count commands (``sharded_run_resident``)."""
+        if self._counts is None:
+            n_prop = jnp.int32(min(n_proposals, self.ext_rows))
+        else:
+            n_prop = self._owner_counts(n_proposals)
         with phase(PH_POD_DISPATCH):
             (self.ss, self._inject_round, self._lat_hist, self._telemetry,
-             self._tiers, committed, in_flight) = sharded_run_resident(
+             self._tiers, committed, in_flight,
+             self._counts) = sharded_run_resident(
                 self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
                 self._inject_round, self._lat_hist, self._telemetry,
-                self._tiers, jnp.int32(min(n_proposals, self.ext_rows)),
+                self._tiers, n_prop,
                 jnp.int32(self.leader), jnp.int32(self._seed),
                 jnp.int32(self.seed), self._step_impl, self.key_space,
-                substeps, jnp.int32(self._tel_base))
+                substeps, jnp.int32(self._tel_base), self._counts)
         self._seed += k_rounds
         # the per-dispatch scalar readback — the ONLY host sync in the
         # measured steady state (paxlint's resident-loop rule keeps it
@@ -733,14 +940,30 @@ class ShardedCluster:
         ran at ``working_capacity`` rows), ``rounds`` in all, and the
         two capacities. A post-window read by the same discipline as
         ``resident_telemetry``; the reading is kept, as of this call,
-        in ``obs.process_pods()``."""
+        in ``obs.process_pods()`` — with, for a multi-owner pod, the
+        ``command_commits`` and ``noop_slots`` the cursor replica's
+        frontier passed over the same rounds."""
         kernel_small, route_small, rounds = np.asarray(self._tiers).tolist()
         self._pod["tiers"] = {"kernel_small_rounds": kernel_small,
                               "route_small_rounds": route_small,
                               "rounds": rounds}
+        if self._counts is not None:
+            commands, noops, _ = (np.asarray(self._counts)
+                                  - np.asarray(self._counts_armed)).tolist()
+            self._pod.update(command_commits=commands, noop_slots=noops)
         return {**self._pod["tiers"],
                 "working_capacity": self._pod["working_capacity"],
                 "inbox": self._pod["inbox"]}
+
+    def command_counts(self) -> dict:
+        """A multi-owner pod's counts, cumulative from boot, at the
+        cursor replica (0): client ``commands`` and ``noop_slots`` its
+        merged frontier has passed (together: every slot up to it) and
+        commands ``assigned`` a slot by their owners. Blocks on the
+        device: a post-window read."""
+        commands, noops, assigned = np.asarray(self._counts).tolist()
+        return {"commands": commands, "noop_slots": noops,
+                "assigned": assigned}
 
     def resident_telemetry(self) -> np.ndarray:
         """The paxray post-window telemetry readback: written rows
