@@ -8,7 +8,7 @@ leading array axis, runs the identical per-replica protocol step under
 ``vmap``, and *routes messages as array ops*: each replica's outbox rows
 carry a ``dst``; routing pools all outboxes and compacts each replica's
 addressed rows into its next inbox in ONE segmented pass (a single
-segment-prefix-sum over the pooled rows + a scatter-free searchsorted
+segment-prefix-sum over the pooled rows + a scatter-free rank-select
 winner — ops/segscatter.py; the original per-destination cumsum-scatter
 fabric survives behind ``route_fabric="dense"`` for the byte-equality
 pin). Replica failure is a mask (see ``alive``): a dead replica's rows
@@ -145,8 +145,10 @@ def _route_segmented(cfg: MinPaxosConfig, out_msgs: MsgBatch,
     computed once, ONE segment-prefix-sum yields per-destination
     offsets (broadcast rows expand in index arithmetic only — the
     payload pool is never copied per destination), and the winner per
-    inbox slot is recovered scatter-free via searchsorted
-    (ops/segscatter.py rationale). Byte-identical to ``_route``
+    inbox slot is recovered scatter-free by a rank-select over those
+    offsets (ops/rankselect.py: vector compares; the binary search it
+    replaced in PR 29 was the top device op of both pod cells, ledger
+    PR 28). Byte-identical to ``_route``
     including row order and overflow-drop semantics — pinned by
     tests/test_route_fabric.py and the golden kernel fixtures."""
     flat, cnt = _pool_counts(out_msgs, dst, alive)

@@ -70,6 +70,7 @@ from minpaxos_tpu.ops.ackruns import (
     scatter_vote_bits,
 )
 from minpaxos_tpu.ops.kvstore import KVState, kv_apply_batch, kv_init
+from minpaxos_tpu.ops.rankselect import rank_select
 from minpaxos_tpu.ops.scan import commit_frontier, segmented_scan_max
 from minpaxos_tpu.ops.sections import Sections
 from minpaxos_tpu.ops.winner import gather_const, gather_row, slot_winner
@@ -204,15 +205,16 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc):
     # scatters (ops/winner.py rationale) — and the winner itself is
     # recovered WITHOUT a scatter (PR 11): propose targets stride R
     # from crt_own, so window slot s takes propose rank
-    # q = (abs - crt_own) / R, and rank q's row is a searchsorted
-    # probe into the propose prefix count (scatters serialize on
-    # XLA:CPU — ops/segscatter.py rationale)
+    # q = (abs - crt_own) / R, and rank q's row is the first whose
+    # propose prefix count reaches q + 1: ops/rankselect.py, vector
+    # compares. Until PR 29 a jnp.searchsorted per slot, whose 11
+    # dependent element gathers were the top device op of
+    # mencius64k_steady (35.4 ms of a 257 ms round; ledger, PR 28)
     off_p = idx_abs - state.crt_own
     rank_p = off_p // R
     hit_p = ((off_p >= 0) & (jnp.mod(off_p, R) == 0)
              & (rank_p < csum_p[-1]))
-    win_p = jnp.searchsorted(
-        csum_p, jnp.clip(rank_p, 0, M - 1) + 1).astype(jnp.int32)
+    win_p = rank_select(csum_p, jnp.clip(rank_p, 0, M - 1) + 1)
     win_p = jnp.where(hit_p, win_p, -1)
     state = state._replace(
         ballot=gather_const(hit_p, 0, state.ballot),

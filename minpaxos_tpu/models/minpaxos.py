@@ -107,12 +107,13 @@ class MinPaxosConfig(NamedTuple):
     gossip_ticks: int = 1
     # Routing-fabric selector (static): "segmented" = the one-pass
     # segmented scatter (ops/segscatter.py — one segment-prefix-sum
-    # over the pooled outbox rows, winner via searchsorted, 12 dense
-    # gathers; PR 11); "dense" = the original per-destination
-    # vmap-over-R masked cumsum (kept for the byte-equality pin and
-    # the profile_substeps before/after table). Both produce
-    # byte-identical inboxes (tests/test_route_fabric.py); segmented
-    # measures 2.5-3.5x faster at bench capacities on the CPU host.
+    # over the pooled outbox rows, winner via rank-select
+    # (ops/rankselect.py: vector compares since PR 29, after the chip
+    # measured searchsorted's dependent gathers as the top device op
+    # of both pod cells), 12 dense gathers; PR 11); "dense" = the
+    # original per-destination vmap-over-R masked cumsum (kept for the
+    # byte-equality pin and the profile_substeps before/after table).
+    # Both produce byte-identical inboxes (tests/test_route_fabric.py).
     route_fabric: str = "segmented"
     # Protocol selector: False = MinPaxos (global ballot, commits learned
     # from the LastCommitted piggyback on Accepts — bareminpaxos.go hot

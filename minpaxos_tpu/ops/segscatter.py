@@ -3,10 +3,9 @@
 The pod-mode routing fabric compacts every replica's addressed outbox
 rows into per-destination inboxes. The original fabric
 (models/cluster.py ``_route``) vmapped a full masked cumsum + scatter
-over the [R·M] pooled rows once PER DESTINATION — O(R²·M) scans, and
-the per-destination ``slot_winner`` scatter serializes on XLA:CPU
-(measured: the scatter-based variant is not faster than the old fabric
-at all; the scatter IS the cost — tools/scatter_micro.py leg e/f).
+over the [R·M] pooled rows once PER DESTINATION — O(R²·M) scans and a
+per-destination ``slot_winner`` scatter (on the CPU of PR 11 the
+scatter was the cost: tools/scatter_micro.py legs e/f).
 
 The segmented plan here does the whole fan-out in one pass:
 
@@ -18,11 +17,15 @@ The segmented plan here does the whole fan-out in one pass:
   rows expand only in this index arithmetic (dup-free positions, the
   ops/winner.py trick) — the 12 payload columns are NEVER copied per
   destination;
-* the winner row for each inbox slot is recovered WITHOUT a scatter:
-  per-destination counts are nondecreasing, so slot s's source row is
-  a ``searchsorted`` probe (log N vectorized gathers), and the payload
-  lands via 12 dense gathers straight into the stacked [R, capacity]
-  inboxes.
+* the winner row for each inbox slot is recovered WITHOUT a scatter
+  and without a search: per-destination counts are nondecreasing, so
+  slot s's source row is the first whose count reaches s + 1, a
+  rank-select done with vector compares (ops/rankselect.py). Until
+  PR 29 it was ``jnp.searchsorted``'s binary search, 14 dependent
+  element gathers a slot, which the chip measured as the largest
+  device op of both pod cells (ledger, PR 28: 45.3 ms of
+  ``pod128_steady``'s 468 ms round). The payload lands via 12 dense
+  gathers straight into the stacked [R, capacity] inboxes.
 
 Row order per destination is pooled-row order — byte-identical to the
 old fabric (tests/test_route_fabric.py pins it, and the golden kernel
@@ -32,7 +35,7 @@ overflow-drop-beyond-capacity semantics (legal message loss).
 The plan comes in two halves so that a caller can look at the counts
 before it chooses how many slots to fill: ``route_counts`` is the
 prefix sum (its last column is each destination's row count), and
-``plan_slots`` the winner search for a static number of slots. Filled
+``plan_slots`` the winner of each of a static number of slots. Filled
 slots are a PREFIX of each inbox, so a plan for fewer slots than the
 capacity is the capacity's plan cut short: nothing moves, and nothing
 drops while every count fits (parallel/sharded.py's two-tier round
@@ -43,6 +46,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from minpaxos_tpu.ops.rankselect import rank_select
 
 __all__ = ["route_counts", "plan_slots", "gather_rows"]
 
@@ -78,7 +83,10 @@ def route_counts(kind_flat: jnp.ndarray, src_rep: jnp.ndarray,
 def plan_slots(cnt: jnp.ndarray,
                slots: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Winner per inbox slot WITHOUT a scatter: cnt[d] is nondecreasing,
-    so the row landing at slot s is the first with cnt == s + 1.
+    so the row landing at slot s is the first with cnt == s + 1, the
+    rank-select of ops/rankselect.py (vector compares; the integers
+    ``jnp.searchsorted(cnt[d], s + 1)`` gave, at 2.2 ms where its
+    binary search took 45 in ``pod128_steady``'s round).
 
     Returns (win, hit): win[d, s] = pooled-row index filling slot s of
     destination d's inbox (rows keep pooled order; slots beyond the
@@ -86,8 +94,7 @@ def plan_slots(cnt: jnp.ndarray,
     dropped), hit[d, s] = slot filled.
     """
     want = jnp.arange(1, slots + 1, dtype=jnp.int32)
-    win = jax.vmap(lambda c: jnp.searchsorted(c, want))(cnt)
-    win = win.astype(jnp.int32)
+    win = jax.vmap(rank_select, in_axes=(0, None))(cnt, want)
     return win, win < cnt.shape[1]
 
 

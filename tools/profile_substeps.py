@@ -31,7 +31,7 @@ kernels — NOT re-implementations):
   stays comparable across the PR-11 rewrite;
 * ``route_v2`` — the one-pass segmented fabric (_route_segmented /
   ops/segscatter.py) the cluster actually runs: one segment-prefix-sum
-  + searchsorted winner, no per-destination scatter;
+  + rank-select winner (ops/rankselect.py), no per-destination scatter;
 * ``apply``   — the KV claim/apply path (ops/kvstore.kv_apply_batch:
   lexsort, segmented scans, two-choice claim rounds) per exec row.
 

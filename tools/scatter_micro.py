@@ -16,12 +16,17 @@ M=1408 updates, S=2048 targets):
   b. argmax+gather — 1 scatter-max of row index, then 10 gathers
   c. onehot-matmul — one-hot [S, M] f32 matmul against [M, 10] payload
 
+  g. rank-select (PR 29): the route's and Mencius's propose's "which
+     row is the k-th" at the pod cells' shapes, device-timed:
+     ``python tools/scatter_micro.py rankselect [part of a label]``
+
 Run: python tools/scatter_micro.py (on the machine with the chip; one
 process owns it)
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import time
@@ -102,7 +107,7 @@ def main() -> None:
     # -- routing fabric: dense pool-per-destination vs one-pass
     # segmented (PR 11). The dense fabric is a masked cumsum + scatter
     # per destination over the [R·M] pool; the segmented one is one
-    # segment-prefix-sum + a searchsorted winner + 12 dense gathers
+    # segment-prefix-sum + a rank-select winner + 12 dense gathers
     # (ops/segscatter.py). Same inputs, byte-identical outputs
     # (tests/test_route_fabric.py) — this leg isolates the (a)
     # rewrite's win from the rest of the round.
@@ -136,5 +141,95 @@ def main() -> None:
               f"({ms_d / ms_s:.1f}x)")
 
 
+# -- rank-select (PR 29): "which row is the k-th destined one", the
+# search ops/segscatter.py plan_slots and models/mencius.py section 1
+# make every round. Formulations that return jnp.searchsorted's result
+# element for element, at the two pod cells' shapes ([G, R, rows] x
+# slots; kernel rows and outbox rows as eval_shape gives them for
+# benchmarks/configs/*.json) and at their full-tier shapes.
+RANK_SHAPES = [
+    ("pod128 route small", 128, 5, 8645, 512),
+    ("pod128 route full", 128, 5, 12485, 1280),
+    ("mencius64k propose small", 16, 5, 1216, 4096),
+    ("mencius64k propose full", 16, 5, 2112, 4096),
+    ("mencius64k route small", 16, 5, 8965, 1152),
+    ("mencius64k route full", 16, 5, 13445, 2048),
+    # either side of ops/rankselect.py SHORT_ROWS
+    ("between, 128 groups", 128, 5, 4096, 512),
+    ("between, 128 groups", 128, 5, 6144, 512),
+    ("between, 16 groups", 16, 5, 4096, 4096),
+]
+
+
+def _device_ms(fn, *args, iters=5):
+    """Device-busy ms a call, from a profiler trace of ``iters`` calls
+    (the union of the device plane's op intervals: benchmarks/lib/
+    xplane.py); None where the trace holds no device plane (CPU)."""
+    import shutil
+    import tempfile
+
+    from benchmarks.lib import xplane
+
+    jax.block_until_ready(fn(*args))
+    d = tempfile.mkdtemp(prefix="rank_micro_")
+    try:
+        jax.profiler.start_trace(d)
+        for _ in range(iters):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        devices, _ = xplane.read_planes(xplane.find_xplane(d))
+    finally:
+        shutil.rmtree(d, ignore_errors=True)
+    if not devices:
+        return None
+    busy = max(sum(e - s for s, e in xplane.busy_intervals(ev))
+               for ev in devices.values())
+    return busy / 1e6 / iters
+
+
+def rank_select_leg(shapes=RANK_SHAPES) -> None:
+    """Leg g: every formulation at every shape, device-timed, with the
+    compiled program's temporary bytes and whether it equals the
+    default search; the last line of a shape is what
+    ``ops/rankselect.py`` chooses there."""
+    from minpaxos_tpu.ops import rankselect as rs
+
+    rng = np.random.default_rng(0)
+    legs = {
+        "scan (jnp default)": jnp.searchsorted,
+        "sort": functools.partial(jnp.searchsorted, method="sort"),
+        "compare_all (jnp)": functools.partial(jnp.searchsorted,
+                                               method="compare_all"),
+        "compare_all (ours)": rs._below,
+        "blocked": rs._blocked,
+        "rank_select": rs.rank_select,
+    }
+    for label, g, r, n, q in shapes:
+        # a healthy round's density: about 0.6 of the slots filled
+        mask = rng.random((g, r, n)) < 0.6 * q / n
+        cnt = jnp.asarray(np.cumsum(mask, -1).astype(np.int32))
+        want = jnp.broadcast_to(jnp.arange(1, q + 1, dtype=jnp.int32),
+                                (g, r, q))
+        ref = None
+        print(f"g. rank-select, {label}: [{g}, {r}, {n}] x {q}")
+        for name, f in legs.items():
+            fn = jax.jit(jax.vmap(jax.vmap(f)))
+            temp = fn.lower(cnt, want).compile() \
+                .memory_analysis().temp_size_in_bytes
+            got = np.asarray(fn(cnt, want))
+            ref = got if ref is None else ref
+            ms_dev = _device_ms(fn, cnt, want)
+            dev = "not measured" if ms_dev is None else f"{ms_dev:9.3f} ms"
+            print(f"   {name:20s} device {dev}  host "
+                  f"{_time(fn, cnt, want, iters=5):9.3f} ms  temp "
+                  f"{temp / 1e6:7.1f} MB  equal {bool((got == ref).all())}")
+
+
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:2] == ["rankselect"]:  # leg g alone: [label part]
+        rank_select_leg([sh for sh in RANK_SHAPES
+                         if all(a in sh[0] for a in sys.argv[2:])])
+    else:
+        main()
+        rank_select_leg()
