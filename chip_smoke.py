@@ -7,19 +7,19 @@ ONE process owns the chip for the whole run and drives the system's two
 main paths through the entry points a user calls, at full width:
 
 * **Phase A — the device-resident consensus loop** (README "Sharded
-  mode", the path bench.py measures): ``ShardedCluster`` +
-  ``begin_resident`` / ``run_resident`` / ``end_resident``, MinPaxos,
-  N=5 majority, bench.py's own on-chip shape and config (g=256,
+  mode", the path the benchmark's pod cells measure): ``ShardedCluster``
+  + ``begin_resident`` / ``run_resident`` / ``end_resident``, MinPaxos,
+  N=5 majority, at ``minpaxos_tpu.deployments``' on-chip shape (g=256,
   w=4096, p=512: 1,048,576 concurrent instances). Healthy dispatches,
   kill one follower -> dead rounds -> revive -> reheal on the same
   compiled variant, drain. With more than one device visible the
   shard axis is laid over all of them.
 * **Phase B — the served path** (README "Distributed mode", BASELINE
-  config 1 as bench_tcp.py runs it): a master and three ``-min
-  -durable`` replica servers in this process (every replica's step on
-  the chip, fsync on), composed from the server binary's own flags at
-  bench_tcp's ``SERVER_SHAPE``; load arrives over localhost TCP from
-  the normal client binary, a child that imports no JAX.
+  config 1): a master and three ``-min -durable`` replica servers in
+  this process (every replica's step on the chip, fsync on), composed
+  from the server binary's own flags at ``deployments.SERVER_SHAPE``;
+  load arrives over localhost TCP from the normal client binary, a
+  child that imports no JAX.
 
 Every check compares against something independent of the code under
 test: the proposal stream replayed on the host into a Python dict
@@ -74,7 +74,7 @@ MAX_DRAIN_DISPATCHES = 12
 #: shards whose whole KV table is held to the host replay (seeded pick)
 REFERENCE_SHARDS = 4
 
-#: phase B load: one bench_tcp trial's worth (dry mode: a toy)
+#: phase B load (dry mode: a toy)
 CLIENT_Q, CLIENT_KEYS = 20_000, 100_000
 DRY_CLIENT_Q, DRY_CLIENT_KEYS = 2_000, 1_000
 DRY_SERVER_SHAPE = ["-window", "1024", "-inbox", "1024", "-kvpow2", "12",
@@ -162,14 +162,15 @@ def phase_a(meter: _CompileMeter, on_tpu: bool, seed: int) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    import bench
+    from minpaxos_tpu import deployments
     from minpaxos_tpu.ops.kvstore import LIVE, kv_lookup
     from minpaxos_tpu.ops.workload import propose_batch_host
     from minpaxos_tpu.parallel import make_mesh
     from minpaxos_tpu.parallel.sharded import ShardedCluster, shard_cursors
 
-    g, w, p = (bench.TPU_SHAPE if on_tpu else bench.CPU_SHAPE)[:3]
-    cfg, key_space = bench.headline_config(on_tpu, w, p)
+    g, w, p = (deployments.TPU_SHAPE if on_tpu
+               else deployments.CPU_SHAPE)[:3]
+    cfg, key_space = deployments.headline_config(on_tpu, w, p)
     n_dev = len(jax.devices())
     mesh = (make_mesh(n_shard_devices=n_dev, n_replica_devices=1)
             if n_dev > 1 else None)
@@ -320,9 +321,9 @@ def phase_b(meter: _CompileMeter, on_tpu: bool) -> dict:
     import jax
     import numpy as np
 
-    import bench_tcp
     from minpaxos_tpu.chaos.campaign import ChaosCluster
     from minpaxos_tpu.cli import server as server_cli
+    from minpaxos_tpu.deployments import SERVER_SHAPE
     from minpaxos_tpu.ops.kvstore import LIVE, kv_lookup
     from minpaxos_tpu.ops.packed import split_i64
     from minpaxos_tpu.runtime.client import gen_workload
@@ -332,7 +333,7 @@ def phase_b(meter: _CompileMeter, on_tpu: bool) -> dict:
     n = 3
     q, key_range = ((CLIENT_Q, CLIENT_KEYS) if on_tpu
                     else (DRY_CLIENT_Q, DRY_CLIENT_KEYS))
-    shape = bench_tcp.SERVER_SHAPE if on_tpu else DRY_SERVER_SHAPE
+    shape = SERVER_SHAPE if on_tpu else DRY_SERVER_SHAPE
     store = ROOT / ".chip_smoke_store"
     shutil.rmtree(store, ignore_errors=True)
     store.mkdir()

@@ -221,10 +221,10 @@ class ChaosCluster:
                  flags: dict | None = None, cfg=None,
                  boot_timeout_s: float = 20.0):
         """``cfg``: a full MinPaxosConfig in place of the 1,024-slot
-        harness default (chip_smoke.py serves at bench_tcp's
-        SERVER_SHAPE); ``boot_timeout_s``: how long the leader may take
-        to report prepared — a first compile on a TPU outlasts the
-        CPU-calibrated 20 s."""
+        harness default (chip_smoke.py serves at
+        ``deployments.SERVER_SHAPE``); ``boot_timeout_s``: how long the
+        leader may take to report prepared — a first compile on a TPU
+        outlasts the CPU-calibrated 20 s."""
         # late imports: chaos/__init__ must stay importable without JAX
         from minpaxos_tpu.models.minpaxos import MinPaxosConfig
         from minpaxos_tpu.runtime.master import Master, register_with_master

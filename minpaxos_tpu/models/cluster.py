@@ -79,10 +79,9 @@ def _route(cfg: MinPaxosConfig, out_msgs: MsgBatch, dst: jnp.ndarray,
     (message loss), sized to be impossible in steady state.
 
     Kept for the byte-equality pin of the segmented fabric
-    (tests/test_route_fabric.py) and the profile_substeps before/after
-    table; O(R²·M) scans plus a per-destination scatter that
-    serializes on XLA:CPU — ``_route_segmented`` replaces it on the
-    hot path (PR 11).
+    (tests/test_route_fabric.py); O(R²·M) scans plus a per-destination
+    scatter that serializes on XLA:CPU — ``_route_segmented`` replaces
+    it on the hot path (PR 11).
     """
     r = cfg.n_replicas
     flat = jax.tree_util.tree_map(lambda x: x.reshape(-1), out_msgs)  # [R*M]

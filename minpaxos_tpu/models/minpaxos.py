@@ -112,7 +112,7 @@ class MinPaxosConfig(NamedTuple):
     # measured searchsorted's dependent gathers as the top device op
     # of both pod cells), 12 dense gathers; PR 11); "dense" = the
     # original per-destination vmap-over-R masked cumsum (kept for the
-    # byte-equality pin and the profile_substeps before/after table).
+    # byte-equality pin).
     # Both produce byte-identical inboxes (tests/test_route_fabric.py).
     route_fabric: str = "segmented"
     # Protocol selector: False = MinPaxos (global ballot, commits learned

@@ -26,8 +26,6 @@ Consumers:
   serves them over the control socket (``STATS`` / ``TRACE`` verbs).
 * ``runtime/master.py`` — fans the verbs out cluster-wide.
 * ``tools/paxtop.py`` — the live terminal view.
-* ``bench.py`` / ``bench_tcp.py`` — embed end-of-run snapshots in
-  their artifacts.
 * a harness that holds the servers in its own process (the
   benchmark's ``served`` runner) — ``process_collection()`` below;
   one that holds a sharded pod (its ``pod`` runner) —

@@ -234,7 +234,7 @@ _EVENT_PHASES = frozenset("XBEiICMsnbe")  # trace-event ph codes we accept
 # Device-side telemetry for the resident measured loop (schema v4).
 # The resident scan (parallel/sharded.py sharded_run_resident)
 # accumulates ONE int32 row per protocol round in a donated device
-# buffer; the bench reads the buffer back exactly once after the
+# buffer; the host reads the buffer back exactly once after the
 # measured window and renders it here as Perfetto tracks. The layout
 # is canonical HERE (obs stays numpy-only, importable by paxtop with
 # no JAX) and ops/telemetry.py — the jnp row constructor traced inside
@@ -275,8 +275,8 @@ WATCH_PID = 9997
 #   device the same reduction over the pending rows picks each
 #   round's tier (parallel/sharded.py sharded_round: rows at or
 #   beyond the working capacity send the round to the full one); on
-#   the host its high-water mark over a run feeds the shape ladder's
-#   inbox axis (tools/shape_ladder.py, PR 11).
+#   the host its high-water mark over a run is the occupancy a
+#   configuration's inbox and small tier are sized from (PR 28).
 (TEL_ROUND, TEL_COMMITTED, TEL_IN_FLIGHT, TEL_ASSIGNED, TEL_INJECTED,
  TEL_INBOX_ROWS, TEL_CLAIM_ROWS, TEL_PREPARED, TEL_INBOX_HWM) = range(9)
 N_TEL_FIELDS = 9

@@ -470,9 +470,8 @@ def _pcts(values) -> dict:
 def analyze_collections(
         collections: list[dict]) -> tuple[dict, list[dict], dict]:
     """(stage table, per-trace decomposition, chains) for a set of
-    span collections — the ONE pipeline tools/tail.py, bench_tcp and
-    the obs_smoke gate all share, so the bench artifact can never
-    silently diverge from what tail.py prints."""
+    span collections — the ONE pipeline tools/tail.py and the
+    obs_smoke gate share, so the gate checks what tail.py prints."""
     chains = span_chains(align_collections(collections))
     decomp = stage_decomposition(chains)
     return stage_table(decomp), decomp, chains

@@ -61,15 +61,17 @@ SEV_NAMES = ("info", "warn", "alert")
 #: event kinds (EV_KIND field). Kind 0 is reserved as the
 #: never-written marker (ring rows are zero-initialized; a real event
 #: always has mono_ns > 0 as well). Append-only: consumers key on the
-#: value, so renumbering is a schema break.
+#: value, so renumbering is a schema break. Kind 8 is retired (its one
+#: emitter went with the CPU-era bench scripts); the number is not
+#: reused.
 (EV_NONE, EV_ELECTION, EV_LEADER_CHANGE, EV_CLIENT_FAILOVER,
  EV_CHAOS_INSTALL, EV_CHAOS_CLEAR, EV_STORE_CORRUPT,
- EV_NARROW_FALLBACK, EV_LATENCY_OVERFLOW, EV_PEER_DOWN, EV_PEER_UP,
+ EV_NARROW_FALLBACK, _EV_RETIRED_8, EV_PEER_DOWN, EV_PEER_UP,
  EV_FATAL, EV_ALARM, EV_ALARM_CLEAR, EV_PHASE, EV_SNAPSHOT,
  EV_TRUNCATE, EV_RECOVERY) = range(18)
 EVENT_NAMES = ("none", "election", "leader_change", "client_failover",
                "chaos_install", "chaos_clear", "store_corrupt",
-               "narrow_fallback", "latency_overflow", "peer_down",
+               "narrow_fallback", "retired_8", "peer_down",
                "peer_up", "fatal", "alarm", "alarm_clear", "phase",
                # durability lifecycle (PR 20): snapshot taken (value =
                # snapshot frontier, aux = log bytes after), redo log
@@ -104,8 +106,8 @@ DETECTOR_IDS = {v: k for k, v in DETECTOR_NAMES.items()}
 
 # event-row field layout. subject: the replica/detector target the
 # event is ABOUT (replica id, or -1 for cluster-wide); value: the
-# event's one evidence scalar (corrupt-record count, overflow count,
-# alarm window ms); aux: a second discriminator (old leader id on
+# event's one evidence scalar (corrupt-record count, alarm window
+# ms); aux: a second discriminator (old leader id on
 # leader_change, DET_* id on alarms); trace_id: the paxtrace join key
 # when the event belongs to a sampled command's story (0 = none).
 (EV_MONO, EV_WALL, EV_KIND, EV_SEV, EV_SUBJECT, EV_VALUE, EV_AUX,

@@ -17,14 +17,14 @@ paxtop and the smoke gates import it without JAX) and imported here;
 ``obs.recorder.device_round_events`` renders the readback as Perfetto
 device-round tracks under the reserved pid. Telemetry writes touch
 ONLY the telemetry buffer — protocol state is byte-identical with
-telemetry on or off (pinned by tests/test_paxray.py), and the
-``BENCH_TELEMETRY=0`` knob drops the writes from the trace entirely
-(a zero-row buffer compiles the exact PR-8 dispatch).
+telemetry on or off (pinned by tests/test_paxray.py), and a zero-row
+buffer drops the writes from the trace entirely (it compiles the
+dispatch without them).
 
 Per-phase latency decomposition is what makes consensus systems
 tunable in production ("Paxos in the Cloud", PAPERS.md 1404.6719);
-the per-round rows here plus ``tools/profile_substeps.py``'s isolated
-substep costs are that decomposition for the resident loop.
+the per-round rows here plus the ``px.*`` scopes of a device trace
+(ops/sections.py) are that decomposition for the resident loop.
 """
 
 from __future__ import annotations

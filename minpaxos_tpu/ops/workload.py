@@ -17,7 +17,7 @@ under a jax upgrade. The PRNG is keyed on (seed, round) and countered
 on (shard, row): any (round, shard, row) cell of the workload can be
 regenerated independently — the property that lets the host injector
 (``propose_batch_host``) reproduce the device stream exactly for the
-``BENCH_RESIDENT=0`` A/B leg and the equivalence tests
+host-in-the-loop runner (``sharded_run``) and the equivalence tests
 (tests/test_workload.py).
 
 Row format is the MsgBatch PROPOSE layout the host injector produces
@@ -102,7 +102,7 @@ def threefry2x32(k0, k1, c0, c1):
 def threefry2x32_host(k0, k1, c0, c1):
     """NumPy mirror of ``threefry2x32`` — the independent host
     reference the equivalence tests hold the device stream to, and the
-    host injector's generator for the ``BENCH_RESIDENT=0`` leg. Kept
+    host injector's generator. Kept
     textually parallel to the jnp version on purpose; uint32 wraparound
     is the defined behavior, so the overflow warnings are silenced."""
     with np.errstate(over="ignore"):
@@ -286,9 +286,9 @@ def propose_batch_host(n_replicas: int, n_shards: int, ext_rows: int,
     """The host injector: NumPy twin of ``propose_batch``, row-for-row
     and byte-for-byte identical from the same (seed, round), in both
     streams (``owners`` as there; ``count`` may be [R] in the
-    multi-owner one). This is what ``BENCH_RESIDENT=0`` feeds the
-    cluster from the host, and the reference the on-device generator
-    is proven against."""
+    multi-owner one). The reference the on-device generator is proven
+    against, and what a host replay of a resident run draws
+    (chip_smoke.py, the benchmark's references)."""
     g, r, m = n_shards, n_replicas, ext_rows
     if leader < 0 if owners is None else owners:
         return _propose_batch_owners_host(g, r, m, count, leader, round_idx,

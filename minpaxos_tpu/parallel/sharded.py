@@ -61,15 +61,13 @@ from minpaxos_tpu.wire.messages import Op
 
 #: round-latency histogram resolution for the resident runner: bins are
 #: exact integer round latencies 1..LATENCY_BINS-1, last bin = overflow
-#: (the bench reports it; with a drained run and sane shapes it is 0).
+#: (with a drained run and sane shapes it is 0).
 LATENCY_BINS = 512
 
 #: which jitted entry points of the fused dispatch path donate their
 #: round-state argument (in-place buffer reuse instead of a fresh
 #: allocation per dispatch). Asserted against reality by
-#: tests/test_workload.py (donated inputs must come back deleted) and
-#: stamped into the bench artifact so a record documents the donation
-#: discipline it ran under.
+#: tests/test_workload.py (donated inputs must come back deleted).
 DONATION = {
     "sharded_step": True,
     "sharded_run": True,
@@ -541,8 +539,8 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
       election/steady flag) written at index ``(round - tel_base) mod
       rounds``, read back once after the measured window exactly like
       the histogram. A ZERO-ROW buffer is the off switch: the writes
-      drop out of the trace at compile time, so ``BENCH_TELEMETRY=0``
-      runs the exact PR-8 dispatch. Telemetry never touches protocol
+      drop out of the trace at compile time (every benchmark cell
+      runs that dispatch). Telemetry never touches protocol
       state — state is byte-identical on/off (tests/test_paxray.py).
     * ``tiers`` int32[3] — how often the two-tier round
       (``sharded_round``) engaged: rounds whose kernel ran at the
@@ -602,9 +600,7 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 # inbox; the max per-(shard, replica) DELIVERED rows
                 # (routed + injected — injection has a closed form, see
                 # `injected` below) is the occupancy one inbox must
-                # hold: its run high-water mark feeds adaptive capacity
-                # selection (TEL_INBOX_HWM -> shape_ladder's inbox
-                # axis, PR 11)
+                # hold (TEL_INBOX_HWM)
                 pending_live = (ss.pending.kind != 0).sum(axis=-1)
                 inbox_rows = pending_live.sum()
                 ext_live = jnp.where(
@@ -837,8 +833,9 @@ class ShardedCluster:
                   substeps: int = 1):
         """k rounds in one dispatch; returns per-round cursor histories
         (numpy [k, G] committed_upto and crt_inst at the leader).
-        Host-in-the-loop readback per dispatch — the pre-resident
-        measured loop, kept as the ``BENCH_RESIDENT=0`` A/B leg."""
+        Host-in-the-loop readback per dispatch — the reference the
+        resident scan is held to byte for byte
+        (tests/test_workload.py)."""
         out = sharded_run(
             self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
             jnp.int32(min(n_proposals, self.ext_rows)),

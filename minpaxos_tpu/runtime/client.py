@@ -231,8 +231,8 @@ class Client:
 
     def trace_collect(self) -> dict | None:
         """This client's paxtrace span collection (None if tracing is
-        off) — merged with the cluster's TRACESPANS fan-out by
-        tools/tail.py / bench_tcp to close chains client-to-client."""
+        off) — merged with the cluster's TRACESPANS fan-out to close
+        chains client-to-client (tests/test_paxtrace.py)."""
         return None if self.trace is None else self.trace.collect()
 
     def events_collect(self) -> dict:
@@ -410,7 +410,7 @@ class MultiClient:
       natural Mencius driver — every owner serves proposals into its
       own slots concurrently, which is the whole point of the
       protocol; a single hinted proposer makes the other owners cede
-      every slot (BENCH_TCP round 3: mencius at half of minpaxos).
+      every slot.
     * ``mode="fast"`` — fast mode (`-f`): every command goes to ALL
       replicas; the first success reply on any connection wins.
       Non-leaders reject (MinPaxos/classic), so exactly one success
@@ -426,7 +426,7 @@ class MultiClient:
 
     def __init__(self, maddr: tuple[str, int], check: bool = False,
                  mode: str = "rr", bar_one: bool = False,
-                 wait_less: bool = False, trace_pow2: int | None = None):
+                 wait_less: bool = False):
         """``bar_one``: send to all replicas except the LAST (reference
         clienttot -barOne, clienttot/client.go:31, :76-78 — the
         excluded replica still learns/executes via the protocol, it
@@ -442,15 +442,9 @@ class MultiClient:
         n_targets = len(self.nodes) - 1 if bar_one else len(self.nodes)
         assert n_targets >= 1, "-barOne needs at least 2 replicas"
         for rid in range(n_targets):
-            c = Client(maddr, check=check, trace_pow2=trace_pow2)
+            c = Client(maddr, check=check)
             c.connect(rid)
             self.clients.append(c)
-
-    def trace_collect(self) -> list[dict]:
-        """Per-connection paxtrace collections (rr partitions have
-        disjoint cmd_id spaces, so the merge is safe)."""
-        out = [c.trace_collect() for c in self.clients]
-        return [c for c in out if c is not None]
 
     def run_workload(self, ops, keys, vals, batch: int = 512,
                      timeout_s: float = 60.0) -> dict:
@@ -552,13 +546,13 @@ class MultiClient:
 
 class ClientSwarm:
     """Many concurrent closed-loop client sessions over ONE selector
-    loop — the ingress-coalescer driver (bench_tcp -swarm).
+    loop — the ingress-coalescer driver (tests/test_swarm.py).
 
     Each session is a real TCP connection (its own conn_id on the
     server, so the coalescer sees genuinely multiplexed ingress) that
     keeps exactly one command outstanding: propose, wait for the
     reply, propose the next. A thread per session would be 2×1024
-    threads at the top of the bench range; instead every socket stays
+    threads at 1024 sessions; instead every socket stays
     blocking (sends are tiny and never fill the kernel buffer) and a
     single ``selectors`` loop in the calling thread drains replies and
     re-kicks sessions, so the swarm's own scheduling noise stays out
@@ -566,7 +560,7 @@ class ClientSwarm:
 
     Per-command latency is stamped at write time and read time in the
     driving thread; the result carries the full sorted distribution so
-    the bench can report any percentile. Commands outstanding longer
+    the caller can report any percentile. Commands outstanding longer
     than ``retransmit_s`` are re-sent with the SAME cmd_id on the same
     connection (the server's same-connection dedup absorbs it) — this
     is the recovery path when the coalescer's admission gate sheds
@@ -575,15 +569,12 @@ class ClientSwarm:
     """
 
     def __init__(self, maddr: tuple[str, int], sessions: int = 256,
-                 trace_pow2: int | None = None,
                  retransmit_s: float = 1.0):
         self.maddr = maddr
         self.sessions = sessions
         self.retransmit_s = retransmit_s
         self.nodes = get_replica_list(maddr)
         self.leader = get_leader(maddr)
-        self.trace = (None if trace_pow2 is None else
-                      TraceSink(enabled=True, sample_pow2=trace_pow2))
         self._socks: list[socket.socket] = []
 
     def _connect_one(self, rid: int) -> tuple[socket.socket, FrameWriter]:
@@ -592,9 +583,6 @@ class ClientSwarm:
         sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         sock.sendall(bytes([int(MsgKind.HANDSHAKE_CLIENT)]))
         return sock, FrameWriter(sock)
-
-    def trace_collect(self) -> dict | None:
-        return None if self.trace is None else self.trace.collect()
 
     def close(self) -> None:
         for s in self._socks:
@@ -605,28 +593,15 @@ class ClientSwarm:
         self._socks = []
 
     def _send(self, st: dict, cmd: int, ops, keys, vals) -> None:
-        """One single-row PROPOSE (+ TRACE_CTX when sampled) on a
-        session's connection; stamps t_send for the latency probe."""
+        """One single-row PROPOSE on a session's connection; stamps
+        t_send for the latency probe."""
         frame = make_batch(MsgKind.PROPOSE,
                            cmd_id=np.asarray([cmd], np.int32),
                            op=ops[cmd:cmd + 1], key=keys[cmd:cmd + 1],
                            val=vals[cmd:cmd + 1],
                            timestamp=time.monotonic_ns())
-        tr = self.trace
-        if tr is not None and tr.sampled(frame["cmd_id"]).any():
-            t_s0 = monotonic_ns()
-            ctx = make_batch(MsgKind.TRACE_CTX, cmd_id=frame["cmd_id"],
-                             trace_id=trace_id_for(frame["cmd_id"]),
-                             origin_wall_ns=time.time_ns())
-            st["writer"].write(MsgKind.TRACE_CTX, ctx)
-            st["writer"].write(MsgKind.PROPOSE, frame)
-            st["writer"].flush()
-            t_s1 = monotonic_ns()
-            ring = tr.ring()
-            ring.record(int(ctx["trace_id"][0]), ST_SEND, t_s0, t_s1, cmd)
-        else:
-            st["writer"].write(MsgKind.PROPOSE, frame)
-            st["writer"].flush()
+        st["writer"].write(MsgKind.PROPOSE, frame)
+        st["writer"].flush()
         st["out_cmd"] = cmd
         st["t_send"] = time.monotonic()
 
@@ -667,7 +642,6 @@ class ClientSwarm:
         while live > 0 and time.monotonic() < deadline:
             events = sel.select(timeout=0.05)
             now = time.monotonic()
-            t_ns = monotonic_ns()
             for key, _ in events:
                 st = key.data
                 try:
@@ -683,9 +657,6 @@ class ClientSwarm:
                 for kind, rows in st["dec"].feed(chunk):
                     if kind != MsgKind.PROPOSE_REPLY:
                         continue
-                    if self.trace is not None and len(rows):
-                        self.trace.stamp_batch(ST_REPLY_RECV,
-                                               rows["cmd_id"], t_ns, t_ns)
                     for r in range(len(rows)):
                         cmd = int(rows["cmd_id"][r])
                         if cmd != st["out_cmd"]:

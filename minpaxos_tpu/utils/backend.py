@@ -5,9 +5,9 @@ The program runs two ways: on the CPU for tests (``JAX_PLATFORMS=cpu``,
 its whole life. Neither way selects a backend here — JAX picks the
 platform (``JAX_PLATFORMS`` or its default) and an entry point that
 needs a chip checks ``jax.devices()[0].platform`` itself and fails when
-it is not there (chip_smoke.py, bench.py). What is shared is the
-compile cache: every process that jits the protocol kernels points at
-the same directory, so only the first pays the compile.
+it is not there (chip_smoke.py, benchmarks/run.py). What is shared is
+the compile cache: every process that jits the protocol kernels points
+at the same directory, so only the first pays the compile.
 """
 
 from __future__ import annotations

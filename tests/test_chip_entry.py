@@ -1,8 +1,9 @@
 """How the program starts: no fallback off the chip, one cache place.
 
-The entry points that need a TPU (chip_smoke.py, bench.py) must FAIL —
-non-zero exit, nothing on stdout — when JAX finds none, instead of
-quietly measuring the CPU; the compile cache is placed from outside
+An entry point that needs a TPU must FAIL — non-zero exit, nothing on
+stdout — when JAX finds none, instead of quietly measuring the CPU
+(chip_smoke.py here; benchmarks/run.py in
+tests/benchmarks/test_run_cli.py); the compile cache is placed from outside
 when ``JAX_COMPILATION_CACHE_DIR`` is set; processes a chip-owning
 parent spawns import no JAX. None of these tests compiles a protocol
 step: they run in seconds (the chip's own check is chip_smoke.py,
@@ -18,9 +19,7 @@ import sys
 import time
 from pathlib import Path
 
-import pytest
-
-import bench
+from minpaxos_tpu import deployments
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -119,53 +118,13 @@ def test_chip_owner_children_import_no_jax():
     assert out.returncode == 0, out.stderr
 
 
-# -------------------------------------------------------------- bench
-
-def test_bench_without_chip_or_explicit_cpu_prints_no_record(
-        monkeypatch, capsys):
-    """The backend is the CPU (conftest) but nothing asked for it
-    explicitly: measure() must stop before building anything."""
-    monkeypatch.delenv("JAX_PLATFORMS")
-    with pytest.raises(SystemExit) as e:
-        bench.measure()
-    assert e.value.code not in (0, None)
-    cap = capsys.readouterr()
-    assert cap.out == "" and "'cpu'" in cap.err
-
-
-def test_bench_unavailable_platform_exits_nonzero():
-    out = _run(["bench.py"], JAX_PLATFORMS="no_such_platform")
-    assert out.returncode != 0
-    assert not [ln for ln in out.stdout.splitlines()
-                if ln.startswith("{")]
-
-
-def test_bench_exception_is_a_failed_run(monkeypatch, capsys):
-    """An exception anywhere in measure() propagates (non-zero exit);
-    no failure record with value 0.0 is printed in its place."""
-    def boom(*a, **kw):
-        raise RuntimeError("boom")
-
-    monkeypatch.setattr(bench, "headline_config", boom)
-    with pytest.raises(RuntimeError, match="boom"):
-        bench.measure()
-    assert capsys.readouterr().out == ""
-
-
-def test_bench_unbuildable_mesh_is_an_error(monkeypatch, capsys):
-    monkeypatch.setenv("MP_BENCH_SHARD_DEVICES", "4096")
-    with pytest.raises(SystemExit) as e:
-        bench.measure()
-    assert e.value.code not in (0, None)
-    cap = capsys.readouterr()
-    assert cap.out == "" and "MP_BENCH_SHARD_DEVICES=4096" in cap.err
-
+# -------------------------------------------------------- deployments
 
 def test_headline_config_on_chip_shape():
-    """chip_smoke.py phase A and bench.py share this one definition."""
-    g, w, p, _k = bench.TPU_SHAPE
+    """What chip_smoke.py phase A runs on the chip."""
+    g, w, p, _k = deployments.TPU_SHAPE
     assert g * w == 1_048_576
-    cfg, key_space = bench.headline_config(True, w, p)
+    cfg, key_space = deployments.headline_config(True, w, p)
     assert (cfg.n_replicas, cfg.window, cfg.exec_batch) == (5, 4096, 512)
     # catch-up 2p (a revived follower reheals under full load) and a
     # table 4x the key space (no insert lost): PR 21's chip findings
@@ -189,11 +148,10 @@ def test_server_absent_platform_fails_before_registering():
 def test_server_flag_builders_compile_bench_tcp_shape():
     """chip_smoke.py phase B composes its replicas from the server
     binary's own flags: the builders must give what main() would."""
-    import bench_tcp
     from minpaxos_tpu.cli import server as server_cli
 
     args = server_cli.build_parser().parse_args(
-        ["-min", "-durable", *bench_tcp.SERVER_SHAPE])
+        ["-min", "-durable", *deployments.SERVER_SHAPE])
     cfg = server_cli.config_from_args(args, 3)
     assert (cfg.n_replicas, cfg.window, cfg.inbox, cfg.exec_batch,
             cfg.kv_pow2) == (3, 2048, 1024, 128, 18)

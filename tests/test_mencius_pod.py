@@ -256,7 +256,7 @@ def test_host_stepped_rounds_count_commands_too():
 
 
 def test_fused_rounds_count_commands_too():
-    """``run_fused`` (bench.py's side configs) carries the counts
+    """``run_fused`` (the host-in-the-loop runner) carries the counts
     through its scan; its cursor histories stay slots of the log."""
     sc = sharded.ShardedCluster(MinPaxosConfig(**_KW), _GROUPS,
                                 ext_rows=_EXT, key_space=256,

@@ -4,7 +4,7 @@ Contract pinned here:
 
 * telemetry is a PURE OBSERVER — protocol state, committed results and
   the latency histogram are byte-identical with the ring armed or not
-  (the ``BENCH_TELEMETRY=0/1`` parity), and the readback is
+  (``telemetry_rounds`` 0 against N), and the readback is
   deterministic across reruns from the same seed;
 * the ring rides the donation discipline (consumed per dispatch like
   the state tree) and its row layout is pinned against the canonical
@@ -96,7 +96,7 @@ def test_telemetry_row_layout_pinned_to_recorder():
 
 
 def test_telemetry_parity_state_byte_identical():
-    """THE BENCH_TELEMETRY=0/1 acceptance pin: telemetry on vs off —
+    """THE acceptance pin: telemetry on vs off —
     same committed totals, same exact latency histogram, and a
     byte-identical final cluster state from the same seed."""
     sc_off = _boot(tel_rounds=0)
@@ -190,9 +190,8 @@ def test_telemetry_ring_wraps_to_last_rounds():
 
 
 def test_telemetry_buffer_is_donated():
-    """The ring rides the donation discipline the bench artifact
-    stamps: consumed per dispatch like the state tree and the other
-    bookkeeping buffers."""
+    """The ring rides the donation discipline: consumed per dispatch
+    like the state tree and the other bookkeeping buffers."""
     assert DONATION["sharded_run_resident"] is True
     sc = _boot(tel_rounds=TEL_ROUNDS)
     old_tel = sc._telemetry

@@ -3,8 +3,7 @@ in-process TCP cluster driven by ClientSwarm's selector loop — many
 concurrent closed-loop sessions multiplexed through the ingress
 coalescer, every command acked exactly once.
 
-The ~64-session leg rides tier-1 (the obs_smoke/bench_tcp gate's
-in-repo half); the 1k-session leg is `slow`. Neither adds a compiled
+The ~64-session leg rides tier-1; the 1k-session leg is `slow`. Neither adds a compiled
 variant: the servers run the same step shapes every other distributed
 test compiles.
 """

@@ -129,9 +129,10 @@ def test_workload_distinct_rounds_distinct_rows():
 
 
 def _run_legacy(sc, dispatches=3, k=6, p=24):
-    """The pre-resident measured loop: per-dispatch history readback
-    + host latency reconstruction (bench.py BENCH_RESIDENT=0)."""
-    from bench import _latency_rounds
+    """The host-in-the-loop reference of the resident scan:
+    per-dispatch cursor-history readback (``run_fused``) + host latency
+    reconstruction (tests/latency_oracle.py)."""
+    from tests.latency_oracle import latency_rounds
 
     u0, c0 = shard_cursors(sc.cfg, sc.leader, sc.ss)
     U, C = [np.asarray(u0)[None].copy()], [np.asarray(c0)[None].copy()]
@@ -145,7 +146,7 @@ def _run_legacy(sc, dispatches=3, k=6, p=24):
         C.append(c)
         if (u[-1] >= c[-1] - 1).all():
             break
-    return _latency_rounds(np.concatenate(U), np.concatenate(C), 1.0)
+    return latency_rounds(np.concatenate(U), np.concatenate(C), 1.0)
 
 
 def _run_resident(sc, dispatches=3, k=6, p=24):
@@ -160,7 +161,7 @@ def _run_resident(sc, dispatches=3, k=6, p=24):
 
 
 def test_resident_loop_equals_legacy_loop():
-    """BENCH_RESIDENT=0 vs =1 acceptance pin, at test scale: identical
+    """Resident scan vs the host-in-the-loop reference: identical
     committed results AND identical final cluster state from the same
     seed, with the device histogram reproducing the host-side latency
     sample and percentiles exactly."""
@@ -247,7 +248,7 @@ def test_resident_histogram_overflow_bin_reports_tail():
 
 
 def test_resident_buffers_are_donated():
-    """The donation contract the bench artifact stamps (DONATION):
+    """The donation contract (``sharded.DONATION``):
     round state and both bookkeeping buffers are consumed by the
     dispatch — in-place update, no per-dispatch allocation of the big
     tree. (jax marks donated inputs as deleted.)"""

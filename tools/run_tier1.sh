@@ -39,29 +39,18 @@ python tools/obs_smoke.py || exit 1
 echo "== paxmc smoke (bounded model check: 3 protocols + quorum mutant) =="
 env JAX_PLATFORMS=cpu python tools/mc.py --smoke || exit 1
 
-# shape-ladder + resident-loop smoke fourth: two tiny (g, w, p, k)
-# points through the fully device-resident measured loop — commits
-# flow, the drain is exact (in-flight == 0: the latency-accounting
-# contract), the on-device latency histogram is populated, and the
-# autotuner picks a winner (PERF.md resident-loop section). The second
-# point runs with OCCUPANCY-ADAPTIVE capacity on (PR 11): its inbox is
-# derived from the first point's measured occupancy high-water mark
-# (paxray TEL_INBOX_HWM, read on the sanctioned post-window path), and
-# must additionally be LOSSLESS (no proposal dropped) — still exactly two compiled dispatch
-# variants. Budgeted <= 60 s including the jit compile of both.
-echo "== shape-ladder smoke (2-point resident-loop sweep, drain-exact) =="
-env JAX_PLATFORMS=cpu python tools/shape_ladder.py --smoke || exit 1
-
-# paxray smoke fifth: the resident-telemetry observability contract
-# (ISSUE 9) — telemetry-on vs telemetry-off dispatch wall within 2%
-# (min-of-N, order-alternating A/B), byte-identical protocol state,
-# and a validated merged host+device Chrome trace with the device
-# rounds under the reserved pid. JAX is warm from the ladder smoke;
-# ~45 s including the two dispatch-variant compiles.
+# paxray smoke fourth: the device-resident loop and its telemetry
+# contract (ISSUE 9) — telemetry-on vs telemetry-off dispatch wall
+# within 2% (min-of-N, order-alternating A/B), byte-identical protocol
+# state after the drain, and a validated merged host+device Chrome
+# trace with the device rounds under the reserved pid. ~45 s including
+# the two dispatch-variant compiles. (That the drain is exact and the
+# on-device latency histogram complete is held by the resident tests
+# of tests/test_workload.py, in the pytest run below.)
 echo "== paxray smoke (telemetry overhead <=2% + merged device trace) =="
 env JAX_PLATFORMS=cpu python tools/obs_smoke.py --resident || exit 1
 
-# paxchaos smoke sixth: two fixed-seed fault schedules (partition-heal
+# paxchaos smoke fifth: two fixed-seed fault schedules (partition-heal
 # + 10% loss/reorder) against a real in-process cluster, checked with
 # the SAME invariant predicates the model checker just proved at small
 # bounds (ROBUSTNESS.md). Budget clock starts after the first run so
@@ -69,7 +58,7 @@ env JAX_PLATFORMS=cpu python tools/obs_smoke.py --resident || exit 1
 echo "== paxchaos smoke (2 seeded fault schedules + invariant checker) =="
 env JAX_PLATFORMS=cpu python tools/chaos.py --smoke || exit 1
 
-# paxsoak smoke seventh: the scenario driver end-to-end (ISSUE 18) —
+# paxsoak smoke sixth: the scenario driver end-to-end (ISSUE 18) —
 # a 2-phase manifest (warmup + a micro overload burst) through the
 # open-loop sharded swarm against a real cluster, checking EV_PHASE
 # landed on every replica's journal, exactly-once held across shards
