@@ -10,10 +10,14 @@ main paths through the entry points a user calls, at full width:
   mode", the path the benchmark's pod cells measure): ``ShardedCluster``
   + ``begin_resident`` / ``run_resident`` / ``end_resident``, MinPaxos,
   N=5 majority, at ``minpaxos_tpu.deployments``' on-chip shape (g=256,
-  w=4096, p=512: 1,048,576 concurrent instances). Healthy dispatches,
-  kill one follower -> dead rounds -> revive -> reheal on the same
-  compiled variant, drain. With more than one device visible the
-  shard axis is laid over all of them.
+  w=4096, p=512: 1,048,576 concurrent instances). Healthy dispatches
+  (the round's gated recovery section never runs: its gate's count
+  reads 0), the quorum dead with slots in flight (the gate of the
+  leader's retry must open) -> revived, kill one follower -> dead
+  rounds -> revive -> reheal on the same compiled variant, drain. With
+  more than one device visible the shard axis is laid over all of
+  them. (The Mencius pod's recovery has its own program on the chip:
+  tools/mencius_recovery.py.)
 * **Phase B — the served path** (README "Distributed mode", BASELINE
   config 1): a master and three ``-min -durable`` replica servers in
   this process (every replica's step on the chip, fsync on), composed
@@ -64,6 +68,10 @@ ROOT = pathlib.Path(__file__).resolve().parent
 #: retention (w//2 slots) or the victim can never reheal on-device.
 K_ROUNDS = 2
 HEALTHY_DISPATCHES = 3
+#: no-proposal dispatches with the quorum dead and slots in flight:
+#: the leader's stall counter has to reach RETRY_STALL_TICKS, four
+#: rounds after the frontier's last move, for its retry to run
+STALLED_DISPATCHES = 4
 RECOVERY_DISPATCHES = 4
 #: no-proposal dispatches allowed for the drain: until nothing is in
 #: flight AND every replica has committed and executed what the leader
@@ -213,6 +221,17 @@ def phase_a(meter: _CompileMeter, on_tpu: bool, seed: int) -> dict:
                         wall_s=round(time.perf_counter() - t_phase, 1))
     for i in range(1, HEALTHY_DISPATCHES):
         run(K_ROUNDS, p, f"healthy{i}")
+    gates_healthy = sc.resident_tiers()["gates"]
+    # leave fewer replicas than a quorum alive, nothing offered: what
+    # is in flight cannot commit, so the leader's retry must run
+    lost = range(cfg.quorum2 - 1, cfg.n_replicas)
+    for r in lost:
+        sc.kill(r)
+    for i in range(STALLED_DISPATCHES):
+        run(K_ROUNDS, 0, f"stalled{i}")
+    gates_stalled = sc.resident_tiers()["gates"]
+    for r in lost:
+        sc.revive(r)
     sc.kill(victim)
     run(K_ROUNDS, p, "dead")
     leader_at_revive = np.asarray(shard_cursors(cfg, sc.leader, sc.ss)[0])
@@ -237,8 +256,10 @@ def phase_a(meter: _CompileMeter, on_tpu: bool, seed: int) -> dict:
     out["after_setup"] = meter.take()
 
     injected = sum(d["k"] * d["proposals"] for d in dispatches) * g
-    # which tier the rounds of all the legs above took (sharded_round)
+    # which tier the rounds of all the legs above took, and in how
+    # many of them each gated section ran (sharded_round)
     out["tiers"] = sc.resident_tiers()
+    out["gates_healthy"], out["gates_stalled"] = gates_healthy, gates_stalled
     hist = sc.end_resident()
     dropped = int(np.asarray(sc.ss.states.kv.dropped).sum())
     checks["victim_fell_behind"] = bool(
@@ -251,6 +272,8 @@ def phase_a(meter: _CompileMeter, on_tpu: bool, seed: int) -> dict:
     checks["latency_hist_holds_every_commit"] = (
         int(hist.sum()) == injected and int(hist[-1]) == 0)
     checks["kv_dropped_0"] = dropped == 0
+    checks["gates_idle_while_healthy"] = not any(gates_healthy.values())
+    checks["gates_ran_while_stalled"] = all(gates_stalled.values())
     out.update(injected=injected, committed=committed - start_committed,
                rehealed_within_rounds=rehealed_after,
                latency_rounds_p50=int(np.searchsorted(

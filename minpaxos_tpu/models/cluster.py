@@ -173,13 +173,24 @@ def _deliver_inbox(pending: MsgBatch, ext: MsgBatch, alive: jnp.ndarray,
 
 
 def step_replicas(cfg: MinPaxosConfig, cs: ClusterState, ext: MsgBatch,
-                  step_impl=replica_step_impl, rows: int | None = None):
+                  step_impl=replica_step_impl, rows: int | None = None,
+                  steady: bool = False, gates: dict | None = None):
     """Deliver (the first ``rows`` slots of) pending + ext and step all
     replicas of one group: (states', outbox, exec results). ``cfg``
-    comes with ``gate_exec`` already off (see ``cluster_step_impl``)."""
+    comes with ``gate_exec`` already off (see ``cluster_step_impl``).
+    ``steady`` / ``gates``: for a step that takes the one or the other
+    (models/mencius.py, models/minpaxos.py); the gates reach it
+    unbatched, or its conditionals would be selects."""
     with jax.named_scope("px.deliver"):
         inbox = _deliver_inbox(cs.pending, ext, cs.alive, rows)
-    return jax.vmap(functools.partial(step_impl, cfg))(cs.states, inbox)
+    step = functools.partial(step_impl, cfg)
+    if steady:
+        step = functools.partial(step, steady=True)
+    if gates is None:
+        return jax.vmap(step)(cs.states, inbox)
+    return jax.vmap(
+        lambda state, inbox, gates: step(state, inbox, gates=gates),
+        in_axes=(0, 0, None))(cs.states, inbox, gates)
 
 
 def route_outbox(cfg: MinPaxosConfig, outbox, alive: jnp.ndarray) -> MsgBatch:

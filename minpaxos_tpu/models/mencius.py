@@ -69,7 +69,12 @@ from minpaxos_tpu.ops.ackruns import (
     range_vote_coverage,
     scatter_vote_bits,
 )
-from minpaxos_tpu.ops.kvstore import KVState, kv_apply_batch, kv_init
+from minpaxos_tpu.ops.kvstore import (
+    KVState,
+    kv_apply_batch,
+    kv_apply_batch_shared,
+    kv_init,
+)
 from minpaxos_tpu.ops.rankselect import rank_select
 from minpaxos_tpu.ops.scan import commit_frontier, segmented_scan_max
 from minpaxos_tpu.ops.sections import Sections
@@ -152,7 +157,7 @@ def init_mencius(cfg: MinPaxosConfig, me: int) -> MenciusState:
 
 def mencius_step_impl(
     cfg: MinPaxosConfig, state: MenciusState, inbox: MsgBatch,
-    tick_inc=1,
+    tick_inc=1, steady: bool = False,
 ) -> tuple[MenciusState, Outbox, ExecResult]:
     """Advance one Mencius replica by one message batch (pure; vmapped
     by the cluster wrapper below).
@@ -160,12 +165,40 @@ def mencius_step_impl(
     ``tick_inc``: wall ticks this step represents (0 for the trailing
     substeps of a fused burst — see models/minpaxos.py
     replica_step_impl); keeps the stall/takeover counters wall-honest
-    under the TCP runtime's multi-substep dispatches."""
+    under the TCP runtime's multi-substep dispatches.
+
+    ``steady`` (static): trace the step without its recovery sections
+    (those ``recovery_gates`` names), as models/minpaxos.py
+    ``replica_step_impl`` does."""
     with Sections() as sec:
-        return _mencius_step_sections(sec, cfg, state, inbox, tick_inc)
+        return _mencius_step_sections(sec, cfg, state, inbox, tick_inc,
+                                      steady)
 
 
-def _mencius_step_sections(sec, cfg, state, inbox, tick_inc):
+#: the takeover's sections and their gates, as models/minpaxos.py
+#: ``replica_step_impl.recovery_gates`` has the leader's: each a
+#: superset of "some replica's section would write something".
+#:
+#: * 7a answers PREPARE_INST rows and 7b adopts from
+#:   PREPARE_INST_REPLY rows only.
+#: * 10 sweeps, fills and re-drives only where ``stall_ticks >=
+#:   cfg.noop_delay`` (the successor's threshold, the lowest), read
+#:   after section 8 has set the counter to its old value plus
+#:   ``tick_inc`` (1 wherever whole rounds are stepped) or to 0. The
+#:   reset that closes an episode is not part of it and always runs.
+#:
+#: SKIP rows (section 4) are the normal path of an uneven load, not
+#: recovery: a steady step keeps them.
+mencius_step_impl.recovery_gates = {
+    "px.takeover_phase1": lambda cfg, states, present: (
+        present(MsgKind.PREPARE_INST) | present(MsgKind.PREPARE_INST_REPLY)),
+    "px.takeover": lambda cfg, states, present: (
+        # paxlint: disable=wall-honesty -- a bound on it, no update
+        states.stall_ticks + 1 >= cfg.noop_delay).any(),
+}
+
+
+def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     """``mencius_step_impl``'s body; ``sec(name)`` opens the ``px.*``
     scope of the section that follows (ops/sections.py). Sections that
     MinPaxos has too carry its names."""
@@ -466,74 +499,77 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc):
     # promise here blocks my own future ballot-0 writes only if the
     # slot was still NONE (owner priority is forfeited once a takeover
     # ballot touches the slot — tracked via ballot bump below).
-    rel_pi, in_win_pi = _rel(state, inbox.inst, S)
-    rel_pi_safe = jnp.minimum(rel_pi, S - 1)
-    pi_answer = is_pinst & (in_win_pi | (inbox.inst >= state.crt_inst))
-    pi_com = pi_answer & in_win_pi & (state.status[rel_pi_safe] >= COMMITTED)
-    pi_occ = (pi_answer & ~pi_com & in_win_pi
-              & (state.status[rel_pi_safe] >= ACCEPTED))
-    pi_val = pi_com | pi_occ
-    # promise: bump slot ballot so ballot-0 owner writes lose from here
-    prom = pi_answer & ~pi_com & in_win_pi & (
-        inbox.ballot > state.ballot[rel_pi_safe])
-    state = state._replace(
-        ballot=state.ballot.at[jnp.where(prom, rel_pi, S)].max(
-            inbox.ballot, mode="drop"))
-    out = out._replace(
-        kind=jnp.where(pi_com, int(MsgKind.COMMIT),
-                       jnp.where(pi_answer & ~pi_com,
-                                 int(MsgKind.PREPARE_INST_REPLY), out.kind)),
-        src=jnp.where(pi_answer, me, out.src),
-        inst=jnp.where(pi_answer, inbox.inst, out.inst),
-        ballot=jnp.where(pi_val, state.ballot[rel_pi_safe],
-                         jnp.where(pi_answer, NO_BALLOT, out.ballot)),
-        # COMMIT answers carry my real frontier (it feeds receivers'
-        # peer_commits, 9d, and crt_inst, section 6 — echoing the
-        # sweep ballot there poisoned catch-up targeting); PIR answers
-        # echo the sweep ballot as the 7b context tag, as in
-        # models/minpaxos.py 2b
-        last_committed=jnp.where(pi_com, state.committed_upto,
-                                 jnp.where(pi_answer, inbox.ballot,
-                                           out.last_committed)),
-        op=jnp.where(pi_val, state.op[rel_pi_safe],
-                     jnp.where(pi_answer, 0, out.op)),
-        key_hi=jnp.where(pi_val, state.key_hi[rel_pi_safe], out.key_hi),
-        key_lo=jnp.where(pi_val, state.key_lo[rel_pi_safe], out.key_lo),
-        val_hi=jnp.where(pi_val, state.val_hi[rel_pi_safe], out.val_hi),
-        val_lo=jnp.where(pi_val, state.val_lo[rel_pi_safe], out.val_lo),
-        cmd_id=jnp.where(pi_val, state.cmd_id[rel_pi_safe], out.cmd_id),
-        client_id=jnp.where(pi_val, state.client_id[rel_pi_safe],
-                            out.client_id),
-    )
-    dst = jnp.where(pi_answer, inbox.src, dst)
+    # a steady step has no such row: no answer, no promise, no adoption
+    # paxlint: disable=trace-hazard -- `steady` is a static bool
+    if not steady:
+        rel_pi, in_win_pi = _rel(state, inbox.inst, S)
+        rel_pi_safe = jnp.minimum(rel_pi, S - 1)
+        pi_answer = is_pinst & (in_win_pi | (inbox.inst >= state.crt_inst))
+        pi_com = pi_answer & in_win_pi & (state.status[rel_pi_safe] >= COMMITTED)
+        pi_occ = (pi_answer & ~pi_com & in_win_pi
+                  & (state.status[rel_pi_safe] >= ACCEPTED))
+        pi_val = pi_com | pi_occ
+        # promise: bump slot ballot so ballot-0 owner writes lose from here
+        prom = pi_answer & ~pi_com & in_win_pi & (
+            inbox.ballot > state.ballot[rel_pi_safe])
+        state = state._replace(
+            ballot=state.ballot.at[jnp.where(prom, rel_pi, S)].max(
+                inbox.ballot, mode="drop"))
+        out = out._replace(
+            kind=jnp.where(pi_com, int(MsgKind.COMMIT),
+                           jnp.where(pi_answer & ~pi_com,
+                                     int(MsgKind.PREPARE_INST_REPLY), out.kind)),
+            src=jnp.where(pi_answer, me, out.src),
+            inst=jnp.where(pi_answer, inbox.inst, out.inst),
+            ballot=jnp.where(pi_val, state.ballot[rel_pi_safe],
+                             jnp.where(pi_answer, NO_BALLOT, out.ballot)),
+            # COMMIT answers carry my real frontier (it feeds receivers'
+            # peer_commits, 9d, and crt_inst, section 6 — echoing the
+            # sweep ballot there poisoned catch-up targeting); PIR answers
+            # echo the sweep ballot as the 7b context tag, as in
+            # models/minpaxos.py 2b
+            last_committed=jnp.where(pi_com, state.committed_upto,
+                                     jnp.where(pi_answer, inbox.ballot,
+                                               out.last_committed)),
+            op=jnp.where(pi_val, state.op[rel_pi_safe],
+                         jnp.where(pi_answer, 0, out.op)),
+            key_hi=jnp.where(pi_val, state.key_hi[rel_pi_safe], out.key_hi),
+            key_lo=jnp.where(pi_val, state.key_lo[rel_pi_safe], out.key_lo),
+            val_hi=jnp.where(pi_val, state.val_hi[rel_pi_safe], out.val_hi),
+            val_lo=jnp.where(pi_val, state.val_lo[rel_pi_safe], out.val_lo),
+            cmd_id=jnp.where(pi_val, state.cmd_id[rel_pi_safe], out.cmd_id),
+            client_id=jnp.where(pi_val, state.client_id[rel_pi_safe],
+                                out.client_id),
+        )
+        dst = jnp.where(pi_answer, inbox.src, dst)
 
-    # 7b. collect PREPARE_INST_REPLY answers (mine): pvotes + adoption
-    rel_v, in_win_v = _rel(state, inbox.inst, S)
-    rel_v_safe = jnp.minimum(rel_v, S - 1)
-    pv_ok = (is_pir & (inbox.last_committed == state.takeover_ballot)
-             & in_win_v)
-    state = state._replace(
-        pvotes=state.pvotes | scatter_vote_bits(S, rel_v, inbox.src,
-                                                pv_ok, R))
-    pir_ok = (pv_ok & (state.status[rel_v_safe] < COMMITTED)
-              & (inbox.ballot > NO_BALLOT)
-              & (inbox.ballot > state.ballot[rel_v_safe]))
-    vb_max = jnp.full(S + 1, NO_BALLOT, jnp.int32).at[
-        jnp.where(pir_ok, rel_v, S)].max(inbox.ballot, mode="drop")
-    pir_win = pir_ok & (inbox.ballot == vb_max[rel_v_safe])
-    win_v, hit_v = slot_winner(S, rel_v, pir_win)
-    state = state._replace(
-        ballot=gather_row(win_v, hit_v, inbox.ballot, state.ballot),
-        status=gather_const(hit_v, ACCEPTED, state.status),
-        op=gather_row(win_v, hit_v, inbox.op, state.op),
-        key_hi=gather_row(win_v, hit_v, inbox.key_hi, state.key_hi),
-        key_lo=gather_row(win_v, hit_v, inbox.key_lo, state.key_lo),
-        val_hi=gather_row(win_v, hit_v, inbox.val_hi, state.val_hi),
-        val_lo=gather_row(win_v, hit_v, inbox.val_lo, state.val_lo),
-        cmd_id=gather_row(win_v, hit_v, inbox.cmd_id, state.cmd_id),
-        client_id=gather_row(win_v, hit_v, inbox.client_id, state.client_id),
-        votes=gather_const(hit_v, me_bit, state.votes),
-    )
+        # 7b. collect PREPARE_INST_REPLY answers (mine): pvotes + adoption
+        rel_v, in_win_v = _rel(state, inbox.inst, S)
+        rel_v_safe = jnp.minimum(rel_v, S - 1)
+        pv_ok = (is_pir & (inbox.last_committed == state.takeover_ballot)
+                 & in_win_v)
+        state = state._replace(
+            pvotes=state.pvotes | scatter_vote_bits(S, rel_v, inbox.src,
+                                                    pv_ok, R))
+        pir_ok = (pv_ok & (state.status[rel_v_safe] < COMMITTED)
+                  & (inbox.ballot > NO_BALLOT)
+                  & (inbox.ballot > state.ballot[rel_v_safe]))
+        vb_max = jnp.full(S + 1, NO_BALLOT, jnp.int32).at[
+            jnp.where(pir_ok, rel_v, S)].max(inbox.ballot, mode="drop")
+        pir_win = pir_ok & (inbox.ballot == vb_max[rel_v_safe])
+        win_v, hit_v = slot_winner(S, rel_v, pir_win)
+        state = state._replace(
+            ballot=gather_row(win_v, hit_v, inbox.ballot, state.ballot),
+            status=gather_const(hit_v, ACCEPTED, state.status),
+            op=gather_row(win_v, hit_v, inbox.op, state.op),
+            key_hi=gather_row(win_v, hit_v, inbox.key_hi, state.key_hi),
+            key_lo=gather_row(win_v, hit_v, inbox.key_lo, state.key_lo),
+            val_hi=gather_row(win_v, hit_v, inbox.val_hi, state.val_hi),
+            val_lo=gather_row(win_v, hit_v, inbox.val_lo, state.val_lo),
+            cmd_id=gather_row(win_v, hit_v, inbox.cmd_id, state.cmd_id),
+            client_id=gather_row(win_v, hit_v, inbox.client_id, state.client_id),
+            votes=gather_const(hit_v, me_bit, state.votes),
+        )
 
     sec("px.commit_scan")
     # ---- 8. commit scan: my owned slots at majority, frontier ----
@@ -709,90 +745,96 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc):
 
     sec("px.takeover")
     # ---- 10. takeover driver: successor sweeps the blocked range ----
-    blocking = state.committed_upto + 1
-    blk_owner = jnp.mod(blocking, R)
-    i_am_successor = jnp.mod(blk_owner + 1, R) == me
-    # successor-priority avoids ballot duels, but a revived laggard's
-    # frontier view is private — the blocking owner's successor (a live
-    # replica, far ahead) will never sweep FOR it. After a long stall
-    # any stuck replica sweeps its own blocked range, with the
-    # threshold staggered by replica id so that under a global stall
-    # competing sweepers start serialized instead of dueling ballots
-    # on the same tick (the reference staggers forceCommit the same
-    # way, mencius.go:878-886 "50+Id").
-    do_tk = (in_flight
-             & ((i_am_successor & (state.stall_ticks >= cfg.noop_delay))
-                | (state.stall_ticks >= (4 + me) * cfg.noop_delay)))
-    # fresh takeover ballot when starting a new takeover episode
-    new_tb = make_ballot(state.max_recv_ballot // 16 + 1, me)
-    tb = jnp.where(do_tk & (state.takeover_ballot < 0), new_tb,
-                   state.takeover_ballot)
-    fresh = do_tk & (state.takeover_ballot < 0)
-    state = state._replace(
-        takeover_ballot=tb,
-        max_recv_ballot=jnp.maximum(state.max_recv_ballot, tb),
-        pvotes=jnp.where(fresh, jnp.uint16(0), state.pvotes),
-        tk_anchor=jnp.where(fresh, blocking, state.tk_anchor),
-    )
     K2 = cfg.recovery_rows
-    tk_slots = blocking + jnp.arange(K2, dtype=jnp.int32)
-    tk_rel = tk_slots - state.window_base
-    tk_rel_safe = jnp.clip(tk_rel, 0, S - 1)
-    tk_ok = (do_tk & (tk_slots < state.crt_inst) & (tk_rel >= 0)
-             & (tk_rel < S))
-    tk = MsgBatch.empty(K2)._replace(
-        kind=jnp.where(tk_ok, int(MsgKind.PREPARE_INST), 0).astype(jnp.int32),
-        src=jnp.full(K2, me, jnp.int32),
-        ballot=jnp.full(K2, tb, jnp.int32),
-        inst=tk_slots,
-    )
-    tk_row = idx - tk_rel[0]
-    state = state._replace(
-        # tk_rel is a contiguous range: slot s's source row is
-        # s - tk_rel[0], so the OR-delta is a dense select (no scatter)
-        pvotes=state.pvotes | jnp.where(
-            (tk_row >= 0) & (tk_row < K2)
-            & tk_ok[jnp.clip(tk_row, 0, K2 - 1)],
-            me_bit, jnp.uint16(0)))
-    # no-op fill empties with a phase-1 majority; re-drive adopted
-    # values; both as ACCEPTs at the takeover ballot
-    pv_cnt = jax.lax.population_count(state.pvotes).astype(jnp.int32)
-    in_tk_span = (idx_abs >= blocking) & (
-        idx_abs < blocking + K2) & (idx_abs < state.crt_inst)
-    fill = (do_tk & in_tk_span & (state.status == NONE)
-            & (pv_cnt >= quorum1))
-    state = state._replace(
-        status=jnp.where(fill, ACCEPTED, state.status),
-        ballot=jnp.where(fill, tb, state.ballot),
-        op=jnp.where(fill, int(Op.NONE), state.op),
-        cmd_id=jnp.where(fill, 0, state.cmd_id),
-        client_id=jnp.where(fill, -1, state.client_id),
-        votes=jnp.where(fill, me_bit, state.votes),
-    )
-    redrive = (do_tk & in_tk_span & (state.status == ACCEPTED)
-               & ((state.ballot == tb) | (pv_cnt >= quorum1)))
-    bump = redrive & (state.ballot != tb)
-    state = state._replace(
-        ballot=jnp.where(bump, tb, state.ballot),
-        votes=jnp.where(bump, me_bit, state.votes),
-    )
-    rd_slots = blocking + jnp.arange(K2, dtype=jnp.int32)
-    rd_rel_safe = jnp.clip(rd_slots - state.window_base, 0, S - 1)
-    rd_ok = tk_ok & redrive[rd_rel_safe]
-    rd = MsgBatch(
-        kind=jnp.where(rd_ok, int(MsgKind.ACCEPT), 0).astype(jnp.int32),
-        src=jnp.full(K2, me, jnp.int32),
-        ballot=jnp.full(K2, tb, jnp.int32),
-        inst=rd_slots,
-        last_committed=jnp.full(K2, state.committed_upto, jnp.int32),
-        op=state.op[rd_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[rd_rel_safe],
-        key_lo=state.key_lo[rd_rel_safe],
-        val_hi=state.val_hi[rd_rel_safe],
-        val_lo=state.val_lo[rd_rel_safe],
-        cmd_id=state.cmd_id[rd_rel_safe],
-        client_id=state.client_id[rd_rel_safe],
-    )
+    # paxlint: disable=trace-hazard -- `steady` is a static bool
+    if steady:
+        # no stall counter at cfg.noop_delay: no ballot is drawn, no
+        # slot swept, filled or re-driven, and no row is live
+        tk = rd = MsgBatch.empty(K2)
+    else:
+        blocking = state.committed_upto + 1
+        blk_owner = jnp.mod(blocking, R)
+        i_am_successor = jnp.mod(blk_owner + 1, R) == me
+        # successor-priority avoids ballot duels, but a revived laggard's
+        # frontier view is private — the blocking owner's successor (a live
+        # replica, far ahead) will never sweep FOR it. After a long stall
+        # any stuck replica sweeps its own blocked range, with the
+        # threshold staggered by replica id so that under a global stall
+        # competing sweepers start serialized instead of dueling ballots
+        # on the same tick (the reference staggers forceCommit the same
+        # way, mencius.go:878-886 "50+Id").
+        do_tk = (in_flight
+                 & ((i_am_successor & (state.stall_ticks >= cfg.noop_delay))
+                    | (state.stall_ticks >= (4 + me) * cfg.noop_delay)))
+        # fresh takeover ballot when starting a new takeover episode
+        new_tb = make_ballot(state.max_recv_ballot // 16 + 1, me)
+        tb = jnp.where(do_tk & (state.takeover_ballot < 0), new_tb,
+                       state.takeover_ballot)
+        fresh = do_tk & (state.takeover_ballot < 0)
+        state = state._replace(
+            takeover_ballot=tb,
+            max_recv_ballot=jnp.maximum(state.max_recv_ballot, tb),
+            pvotes=jnp.where(fresh, jnp.uint16(0), state.pvotes),
+            tk_anchor=jnp.where(fresh, blocking, state.tk_anchor),
+        )
+        tk_slots = blocking + jnp.arange(K2, dtype=jnp.int32)
+        tk_rel = tk_slots - state.window_base
+        tk_rel_safe = jnp.clip(tk_rel, 0, S - 1)
+        tk_ok = (do_tk & (tk_slots < state.crt_inst) & (tk_rel >= 0)
+                 & (tk_rel < S))
+        tk = MsgBatch.empty(K2)._replace(
+            kind=jnp.where(tk_ok, int(MsgKind.PREPARE_INST), 0).astype(jnp.int32),
+            src=jnp.full(K2, me, jnp.int32),
+            ballot=jnp.full(K2, tb, jnp.int32),
+            inst=tk_slots,
+        )
+        tk_row = idx - tk_rel[0]
+        state = state._replace(
+            # tk_rel is a contiguous range: slot s's source row is
+            # s - tk_rel[0], so the OR-delta is a dense select (no scatter)
+            pvotes=state.pvotes | jnp.where(
+                (tk_row >= 0) & (tk_row < K2)
+                & tk_ok[jnp.clip(tk_row, 0, K2 - 1)],
+                me_bit, jnp.uint16(0)))
+        # no-op fill empties with a phase-1 majority; re-drive adopted
+        # values; both as ACCEPTs at the takeover ballot
+        pv_cnt = jax.lax.population_count(state.pvotes).astype(jnp.int32)
+        in_tk_span = (idx_abs >= blocking) & (
+            idx_abs < blocking + K2) & (idx_abs < state.crt_inst)
+        fill = (do_tk & in_tk_span & (state.status == NONE)
+                & (pv_cnt >= quorum1))
+        state = state._replace(
+            status=jnp.where(fill, ACCEPTED, state.status),
+            ballot=jnp.where(fill, tb, state.ballot),
+            op=jnp.where(fill, int(Op.NONE), state.op),
+            cmd_id=jnp.where(fill, 0, state.cmd_id),
+            client_id=jnp.where(fill, -1, state.client_id),
+            votes=jnp.where(fill, me_bit, state.votes),
+        )
+        redrive = (do_tk & in_tk_span & (state.status == ACCEPTED)
+                   & ((state.ballot == tb) | (pv_cnt >= quorum1)))
+        bump = redrive & (state.ballot != tb)
+        state = state._replace(
+            ballot=jnp.where(bump, tb, state.ballot),
+            votes=jnp.where(bump, me_bit, state.votes),
+        )
+        rd_slots = blocking + jnp.arange(K2, dtype=jnp.int32)
+        rd_rel_safe = jnp.clip(rd_slots - state.window_base, 0, S - 1)
+        rd_ok = tk_ok & redrive[rd_rel_safe]
+        rd = MsgBatch(
+            kind=jnp.where(rd_ok, int(MsgKind.ACCEPT), 0).astype(jnp.int32),
+            src=jnp.full(K2, me, jnp.int32),
+            ballot=jnp.full(K2, tb, jnp.int32),
+            inst=rd_slots,
+            last_committed=jnp.full(K2, state.committed_upto, jnp.int32),
+            op=state.op[rd_rel_safe].astype(jnp.int32),
+            key_hi=state.key_hi[rd_rel_safe],
+            key_lo=state.key_lo[rd_rel_safe],
+            val_hi=state.val_hi[rd_rel_safe],
+            val_lo=state.val_lo[rd_rel_safe],
+            cmd_id=state.cmd_id[rd_rel_safe],
+            client_id=state.client_id[rd_rel_safe],
+        )
     # takeover episode ends when the frontier moves again
     state = state._replace(
         takeover_ballot=jnp.where(advanced, jnp.int32(NO_BALLOT),
@@ -888,7 +930,9 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc):
         evalid = slot_of < S
         slot_of_safe = jnp.clip(slot_of, 0, S - 1)
         op_e = jnp.where(evalid, st.op[slot_of_safe].astype(jnp.int32), 0)
-        kv, o_hi, o_lo, o_found = kv_apply_batch(
+        # the vmapped compositions share one trace of the apply
+        apply = kv_apply_batch if cfg.gate_exec else kv_apply_batch_shared
+        kv, o_hi, o_lo, o_found = apply(
             st.kv,
             op_e,
             st.key_hi[slot_of_safe],

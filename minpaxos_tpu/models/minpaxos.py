@@ -43,7 +43,12 @@ from minpaxos_tpu.ops.ackruns import (
     range_vote_coverage,
     scatter_vote_bits,
 )
-from minpaxos_tpu.ops.kvstore import KVState, kv_apply_batch, kv_init
+from minpaxos_tpu.ops.kvstore import (
+    KVState,
+    kv_apply_batch,
+    kv_apply_batch_shared,
+    kv_init,
+)
 from minpaxos_tpu.ops.scan import commit_frontier
 from minpaxos_tpu.ops.sections import Sections
 from minpaxos_tpu.wire.messages import MsgKind
@@ -389,7 +394,7 @@ def _rel(state: ReplicaState, inst, window: int):
 
 def replica_step_impl(
     cfg: MinPaxosConfig, state: ReplicaState, inbox: MsgBatch,
-    tick_inc=1,
+    tick_inc=1, gates: dict | None = None,
 ) -> tuple[ReplicaState, Outbox, ExecResult]:
     """Advance one replica by one batch of messages (pure, unjitted —
     models/cluster.py vmaps this over the replica axis).
@@ -405,12 +410,49 @@ def replica_step_impl(
     round-5 threshold tuning removed. The fused path passes 1 for the
     first substep and 0 for the rest; every other caller uses the
     default 1.
+
+    ``gates``: this round's ``recovery_gates`` (below), by section, as
+    bool scalars that are NOT batched by the vmaps that batch the step
+    (parallel/sharded.py ``sharded_round`` hands them in): a section
+    whose gate is shut is skipped by a ``lax.cond``, which on a
+    per-replica predicate would lower to a select and run both sides.
+    Every other caller passes none and runs every section in line.
     """
     with Sections() as sec:
-        return _replica_step_sections(sec, cfg, state, inbox, tick_inc)
+        return _replica_step_sections(sec, cfg, state, inbox, tick_inc,
+                                      gates)
 
 
-def _replica_step_sections(sec, cfg, state, inbox, tick_inc):
+#: stalled ticks after which the leader re-sends its in-flight slots
+#: (7d, whose comment says why 4)
+RETRY_STALL_TICKS = 4
+
+#: the recovery sections of the step that a caller can gate, by
+#: ``px.*`` scope, each with its GATE: ``gate(cfg, states, present)``
+#: says, from what can be seen before a step, whether the section can
+#: have work in it, as one bool scalar over ALL the replicas of
+#: ``states`` (any leading axes); ``present(kind)``: a row of that kind
+#: is in some inbox. A gate is a SUPERSET of "some replica's section
+#: would write something": it may be open needlessly and is never shut
+#: on a section that matters, so the bytes are the same either way
+#: (tests/test_route_fabric.py holds it to that).
+#:
+#: 7d acts only where ``stall_ticks >= RETRY_STALL_TICKS``, read AFTER
+#: 7b has set the counter to its old value plus ``tick_inc`` (1
+#: wherever whole rounds are stepped) or to 0: no counter one short of
+#: the threshold before the step, none at it inside. (1c and 2b, whose
+#: gates would be a PREPARE_INST_REPLY / PREPARE_INST row present, and
+#: 7e are not gated: PERF.md section 7 rows 14 and 16.)
+replica_step_impl.recovery_gates = {
+    "px.retry": lambda cfg, states, present: (
+        # paxlint: disable=wall-honesty -- a bound on it, no update
+        states.stall_ticks + 1 >= RETRY_STALL_TICKS).any(),
+}
+#: the step skips them itself, by a conditional on ``gates``
+replica_step_impl.takes_gates = True
+
+
+def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     """``replica_step_impl``'s body; ``sec(name)`` opens the ``px.*``
     scope of the section that follows (ops/sections.py)."""
     S, R = cfg.window, cfg.n_replicas
@@ -1052,70 +1094,93 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc):
     # in-flight accept once per op — pure duplicate traffic that the
     # followers then re-ack (round-5 trace). Genuinely lost accepts
     # still retry within ~4 ticks (milliseconds).
-    do_rt = state.is_leader & state.prepared & (state.stall_ticks >= 4)
-    rt_slots = state.committed_upto + 1 + jnp.arange(K, dtype=jnp.int32)
-    rt_rel = rt_slots - state.window_base
-    rt_rel_safe = jnp.clip(rt_rel, 0, S - 1)
-    rt_in = do_rt & (rt_slots < state.crt_inst) & (rt_rel >= 0) & (rt_rel < S)
-    rt_empty = rt_in & (state.status[rt_rel_safe] == NONE)
-    # A gap slot may be no-op filled ONLY when a majority (self
-    # included) answered the current-ballot per-instance phase 1 with
-    # "no value" (pvotes, fed by the 7e sweep). This is the Paxos
-    # phase-1 safety condition; the old time-based heuristic
-    # (stall_ticks >= noop_delay) could fill a slot whose committed
-    # value simply hadn't been transferred yet.
-    pv_cnt = jax.lax.population_count(
-        state.pvotes[rt_rel_safe]).astype(jnp.int32)
-    noop_fill = rt_empty & (pv_cnt >= quorum1)
-    # A slot holding a value adopted from phase-1 answers (ballot !=
-    # default_ballot) may be re-driven at the current ballot ONLY after
-    # a majority answered the per-instance phase 1: the adopted value
-    # is then the max-vballot value over a majority — the classic Paxos
-    # phase-2 precondition. Re-driving off a single early answer could
-    # push a superseded value over a committed one (the superseding
-    # higher-vballot answer lands via 1c only later). Slots already at
-    # the current ballot were driven by this leader (safe); committed
-    # slots carry the decided value (safe).
-    own_ballot = state.ballot[rt_rel_safe] == state.default_ballot
-    settled = (pv_cnt >= quorum1) | (state.status[rt_rel_safe] >= COMMITTED)
-    rt_ok = rt_in & (
-        ((state.status[rt_rel_safe] >= ACCEPTED) & (own_ballot | settled))
-        | noop_fill)
-    # bump retried slots to the current ballot (resetting votes when
-    # the ballot actually changes), so follower acks count
-    bump = rt_ok & (state.ballot[rt_rel_safe] != state.default_ballot)
-    # rt_rel is the contiguous range [rt_rel[0], rt_rel[0]+K): each
-    # slot's source row is arithmetic (slot - rt_rel[0]) — the masked
-    # writes become dense gathers with NO scatter (ops/winner.py)
-    sidx = jnp.arange(S, dtype=jnp.int32)
-    rt_row = sidx - rt_rel[0]
-    rt_row_safe = jnp.clip(rt_row, 0, K - 1)
-    in_rt = (rt_row >= 0) & (rt_row < K)
-    hit_b = in_rt & bump[rt_row_safe]
-    hit_n = in_rt & noop_fill[rt_row_safe]
-    state = state._replace(
-        ballot=jnp.where(hit_b, state.default_ballot, state.ballot),
-        status=jnp.where(hit_n, jnp.asarray(ACCEPTED, state.status.dtype),
-                         state.status),
-        op=jnp.where(hit_n, jnp.uint8(0), state.op),
-        cmd_id=jnp.where(hit_n, 0, state.cmd_id),
-        client_id=jnp.where(hit_n, -1, state.client_id),
-        votes=jnp.where(hit_b, me_bit, state.votes),
-    )
-    rt = MsgBatch(
-        kind=jnp.where(rt_ok, int(MsgKind.ACCEPT), 0).astype(jnp.int32),
-        src=jnp.full(K, state.me, jnp.int32),
-        ballot=jnp.full(K, state.default_ballot, jnp.int32),
-        inst=rt_slots,
-        last_committed=jnp.full(K, state.committed_upto, jnp.int32),
-        op=state.op[rt_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[rt_rel_safe],
-        key_lo=state.key_lo[rt_rel_safe],
-        val_hi=state.val_hi[rt_rel_safe],
-        val_lo=state.val_lo[rt_rel_safe],
-        cmd_id=state.cmd_id[rt_rel_safe],
-        client_id=state.client_id[rt_rel_safe],
-    )
+    def retry():
+        do_rt = state.is_leader & state.prepared & (
+            state.stall_ticks >= RETRY_STALL_TICKS)
+        rt_slots = state.committed_upto + 1 + jnp.arange(K, dtype=jnp.int32)
+        rt_rel = rt_slots - state.window_base
+        rt_rel_safe = jnp.clip(rt_rel, 0, S - 1)
+        rt_in = do_rt & (rt_slots < state.crt_inst) & (rt_rel >= 0) & (
+            rt_rel < S)
+        rt_empty = rt_in & (state.status[rt_rel_safe] == NONE)
+        # A gap slot may be no-op filled ONLY when a majority (self
+        # included) answered the current-ballot per-instance phase 1
+        # with "no value" (pvotes, fed by the 7e sweep). This is the
+        # Paxos phase-1 safety condition; the old time-based heuristic
+        # (stall_ticks >= noop_delay) could fill a slot whose committed
+        # value simply hadn't been transferred yet.
+        pv_cnt = jax.lax.population_count(
+            state.pvotes[rt_rel_safe]).astype(jnp.int32)
+        noop_fill = rt_empty & (pv_cnt >= quorum1)
+        # A slot holding a value adopted from phase-1 answers (ballot
+        # != default_ballot) may be re-driven at the current ballot
+        # ONLY after a majority answered the per-instance phase 1: the
+        # adopted value is then the max-vballot value over a majority —
+        # the classic Paxos phase-2 precondition. Re-driving off a
+        # single early answer could push a superseded value over a
+        # committed one (the superseding higher-vballot answer lands
+        # via 1c only later). Slots already at the current ballot were
+        # driven by this leader (safe); committed slots carry the
+        # decided value (safe).
+        own_ballot = state.ballot[rt_rel_safe] == state.default_ballot
+        settled = (pv_cnt >= quorum1) | (
+            state.status[rt_rel_safe] >= COMMITTED)
+        rt_ok = rt_in & (
+            ((state.status[rt_rel_safe] >= ACCEPTED)
+             & (own_ballot | settled))
+            | noop_fill)
+        # bump retried slots to the current ballot (resetting votes
+        # when the ballot actually changes), so follower acks count
+        bump = rt_ok & (state.ballot[rt_rel_safe] != state.default_ballot)
+        # rt_rel is the contiguous range [rt_rel[0], rt_rel[0]+K): each
+        # slot's source row is arithmetic (slot - rt_rel[0]) — the
+        # masked writes become dense gathers with NO scatter
+        # (ops/winner.py)
+        sidx = jnp.arange(S, dtype=jnp.int32)
+        rt_row = sidx - rt_rel[0]
+        rt_row_safe = jnp.clip(rt_row, 0, K - 1)
+        in_rt = (rt_row >= 0) & (rt_row < K)
+        hit_b = in_rt & bump[rt_row_safe]
+        hit_n = in_rt & noop_fill[rt_row_safe]
+        ballot = jnp.where(hit_b, state.default_ballot, state.ballot)
+        status = jnp.where(hit_n, jnp.asarray(ACCEPTED, state.status.dtype),
+                           state.status)
+        op = jnp.where(hit_n, jnp.uint8(0), state.op)
+        cmd_id = jnp.where(hit_n, 0, state.cmd_id)
+        client_id = jnp.where(hit_n, -1, state.client_id)
+        votes = jnp.where(hit_b, me_bit, state.votes)
+        rt = MsgBatch(
+            kind=jnp.where(rt_ok, int(MsgKind.ACCEPT), 0).astype(jnp.int32),
+            src=jnp.full(K, state.me, jnp.int32),
+            ballot=jnp.full(K, state.default_ballot, jnp.int32),
+            inst=rt_slots,
+            last_committed=jnp.full(K, state.committed_upto, jnp.int32),
+            op=op[rt_rel_safe].astype(jnp.int32),
+            key_hi=state.key_hi[rt_rel_safe],
+            key_lo=state.key_lo[rt_rel_safe],
+            val_hi=state.val_hi[rt_rel_safe],
+            val_lo=state.val_lo[rt_rel_safe],
+            cmd_id=cmd_id[rt_rel_safe],
+            client_id=client_id[rt_rel_safe],
+        )
+        return (ballot, status, op, cmd_id, client_id, votes), rt, sidx
+
+    def no_retry():
+        # no stall counter at RETRY_STALL_TICKS: do_rt is false in
+        # every replica, so no slot is written and no row is live (the
+        # fabric never counts or copies a row of kind 0)
+        return ((state.ballot, state.status, state.op, state.cmd_id,
+                 state.client_id, state.votes), MsgBatch.empty(K),
+                jnp.arange(S, dtype=jnp.int32))
+
+    # sidx: the window's slot index, which 7e and 8 read too; it is
+    # made inside the section so that the step without gates stays the
+    # program it was, equation for equation
+    (ballot, status, op, cmd_id, client_id, votes), rt, sidx = (
+        retry() if gates is None
+        else jax.lax.cond(gates["px.retry"], retry, no_retry))
+    state = state._replace(ballot=ballot, status=status, op=op,
+                           cmd_id=cmd_id, client_id=client_id, votes=votes)
 
     sec("px.sweep")
     # ---- 7e. per-instance phase-1 sweep (new-leader value discovery,
@@ -1190,8 +1255,8 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc):
     # fixed block; steps with nothing to execute (pure propose/accept
     # traffic — 2 of the ~3 steps on a serial op's path) skip it
     # entirely via cond instead of running it over all-invalid rows
-    def _exec_kv(kv):
-        return kv_apply_batch(
+    def _exec_kv(kv, apply=kv_apply_batch):
+        return apply(
             kv, op_e, state.key_hi[rel_e_safe], state.key_lo[rel_e_safe],
             state.val_hi[rel_e_safe], state.val_lo[rel_e_safe], evalid)
 
@@ -1203,7 +1268,7 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc):
         kv, o_hi, o_lo, o_found = jax.lax.cond(
             n_exec > 0, _exec_kv, _no_exec, state.kv)
     else:  # vmapped composition: cond would run both branches anyway
-        kv, o_hi, o_lo, o_found = _exec_kv(state.kv)
+        kv, o_hi, o_lo, o_found = _exec_kv(state.kv, kv_apply_batch_shared)
     state = state._replace(
         kv=kv,
         executed_upto=state.executed_upto + n_exec,

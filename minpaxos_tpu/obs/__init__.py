@@ -129,11 +129,12 @@ def process_collection() -> list[dict]:
 
 #: the newest sharded pods of this process (parallel/sharded.py
 #: ``ShardedCluster``), each the dict it registered at construction:
-#: its shape and, under ``tiers``, the resident loop's tier counts as of
-#: the pod's last post-window read (None before one), with a
-#: multi-owner pod's ``command_commits`` and ``noop_slots`` of the same
-#: rounds (None for a single-leader pod). Apart from
-#: ``_PROCESS_REPLICAS``, whose entries are replicas with recorder rows.
+#: its shape and, under ``tiers`` and ``gates``, the resident loop's
+#: tier and recovery-gate counts as of the pod's last post-window read
+#: (None before one), with a multi-owner pod's ``command_commits`` and
+#: ``noop_slots`` of the same rounds (None for a single-leader pod).
+#: Apart from ``_PROCESS_REPLICAS``, whose entries are replicas with
+#: recorder rows.
 _PROCESS_PODS: collections.deque = collections.deque(maxlen=16)
 
 
@@ -148,11 +149,15 @@ def process_pods() -> list[dict]:
     """Per registered pod, oldest first: ``protocol``, ``n_shards``,
     ``n_replicas``, ``inbox``, ``working_capacity``, ``tiers``
     (``kernel_small_rounds``, ``route_small_rounds``, ``rounds`` from
-    the last ``begin_resident`` to the last post-window read, or None)
-    and, over the same rounds, a multi-owner (Mencius) pod's
+    the last ``begin_resident`` to the last post-window read, or None),
+    ``gates`` (over the same rounds, per recovery section of the step,
+    by its ``px.*`` scope, the rounds in which its gate was OPEN:
+    ``{"px.retry": 0}``, of ``tiers["rounds"]``; or None) and, over the
+    same rounds, a multi-owner (Mencius) pod's
     ``command_commits`` and ``noop_slots`` (else None). Copies taken
     now."""
-    return [dict(p, tiers=p["tiers"] and dict(p["tiers"]))
+    return [dict(p, tiers=p["tiers"] and dict(p["tiers"]),
+                 gates=p["gates"] and dict(p["gates"]))
             for p in list(_PROCESS_PODS)]
 
 

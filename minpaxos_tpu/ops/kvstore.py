@@ -333,3 +333,13 @@ def kv_apply_batch(kv: KVState, op, k_hi, k_lo, v_hi, v_lo, valid):
     v = jnp.stack([v_hi, v_lo], axis=1)
     kv, out, found = kv_apply_batch_lanes(kv, op, k_hi, k_lo, v, valid)
     return kv, out[:, 0], out[:, 1], found
+
+
+#: ``kv_apply_batch`` as an inlined jit, for the vmapped compositions
+#: (``cfg.gate_exec`` off): the program that holds it is what it was,
+#: but the body is TRACED, and batched under the pod's two vmaps, once
+#: per shape in a process instead of once per kernel variant. Its
+#: arguments do not depend on the inbox rows, so the election's kernel
+#: and both tiers of a fused dispatch share the one trace, which was
+#: 3.3 s of each kernel trace on the chip's host (PERF.md, PR 31).
+kv_apply_batch_shared = jax.jit(kv_apply_batch, inline=True)

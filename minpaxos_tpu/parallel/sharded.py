@@ -167,11 +167,48 @@ def small_tier_rows(cfg: MinPaxosConfig, ext_rows: int, owners: bool) -> int:
     return rows
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _step_groups(cfg: MinPaxosConfig, step, rows: int, ss: ClusterState,
-                 ext: MsgBatch):
+def recovery_sections(step) -> tuple:
+    """The ``px.*`` scopes of the recovery sections ``step`` declares
+    (``step.recovery_gates``: models/minpaxos.py, models/mencius.py),
+    in the order every per-section array here has; () for a step that
+    declares none."""
+    return tuple(getattr(step, "recovery_gates", ()))
+
+
+def recovery_gates(cfg: MinPaxosConfig, step, ss: ClusterState,
+                   ext: MsgBatch) -> dict:
+    """This round's whole-chip recovery gates: per recovery section of
+    ``step``, a bool scalar: can the section have work in the round to
+    come, from what can be seen before it steps, as the step's module
+    declares it (where each is shown to be a superset). Taken OUTSIDE
+    the vmap over groups, over all groups at once.
+
+    A message kind is looked for in every pending slot and every ext
+    row, which is more than the step is delivered (a dead replica's
+    rows are silenced, and the small tier cuts padding alone)."""
+    def present(kind):
+        return ((ss.pending.kind == int(kind)).any()
+                | (ext.kind == int(kind)).any())
+
+    return {section: gate(cfg, ss.states, present) for section, gate
+            in getattr(step, "recovery_gates", {}).items()}
+
+
+def _takes_gates(step) -> bool:
+    """Whether ``step`` skips its recovery sections itself, by a
+    conditional on the gates it is handed (models/minpaxos.py); else a
+    step that declares such sections has a ``steady`` form without
+    them (models/mencius.py)."""
+    return getattr(step, "takes_gates", False)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _step_groups(cfg: MinPaxosConfig, step, rows: int, steady: bool,
+                 ss: ClusterState, ext: MsgBatch, gates: dict | None):
     """Every group's replicas delivered ``rows`` pending slots + ext and
     stepped: (states', outboxes, exec results), leading axes [G, R].
+    ``steady`` (static) and ``gates`` (through both vmaps unbatched)
+    are the step's own arguments.
 
     A jit of its own so that the kernel is TRACED once per shape in a
     process, not once per program that holds it: ``sharded_step`` (the
@@ -179,49 +216,81 @@ def _step_groups(cfg: MinPaxosConfig, step, rows: int, ss: ClusterState,
     call it with the same arguments, and tracing the kernel is seconds
     of every warm set-up (the compile cache spares the compile, never
     the trace). It is inlined where it is called."""
-    return jax.vmap(functools.partial(
-        step_replicas, cfg, step_impl=step, rows=rows))(ss, ext)
+    return jax.vmap(
+        lambda cs, ext, gates: step_replicas(cfg, cs, ext, step, rows,
+                                             steady, gates),
+        in_axes=(0, 0, None))(ss, ext, gates)
+
+
+def _open_gates(step) -> dict | None:
+    """Constant gates, all open, for a step that takes gates (its
+    kernel is then the one a fused dispatch's full tier traces); None
+    for any other."""
+    if not _takes_gates(step):
+        return None
+    return dict.fromkeys(recovery_sections(step), jnp.ones((), bool))
 
 
 def _one_tier_round(cfg: MinPaxosConfig, step, ss: ClusterState,
-                    ext: MsgBatch):
+                    ext: MsgBatch, gates: dict | None):
     """``jax.vmap(cluster_step_impl)`` with the outboxes kept:
     (ss', exec results, outboxes)."""
-    states, outbox, execr = _step_groups(cfg, step, cfg.inbox, ss, ext)
+    states, outbox, execr = _step_groups(cfg, step, cfg.inbox, False, ss,
+                                         ext, gates)
     pending = jax.vmap(functools.partial(route_outbox, cfg))(outbox,
                                                              ss.alive)
     return ClusterState(states, pending, ss.alive), execr, outbox
 
 
+def _round_kernels(cfg: MinPaxosConfig, step, work_rows: int) -> list:
+    """The kernel variants of a two-tier round, (rows, steady) each, in
+    the order ``sharded_round`` indexes them: the configured capacity,
+    the working capacity and, for a step with a steady form, the
+    working capacity without its recovery sections."""
+    kernels = [(cfg.inbox, False), (work_rows, False)]
+    if recovery_sections(step) and not _takes_gates(step):
+        kernels.append((work_rows, True))
+    return kernels
+
+
 def _pretrace_kernels(cfg: MinPaxosConfig, step, ss: ClusterState,
-                      ext_rows: int) -> None:
-    """Trace the kernel variants of a fused dispatch at the TOP of its
-    jit, before its scan; the scan's ``cond`` branches then find them
-    in jit's trace cache. On the chip's host a kernel trace costs
-    2.1 s here, 4.3 s inside the scan's body and 7.3 s inside a
-    ``cond`` branch there (trace only, one process; my chip runs,
-    PR 27; the sandbox shows no such difference), and every warm
-    set-up pays for it, whatever the compile cache holds."""
+                      ext_rows: int, tiers: bool = True) -> None:
+    """Trace the kernel variants of a fused dispatch (``tiers`` off:
+    the configured capacity's alone, which ``sharded_step`` runs too)
+    BEFORE the program that holds them is traced; its ``cond`` branches
+    then find them in jit's trace cache. Where a kernel is traced
+    decides what the trace costs on the chip's host: 1.7 s outside any
+    jit (``ShardedCluster`` does that), 2.1 s at the top of one (the
+    fused dispatches do, for a caller that has not), 4.3 s inside a
+    scan's body and 7.3 s inside a ``cond`` branch there (trace only,
+    one process; my chip runs, PRs 27 and 31; the sandbox shows no
+    such difference), and every warm set-up pays for it, whatever the
+    compile cache holds."""
     cfg = cfg._replace(gate_exec=False)
     ext = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(ss.alive.shape + (ext_rows,), x.dtype),
         MsgBatch.empty(1))
-    tiers = {cfg.inbox} if cfg.route_fabric == "dense" else {
-        small_tier_rows(cfg, ext_rows, _has_owners(ss.states)), cfg.inbox}
-    for rows in tiers:
-        _step_groups.trace(cfg, step, rows, ss, ext)
+    gates = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), _open_gates(step))
+    small = small_tier_rows(cfg, ext_rows, _has_owners(ss.states))
+    kernels = [(cfg.inbox, False)]
+    if tiers and small < cfg.inbox and cfg.route_fabric != "dense":
+        kernels = _round_kernels(cfg, step, small)
+    for rows, steady in kernels:
+        _step_groups.trace(cfg, step, rows, steady, ss, ext, gates)
 
 
 def sharded_round(cfg: MinPaxosConfig, step, work_rows: int,
                   ss: ClusterState, ext: MsgBatch):
     """One synchronous round for every shard, [G, R, ...] in and out,
-    at the rows that are live: what ``jax.vmap(cluster_step_impl)``
-    computes, byte for byte, from kernels and a route called at a
-    smaller static shape whenever this round's rows fit it.
+    at the rows that are live and the sections that can have work: what
+    ``jax.vmap(cluster_step_impl)`` computes, byte for byte, from
+    kernels and a route called at a smaller static shape whenever this
+    round's rows fit it.
 
     Routing packs each destination's rows to a PREFIX of its inbox, so
-    the padding is a suffix that can be cut without moving a row. Both
-    choices are whole-chip scalars taken OUTSIDE the vmap over groups
+    the padding is a suffix that can be cut without moving a row. Every
+    choice is a whole-chip scalar taken OUTSIDE the vmap over groups
     (inside it a ``lax.cond`` lowers to a select and runs both sides):
 
     * kernel tier: when no live row lies at or beyond slot
@@ -238,28 +307,50 @@ def sharded_round(cfg: MinPaxosConfig, step, work_rows: int,
       and zero-pad to ``cfg.inbox`` so the carried state keeps its
       shape, else ``cfg.inbox`` slots. Overflow beyond ``cfg.inbox``
       drops as it always did.
+    * recovery sections: a section whose ``recovery_gates`` entry is
+      shut has every write mask false in this round, and is skipped.
+      A step that takes gates is handed them and skips by a
+      conditional of its own, in both tiers. Any other step that
+      declares such sections gets a third kernel, the small tier's
+      traced ``steady`` (without them), taken when the rows fit AND
+      every gate is shut; a round with one open runs the kernel with
+      every section, at the tier its rows fit, as it always did.
 
-    Both tiers are in the one compiled program: a round that falls
+    (Two ways, for no reason in the protocols. Kernels of unequal
+    structure under one ``cond`` cost the MinPaxos pod its fast
+    gathers, in every arrangement tried; and conditionals in Mencius's
+    step took its warm set-up past its bound when they were measured,
+    which was before ``ShardedCluster`` traced its kernels outside any
+    jit: PERF.md section 7 rows 15 and 14, which says what to measure
+    next for one way to serve both.)
+
+    All variants are in the one compiled program: a round that falls
     back compiles nothing. With ``work_rows >= cfg.inbox`` (or the
-    dense fabric, which has no counts to look at) there is one tier and
-    the program is ``jax.vmap(cluster_step_impl)``.
+    dense fabric, which has no counts to look at) there is one kernel.
 
-    Returns (ss', exec results, small); ``small`` is bool[2]: this
-    round's kernel, and its route, ran at ``work_rows``.
+    Returns (ss', exec results, small, open); ``small`` is bool[2]:
+    this round's kernel, and its route, ran at ``work_rows``; ``open``
+    is bool[``recovery_sections``]: the section's gate was open.
     """
     cfg = cfg._replace(gate_exec=False)  # see cluster_step_impl
     full = cfg.inbox
+    gates = recovery_gates(cfg, step, ss, ext)
+    gate_open = (jnp.stack(list(gates.values())) if gates
+                 else jnp.zeros(0, bool))
+    if not _takes_gates(step):
+        gates = None
     if work_rows >= full or cfg.route_fabric == "dense":
-        ss, execr, _ = _one_tier_round(cfg, step, ss, ext)
-        return ss, execr, jnp.zeros(2, bool)
+        ss, execr, _ = _one_tier_round(cfg, step, ss, ext, gates)
+        return ss, execr, jnp.zeros(2, bool), gate_open
 
     def fill(slots):
         return jax.vmap(functools.partial(
             _fill_inboxes, slots=slots, capacity=full))
 
-    def kernel(rows):
+    def kernel(rows, steady):
         def run(ss, ext):
-            states, outbox, execr = _step_groups(cfg, step, rows, ss, ext)
+            states, outbox, execr = _step_groups(cfg, step, rows, steady,
+                                                 ss, ext, gates)
             flat, cnt = jax.vmap(_pool_counts)(outbox.msgs, outbox.dst,
                                                ss.alive)
             route_small = cnt[..., -1].max() <= work_rows
@@ -270,9 +361,16 @@ def sharded_round(cfg: MinPaxosConfig, step, work_rows: int,
         return run
 
     kernel_small = ~(ss.pending.kind[..., work_rows:] != 0).any()
-    ss, execr, route_small = jax.lax.cond(
-        kernel_small, kernel(work_rows), kernel(full), ss, ext)
-    return ss, execr, jnp.stack([kernel_small, route_small])
+    full_k, small_k, *steady_k = [
+        kernel(*k) for k in _round_kernels(cfg, step, work_rows)]
+    if steady_k:
+        which = jnp.where(kernel_small, 2 - gate_open.any(), 0)
+        ss, execr, route_small = jax.lax.switch(
+            which, [full_k, small_k, *steady_k], ss, ext)
+    else:
+        ss, execr, route_small = jax.lax.cond(kernel_small, small_k, full_k,
+                                              ss, ext)
+    return ss, execr, jnp.stack([kernel_small, route_small]), gate_open
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3), donate_argnums=1)
@@ -286,11 +384,12 @@ def sharded_step(cfg: MinPaxosConfig, ss: ClusterState, ext: MsgBatch,
     communication. One tier, at the configured capacity: this is the
     host-in-the-loop entry (elections, tests, the multi-host worker),
     and a second kernel variant here is seconds of every set-up; the
-    fused dispatches below take ``sharded_round``.
+    fused dispatches below take ``sharded_round``. Every section runs
+    (``_open_gates``).
     """
     step = replica_step_impl if step_impl is None else step_impl
     ss, execr, outbox = _one_tier_round(
-        cfg._replace(gate_exec=False), step, ss, ext)
+        cfg._replace(gate_exec=False), step, ss, ext, _open_gates(step))
     return (ss, execr, *client_rows_of(outbox))
 
 
@@ -480,7 +579,7 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
             before = _owner_cursors(ss.states, cursor_rep)
         ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
                              n_proposals, leader, round0 + t, key_t, val_t)
-        ss, _, _ = cstep(ss, ext)
+        ss, *_ = cstep(ss, ext)
         # drain-only sub-steps: deliver queued traffic, no new work —
         # the ext batch is ZERO-WIDTH, not zero-filled, so the kernel
         # (and the routed pool behind it) runs at the inbox capacity
@@ -488,7 +587,7 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         # was inert anyway, so the commit stream is unchanged (PR 11)
         ext0 = jax.tree_util.tree_map(lambda x: x[..., :0], ext)
         for _ in range(substeps - 1):
-            ss, _, _ = cstep(ss, ext0)
+            ss, *_ = cstep(ss, ext0)
         if owners:
             counts, _, _ = _count_round(cfg, cursor_rep, before, ss.states,
                                         counts)
@@ -502,13 +601,13 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
 
 # paxlint: resident-loop
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 13, 14, 15),
-                   donate_argnums=(4, 5, 6, 7, 8, 17))
+                   donate_argnums=(4, 5, 6, 7, 8, 17, 18))
 def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                          k_rounds: int, ss: ClusterState, inject_round,
                          lat_hist, telemetry, tiers, n_proposals, leader,
                          round0, seed=0, step_impl=None,
                          key_space: int = 1 << 20, substeps: int = 1,
-                         tel_base=0, counts=None):
+                         tel_base=0, counts=None, gate_opens=None):
     """k rounds in ONE dispatch with nothing read back but two scalars.
 
     The fully device-resident measured loop (ISSUE 8): workload rows
@@ -547,6 +646,12 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
       working capacity, rounds whose route did, rounds in all (a round
       of several sub-steps counts as small when each of them was).
       Read back after the window like the histogram.
+    * ``gate_opens`` int32[``recovery_sections``] (None: zeros) — per
+      recovery section of the step, the rounds in which its gate was
+      OPEN (in any of a round's sub-steps): the round then ran the
+      kernel with every section, else the steady one where its rows
+      fit the small tier (``sharded_round``). Beside ``tiers`` and read
+      with it: 0 over a window with no recovery in it.
 
     A multi-owner pod (Mencius; a trace-time choice on the state's
     structure, ``_has_owners``, so the single-leader program is
@@ -560,10 +665,10 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     merged frontier (``_count_round`` has the definitions).
 
     Returns (ss', inject_round', lat_hist', telemetry', tiers',
-    committed_total, in_flight, counts' or None) — the two scalars are
-    the per-dispatch cursors (committed frontier for throughput
-    progress, assigned-but-uncommitted count for the drain loop's
-    exactness check).
+    committed_total, in_flight, counts' or None, gate_opens') — the two
+    scalars are the per-dispatch cursors (committed frontier for
+    throughput progress, assigned-but-uncommitted count for the drain
+    loop's exactness check).
     """
     step = replica_step_impl if step_impl is None else step_impl
     cursor_rep = jnp.maximum(leader, 0)
@@ -571,6 +676,9 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     cstep = functools.partial(sharded_round, cfg, step,
                               small_tier_rows(cfg, ext_rows, owners))
     _pretrace_kernels(cfg, step, ss, ext_rows)
+    if gate_opens is None:
+        gate_opens = jnp.zeros(
+            len(recovery_sections(step)), jnp.int32)
     w = cfg.window
     pos = jnp.arange(w, dtype=jnp.int32)[None, :]  # [1, W] ring positions
     ts = jnp.arange(k_rounds, dtype=jnp.int32)
@@ -586,7 +694,7 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     has_prepared = getattr(ss.states, "prepared", None) is not None
 
     def body(carry, xs):
-        ss, inj, hist, tel, tiers, counts = carry
+        ss, inj, hist, tel, tiers, counts, gate_opens = carry
         t, key_t, val_t = xs
         r = round0 + t
         u_prev = ss.states.committed_upto[:, cursor_rep]
@@ -610,7 +718,7 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         with jax.named_scope("px.workload"):
             ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
                                  n_proposals, leader, r, key_t, val_t)
-        ss, _, small = cstep(ss, ext)
+        ss, _, small, gate_open = cstep(ss, ext)
         # zero-WIDTH drain sub-steps (see sharded_run): smaller static
         # kernel shape, identical commit stream
         ext0 = jax.tree_util.tree_map(lambda x: x[..., :0], ext)
@@ -623,9 +731,10 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 drain_live = (ss.pending.kind != 0).sum(axis=-1)
                 inbox_rows = inbox_rows + drain_live.sum()
                 inbox_hwm = jnp.maximum(inbox_hwm, drain_live.max())
-            ss, _, small_d = cstep(ss, ext0)
-            small = small & small_d
+            ss, _, small_d, open_d = cstep(ss, ext0)
+            small, gate_open = small & small_d, gate_open | open_d
         tiers = tiers + jnp.append(small, True).astype(tiers.dtype)
+        gate_opens = gate_opens + gate_open.astype(gate_opens.dtype)
         with jax.named_scope("px.lat_hist"):
             u_new = ss.states.committed_upto[:, cursor_rep]
             c_new = ss.states.crt_inst[:, cursor_rep]
@@ -673,18 +782,19 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 tel = jax.lax.dynamic_update_index_in_dim(
                     tel, row,
                     jnp.mod(r - tel_base, telemetry.shape[0]), 0)
-        return (ss, inj, hist, tel, tiers, counts), None
+        return (ss, inj, hist, tel, tiers, counts, gate_opens), None
 
-    (ss, inject_round, lat_hist, telemetry, tiers, counts), _ = jax.lax.scan(
-        body, (ss, inject_round, lat_hist, telemetry, tiers, counts),
-        (ts, keys, vals))
+    (ss, inject_round, lat_hist, telemetry, tiers, counts,
+     gate_opens), _ = jax.lax.scan(
+        body, (ss, inject_round, lat_hist, telemetry, tiers, counts,
+               gate_opens), (ts, keys, vals))
     if owners:
         return (ss, inject_round, lat_hist, telemetry, tiers,
-                counts[0], counts[2] - counts[0], counts)
+                counts[0], counts[2] - counts[0], counts, gate_opens)
     upto = ss.states.committed_upto[:, cursor_rep]
     crt = ss.states.crt_inst[:, cursor_rep]
     return (ss, inject_round, lat_hist, telemetry, tiers,
-            (upto + 1).sum(), (crt - 1 - upto).sum(), None)
+            (upto + 1).sum(), (crt - 1 - upto).sum(), None, gate_opens)
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
@@ -759,6 +869,11 @@ class ShardedCluster:
             self._init_fn, self._step_impl = init_replica, replica_step_impl
             self.leader = 0
         self.ss = init_sharded(cfg, n_shards, mesh, self._init_fn)
+        # the kernel every path steps, traced here, outside any jit
+        _pretrace_kernels(cfg, self._step_impl, self.ss, ext_rows,
+                          tiers=False)
+        # the step's recovery sections, in ``sharded_round``'s order
+        self._sections = recovery_sections(self._step_impl)
         self._seed = 0
         # a multi-owner pod counts COMMANDS beside the log's slots
         # (``N_COUNTS``), on the device, in every path that steps it
@@ -772,7 +887,8 @@ class ShardedCluster:
             "n_replicas": cfg.n_replicas, "inbox": cfg.inbox,
             "working_capacity": small_tier_rows(
                 cfg, ext_rows, _has_owners(self.ss.states)),
-            "tiers": None, "command_commits": None, "noop_slots": None})
+            "tiers": None, "gates": None, "command_commits": None,
+            "noop_slots": None})
 
     def _replicated(self, x):
         """A cross-shard reduction's buffer: replicated on the mesh, to
@@ -855,16 +971,19 @@ class ShardedCluster:
         """Arm the resident loop's device-side bookkeeping: a fresh
         inject-round ring (all -1: slots already in flight are excluded
         from the latency sample, mirroring the host path's pre-phase
-        cursor row), a zeroed latency histogram, zeroed tier counts
-        and — when ``telemetry_rounds`` > 0 — the paxray telemetry
-        ring (one row per round, round column -1 = never written; 0
-        rows compiles the telemetry-free PR-8 dispatch)."""
+        cursor row), a zeroed latency histogram, zeroed tier and
+        section-gate counts and — when ``telemetry_rounds`` > 0 — the
+        paxray telemetry ring (one row per round, round column -1 =
+        never written; 0 rows compiles the telemetry-free PR-8
+        dispatch)."""
+        _pretrace_kernels(self.cfg, self._step_impl, self.ss, self.ext_rows)
         self._inject_round = jnp.full(
             (self.n_shards, self.cfg.window), -1, jnp.int32)
         self._lat_hist = jnp.zeros(lat_bins, jnp.int32)
         self._telemetry = jnp.full((telemetry_rounds, N_TEL_FIELDS), -1,
                                    jnp.int32)
         self._tiers = jnp.zeros(3, jnp.int32)
+        self._gate_opens = jnp.zeros(len(self._sections), jnp.int32)
         # the window's command counts are those since this arming (a
         # copy: the dispatches donate the live buffer)
         self._counts_armed = (None if self._counts is None
@@ -875,9 +994,9 @@ class ShardedCluster:
         self._tel_base = int(self._seed)
         if self.mesh is not None:
             # ring rides the shard axis like the state; the histogram,
-            # the telemetry rows and the tier counts are cross-shard
-            # reductions and are REPLICATED on the mesh — all placed up
-            # front to match
+            # the telemetry rows and the tier and gate counts are
+            # cross-shard reductions and are REPLICATED on the mesh —
+            # all placed up front to match
             # the dispatch's output shardings exactly, or the second
             # dispatch recompiles (~9 s observed: arm-time
             # SingleDeviceSharding vs XLA's NamedSharding(P()) output
@@ -888,6 +1007,7 @@ class ShardedCluster:
             self._lat_hist = self._replicated(self._lat_hist)
             self._telemetry = self._replicated(self._telemetry)
             self._tiers = self._replicated(self._tiers)
+            self._gate_opens = self._replicated(self._gate_opens)
 
     # paxlint: resident-loop
     def run_resident(self, k_rounds: int, n_proposals,
@@ -896,7 +1016,7 @@ class ShardedCluster:
         (committed_total, in_flight) — the sanctioned per-dispatch
         scalar readbacks (progress cursor + drain check). Everything
         else (state, inject ring, latency histogram, telemetry ring,
-        tier counts) stays on device in donated buffers until
+        tier and gate counts) stays on device in donated buffers until
         ``end_resident``. For a multi-owner pod ``n_proposals`` is per
         OWNER (one number for all, or a sequence of R) and the scalars
         count commands (``sharded_run_resident``)."""
@@ -906,14 +1026,15 @@ class ShardedCluster:
             n_prop = self._owner_counts(n_proposals)
         with phase(PH_POD_DISPATCH):
             (self.ss, self._inject_round, self._lat_hist, self._telemetry,
-             self._tiers, committed, in_flight,
-             self._counts) = sharded_run_resident(
+             self._tiers, committed, in_flight, self._counts,
+             self._gate_opens) = sharded_run_resident(
                 self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
                 self._inject_round, self._lat_hist, self._telemetry,
                 self._tiers, n_prop,
                 jnp.int32(self.leader), jnp.int32(self._seed),
                 jnp.int32(self.seed), self._step_impl, self.key_space,
-                substeps, jnp.int32(self._tel_base), self._counts)
+                substeps, jnp.int32(self._tel_base), self._counts,
+                self._gate_opens)
         self._seed += k_rounds
         # the per-dispatch scalar readback — the ONLY host sync in the
         # measured steady state (paxlint's resident-loop rule keeps it
@@ -935,8 +1056,11 @@ class ShardedCluster:
         ``begin_resident``: ``kernel_small_rounds`` and
         ``route_small_rounds`` (rounds whose kernel, and whose route,
         ran at ``working_capacity`` rows), ``rounds`` in all, and the
-        two capacities. A post-window read by the same discipline as
-        ``resident_telemetry``; the reading is kept, as of this call,
+        two capacities, with, under ``gates``, the rounds in which the
+        gate of each recovery section of the step was open, by ``px.*``
+        scope (``sharded_round``). A post-window read by the same
+        discipline as ``resident_telemetry``; the reading is kept, as
+        of this call,
         in ``obs.process_pods()`` — with, for a multi-owner pod, the
         ``command_commits`` and ``noop_slots`` the cursor replica's
         frontier passed over the same rounds."""
@@ -944,11 +1068,13 @@ class ShardedCluster:
         self._pod["tiers"] = {"kernel_small_rounds": kernel_small,
                               "route_small_rounds": route_small,
                               "rounds": rounds}
+        self._pod["gates"] = dict(zip(
+            self._sections, np.asarray(self._gate_opens).tolist()))
         if self._counts is not None:
             commands, noops, _ = (np.asarray(self._counts)
                                   - np.asarray(self._counts_armed)).tolist()
             self._pod.update(command_commits=commands, noop_slots=noops)
-        return {**self._pod["tiers"],
+        return {**self._pod["tiers"], "gates": dict(self._pod["gates"]),
                 "working_capacity": self._pod["working_capacity"],
                 "inbox": self._pod["inbox"]}
 
@@ -981,7 +1107,7 @@ class ShardedCluster:
         self._inject_round = None
         self._lat_hist = None
         self._telemetry = None
-        self._tiers = None
+        self._tiers = self._gate_opens = None
         return hist
 
     def kill(self, replica: int) -> None:

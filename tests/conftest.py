@@ -62,3 +62,27 @@ def _release_compiled_programs():
         return
     if n_maps > MAPS_BEFORE_CLEAR:
         jax.clear_caches()
+
+
+@pytest.fixture(autouse=True)
+def _one_mencius_cell_run_at_a_time(request):
+    """tests/benchmarks runs the Mencius cell from two files: in this
+    process (test_mencius_cell.py) and as a subprocess (test_run_cli.py),
+    whose test then asserts that no scratch directory of the cell is
+    left under ``.bench_scratch`` — which the OTHER file's run, live in
+    another xdist worker at that moment, breaks (ROADMAP C-m; it had
+    come to fail in every whole run of PR 31's tree). A file lock
+    across either keeps them apart on any machine; both files lie under
+    the benchmark's ``paths`` and are not this repo's to edit."""
+    nodeid = request.node.nodeid
+    if not (nodeid.startswith("tests/benchmarks/") and "mencius" in nodeid):
+        yield
+        return
+    import fcntl
+    import pathlib
+
+    scratch = pathlib.Path(__file__).resolve().parent.parent / ".bench_scratch"
+    scratch.mkdir(exist_ok=True)
+    with open(scratch / "lock.mencius_cell", "w") as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        yield

@@ -231,6 +231,12 @@ def test_pod_entry_carries_the_windows_command_counts():
     assert pod["command_commits"] == counts["commands"]
     assert pod["noop_slots"] == counts["noop_slots"]
     assert pod["tiers"]["rounds"] == tiers["rounds"] == _ROUNDS
+    # the recovery gates: an idle owner cedes by SKIP rows, which are
+    # no recovery, and nothing stalled, so no takeover's gate opened
+    # and every round took the small tier's steady kernel
+    assert pod["gates"] == tiers["gates"] == {
+        "px.takeover_phase1": 0, "px.takeover": 0}
+    assert counts["noop_slots"] > 0
     # a single-leader pod has no such counts
     single = sharded.ShardedCluster(MinPaxosConfig(**_KW), 1, ext_rows=_EXT)
     assert single._counts is None

@@ -24,6 +24,7 @@ lossy knob the tier replaced).
 from __future__ import annotations
 
 import functools
+import re
 from typing import NamedTuple
 
 import jax
@@ -262,7 +263,9 @@ def test_tier_counter_small_when_healthy_full_after_revive():
     that routes the revived follower's catch-up burst (145 rows at a
     working capacity of 128) counts a full route, the round that
     delivers it a full kernel; small + full = rounds; and the reading
-    is left in ``obs.process_pods()`` as of the last read."""
+    is left in ``obs.process_pods()`` as of the last read. No recovery
+    gate opened: a follower that dies and heals under a live quorum
+    stalls no leader and starts no discovery."""
     from minpaxos_tpu import obs
     from minpaxos_tpu.obs.recorder import TEL_INBOX_HWM
 
@@ -284,6 +287,8 @@ def test_tier_counter_small_when_healthy_full_after_revive():
            and p["protocol"] == "minpaxos"][-1]
     assert pod["tiers"] == {f: tiers[-1][f] for f in (
         "kernel_small_rounds", "route_small_rounds", "rounds")}
+    assert pod["gates"] == tiers[-1]["gates"] == dict.fromkeys(
+        sharded.recovery_sections(replica_step_impl), 0)
 
 
 class _Canned(NamedTuple):
@@ -335,9 +340,10 @@ def test_route_tier_boundary_exact_capacity_small_one_more_full(count,
             MsgBatch.empty(1)),
         alive=alive)
     ext = jax.tree_util.tree_map(lambda x: x[..., :0], ss.pending)
-    out, _, flags = jax.jit(functools.partial(
+    out, _, flags, ran = jax.jit(functools.partial(
         sharded.sharded_round, cfg, _canned_step, 128))(ss, ext)
     assert flags.tolist() == [True, small]  # empty pending: small kernel
+    assert ran.shape == (0,)  # a step that declares no recovery section
     want = jax.vmap(lambda o, d, a: _route_segmented(cfg, o, d, a,
                                                      cfg.inbox))(
         msgs, jnp.asarray(dst), alive)
@@ -347,20 +353,27 @@ def test_route_tier_boundary_exact_capacity_small_one_more_full(count,
     assert not got[count:].any()
 
 
-def _count_conds(jaxpr) -> int:
-    n = 0
+def _conds(jaxpr) -> list:
+    """Every ``cond`` equation of a jaxpr, nested ones included."""
+    found = []
     for eqn in jaxpr.eqns:
-        n += eqn.primitive.name == "cond"
+        if eqn.primitive.name == "cond":
+            found.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _count_conds(sub)
-    return n
+            found += _conds(sub)
+    return found
+
+
+def _count_conds(jaxpr) -> int:
+    return len(_conds(jaxpr))
 
 
 def test_working_capacity_at_or_above_inbox_compiles_one_tier():
     """Where the working capacity does not lie below the inbox there
-    is nothing to choose: the traced round holds no ``cond`` and is
-    the one-tier program; below it, the kernel's choice and one route
-    choice inside each of its sides."""
+    is no tier to choose: the traced round holds no ``cond`` but the
+    kernel's own, on its recovery gate; below it, the kernel's choice
+    and one route choice inside each of its sides, each side with the
+    conditional of its own kernel."""
     cfg = MinPaxosConfig(n_replicas=3, window=64, inbox=256, exec_batch=8,
                          kv_pow2=6, catchup_rows=8, recovery_rows=8)
     ss = sharded.init_sharded(cfg, 2)
@@ -374,8 +387,227 @@ def test_working_capacity_at_or_above_inbox_compiles_one_tier():
             sharded.sharded_round, cfg, replica_step_impl, rows))(
                 ss, ext).jaxpr)
 
-    assert conds(64) == (256, 0)
-    assert conds(16) == (128, 3)
+    gates = len(sharded.recovery_sections(replica_step_impl))
+    assert conds(64) == (256, gates)
+    assert conds(16) == (128, 3 + 2 * gates)
     assert sharded.working_capacity(cfg, 0) == 128
     assert sharded.working_capacity(cfg._replace(inbox=1280), 128) == 512
     assert sharded.working_capacity(cfg._replace(inbox=2688), 512) == 2048
+
+
+# ----------------------------------------- the whole-chip recovery gates
+
+#: a toy shape whose window and inboxes a fault fills in a few rounds
+_GATE_KW = dict(n_replicas=5, window=128, inbox=256, exec_batch=16,
+                kv_pow2=8, catchup_rows=32, recovery_rows=16)
+_GATE_EXT, _GATE_GROUPS = 8, 2
+
+
+def _alive(ss, replicas, value):
+    return ss._replace(alive=ss.alive.at[:, jnp.asarray(replicas)].set(value))
+
+
+def _leader_schedule(cfg, i, ss):
+    """MinPaxos / classic: election, steady load, the quorum lost for
+    ten rounds (the leader stalls: retry, then the rescan's discovery),
+    three followers revived under load (catch-up), a leader change
+    under load (PREPARE_INST / PREPARE_INST_REPLY flow), drain.
+    Returns (ss, proposals, leader)."""
+    if i == 0:
+        ss = sharded.elect_all(cfg, ss, 0)
+    if i == 10:
+        ss = _alive(ss, [2, 3, 4], False)
+    if i == 20:
+        ss = _alive(ss, [2, 3, 4], True)
+    if i == 34:
+        ss = sharded.elect_all(cfg, ss, 1)
+    return (ss, 0 if i < 2 or i >= 50 else _GATE_EXT, 0 if i < 34 else 1)
+
+
+def _owner_schedule(cfg, i, ss):
+    """Mencius: every owner loaded, owner 2 idle for six rounds (it
+    cedes: SKIP rows flow), owner 1 dead for twenty (its slots block
+    the frontier: a takeover runs), revived, drain."""
+    if i == 20:
+        ss = _alive(ss, [1], False)
+    if i == 40:
+        ss = _alive(ss, [1], True)
+    load = [4, 4, 0, 4, 4] if 8 <= i < 14 else [4] * R
+    return ss, load if i < 50 else [0] * R, -1
+
+
+@pytest.mark.parametrize("protocol,rounds", [
+    ("minpaxos", 60), ("classic", 60), ("mencius", 70)])
+def test_gated_round_equals_the_vmapped_cluster_step(protocol, rounds):
+    """``sharded_round``, which skips the recovery sections whose
+    gates are shut (MinPaxos and classic by a conditional in the step,
+    Mencius by a steady kernel traced without them), against
+    ``jax.vmap(cluster_step_impl)``, which knows no gate: every leaf
+    of the ClusterState and of the exec results equal after EVERY
+    round, over a schedule in which every section's gate is seen both
+    open and shut, the small tier is taken with gates open and with
+    all shut, and the full tier too."""
+    from minpaxos_tpu.models.cluster import cluster_step_impl
+    from minpaxos_tpu.models.mencius import init_mencius, mencius_step_impl
+    from minpaxos_tpu.models.minpaxos import init_replica
+    from minpaxos_tpu.models.paxos import classic_config
+
+    cfg = (classic_config(**_GATE_KW) if protocol == "classic"
+           else MinPaxosConfig(**_GATE_KW))
+    owners = protocol == "mencius"
+    step, init, schedule = (
+        (mencius_step_impl, init_mencius, _owner_schedule) if owners
+        else (replica_step_impl, init_replica, _leader_schedule))
+    gated = jax.jit(functools.partial(
+        sharded.sharded_round, cfg, step,
+        sharded.small_tier_rows(cfg, _GATE_EXT, owners) if owners else 64))
+    plain = jax.jit(jax.vmap(
+        lambda cs, ext: cluster_step_impl(cfg, cs, ext, step)[:2]))
+    ss = sharded.init_sharded(cfg, _GATE_GROUPS, None, init)
+    sections = sharded.recovery_sections(step)
+    opened = np.zeros(len(sections), int)
+    kernels = {"small, gates shut": 0, "small, a gate open": 0, "full": 0}
+    for i in range(rounds):
+        ss, load, leader = schedule(cfg, i, ss)
+        ext = sharded.make_propose_ext(
+            cfg, _GATE_GROUPS, _GATE_EXT, jnp.asarray(load, jnp.int32),
+            jnp.int32(leader), jnp.int32(i), jnp.int32(3), 256, owners)
+        got_ss, got_exec, small, gate_open = gated(ss, ext)
+        _assert_tree_equal((got_ss, got_exec), plain(ss, ext),
+                           f"{protocol}: round {i}")
+        ss = got_ss
+        opened += np.asarray(gate_open)
+        kernels["full" if not small[0] else
+                "small, a gate open" if np.asarray(gate_open).any()
+                else "small, gates shut"] += 1
+    assert int(np.asarray(ss.states.committed_upto).min()) > 200
+    assert ((0 < opened) & (opened < rounds)).all(), dict(
+        zip(sections, opened))
+    assert all(kernels.values()), kernels
+
+
+def _gate_kernel(step, init, steady, gates=None):
+    """The jaxpr of the vmapped kernel of the toy pod."""
+    cfg = MinPaxosConfig(**_GATE_KW)._replace(gate_exec=False)
+    ss = sharded.init_sharded(cfg, _GATE_GROUPS, None, init)
+    ext = jax.tree_util.tree_map(
+        lambda x: jnp.zeros((_GATE_GROUPS, R, _GATE_EXT), x.dtype),
+        MsgBatch.empty(1))
+    if gates:
+        gates = sharded.recovery_gates(cfg, step, ss, ext)
+    return jax.make_jaxpr(lambda ss, ext, gates: sharded._step_groups(
+        cfg, step, 128, steady, ss, ext, gates))(ss, ext, gates).jaxpr
+
+
+def _served_conds(step, init) -> list:
+    """The ``cond``s of the step alone, as the served path calls it."""
+    cfg = MinPaxosConfig(**_GATE_KW)
+    return [str(c.source_info.name_stack) for c in _conds(jax.make_jaxpr(
+        lambda st, ib: step(cfg, st, ib))(
+            init(cfg, 0), MsgBatch.empty(cfg.inbox)).jaxpr)]
+
+
+def test_gates_are_conditionals_of_the_step_not_selects():
+    """With gates, the vmapped MinPaxos kernel holds one ``cond`` per
+    gated section, on an unbatched scalar under the section's scope (a
+    batched predicate would have lowered to a ``select_n`` of both
+    sides); without them it holds none, and the step alone only the
+    exec gate it always had."""
+    from minpaxos_tpu.models.minpaxos import init_replica
+
+    step, init = replica_step_impl, init_replica
+    scopes = list(sharded.recovery_sections(step))
+    assert scopes == ["px.retry"] and sharded._takes_gates(step)
+    conds = _conds(_gate_kernel(step, init, False, gates=True))
+    assert [c.invars[0].aval.shape for c in conds] == [()] * len(scopes)
+    assert [next(s for s in scopes if s in str(c.source_info.name_stack))
+            for c in conds] == scopes
+    assert _conds(_gate_kernel(step, init, False)) == []
+    served = _served_conds(step, init)
+    assert len(served) == 1 and "px.exec" in served[0]
+
+
+def _scope_eqns(jaxpr, counts=None) -> dict:
+    """Equations of a jaxpr, nested ones included, per ``px.*`` scope
+    that encloses them."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        for sub in subs:
+            _scope_eqns(sub, counts)
+        if not subs:
+            for scope in set(re.findall(r"px\.[a-z_0-9.]*[a-z_0-9]",
+                                        str(eqn.source_info.name_stack))):
+                counts[scope] = counts.get(scope, 0) + 1
+    return counts
+
+
+def test_the_steady_kernel_leaves_its_sections_out():
+    """Mencius's steady kernel holds nothing under the scopes of its
+    recovery sections, and every other section as the plain kernel has
+    it, SKIP rows among them; neither holds a ``cond`` (one on a
+    per-replica predicate would be a select of both sides under the
+    vmaps), the step alone holds only the exec gate it always had, and
+    the round chooses among its three kernels by ONE conditional on an
+    unbatched scalar, whose third side alone lacks the sections."""
+    from minpaxos_tpu.models.mencius import init_mencius, mencius_step_impl
+
+    step, init = mencius_step_impl, init_mencius
+    gated = set(sharded.recovery_sections(step))
+    assert gated == {"px.takeover_phase1", "px.takeover"}
+    assert not sharded._takes_gates(step)
+    kernels = [_gate_kernel(step, init, steady) for steady in (False, True)]
+    assert [_conds(k) for k in kernels] == [[], []]
+    plain, steady = map(_scope_eqns, kernels)
+    assert gated <= set(plain) and set(steady) <= set(plain)
+    for scope, n in plain.items():
+        if scope in gated:
+            assert steady.get(scope, 0) <= 5 < n, (scope, n, steady)
+        elif scope != "px.outbox":  # which joins the sections' rows
+            assert steady[scope] == n, (scope, n, steady)
+    served = _served_conds(step, init)
+    assert len(served) == 1 and "px.exec" in served[0]
+    cfg = MinPaxosConfig(**_GATE_KW)
+    ss = sharded.init_sharded(cfg, _GATE_GROUPS, None, init)
+    ext = jax.tree_util.tree_map(
+        lambda x: jnp.zeros((_GATE_GROUPS, R, _GATE_EXT), x.dtype),
+        MsgBatch.empty(1))
+    top = [e for e in jax.make_jaxpr(functools.partial(
+        sharded.sharded_round, cfg, step, 128))(ss, ext).jaxpr.eqns
+           if e.primitive.name == "cond"]
+    assert len(top) == 1 and top[0].invars[0].aval.shape == ()
+    sides = [_scope_eqns(b.jaxpr) for b in top[0].params["branches"]]
+    assert [all(side.get(scope, 0) > 5 for scope in gated)
+            for side in sides] == [True, True, False]
+
+
+def test_gate_counters_count_the_rounds_a_gate_was_open():
+    """Through the resident loop: 0 for every section over healthy
+    rounds; with the quorum dead the frontier still moves in the first
+    round (acks on their way), the leader's stall counter then takes
+    ``RETRY_STALL_TICKS`` - 1 rounds to stand one short of its
+    threshold, and the gate of ``px.retry`` is open from the next on;
+    the reading is the pod's entry in ``obs.process_pods()``, and
+    arming again zeroes it."""
+    from minpaxos_tpu import obs
+    from minpaxos_tpu.models.minpaxos import RETRY_STALL_TICKS
+
+    cfg = MinPaxosConfig(**_GATE_KW)
+    sc = sharded.ShardedCluster(cfg, _GATE_GROUPS, ext_rows=_GATE_EXT,
+                                key_space=256)
+    sc.ss = sharded.elect_all(cfg, sc.ss, 0)
+    sc.begin_resident()
+    for n in (0, 0) + (_GATE_EXT,) * 6:
+        sc.run_resident(1, n)
+    idle = dict.fromkeys(sharded.recovery_sections(replica_step_impl), 0)
+    assert sc.resident_tiers()["gates"] == idle == {"px.retry": 0}
+    for r in (2, 3, 4):
+        sc.kill(r)
+    for _ in range(8):
+        sc.run_resident(1, _GATE_EXT)
+    tiers = sc.resident_tiers()
+    assert tiers["rounds"] == 16
+    assert tiers["gates"] == {**idle, "px.retry": 8 - RETRY_STALL_TICKS}
+    assert obs.process_pods()[-1]["gates"] == tiers["gates"]
+    sc.begin_resident()
+    assert sc.resident_tiers()["gates"] == idle
