@@ -64,8 +64,12 @@ ROOT = pathlib.Path(__file__).resolve().parent
 #: the k-round scan serves every leg, because the smoke has to fit the
 #: chip tool's time limit: at this shape each variant compiled for
 #: 4-5 minutes on a v5e and a round ran for ~6 s (PR 21). The dead
-#: leg fixes k at 2: the dead gap k*p must stay well below the leader's
-#: retention (w//2 slots) or the victim can never reheal on-device.
+#: leg stays at k = 2 rounds, a gap k*p inside the leader's retention
+#: (w//2 slots), so this smoke's victim is healed by catch-up rows as it
+#: always was and the schedule keeps inside the chip tool's time limit;
+#: an outage beyond retention is healed on the device by a state
+#: transfer since PR 33 (parallel/sharded.py ``transfer_round``), which
+#: the benchmark's cell ``pod128_kill_recover`` runs.
 K_ROUNDS = 2
 HEALTHY_DISPATCHES = 3
 #: no-proposal dispatches with the quorum dead and slots in flight:

@@ -32,6 +32,7 @@ the reference's cold paths deliberately stay off the device
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -82,12 +83,19 @@ class MinPaxosConfig(NamedTuple):
     # (bareminpaxos.go:95) without unbounded device memory. Every
     # replica retains up to `retention` executed slots so whoever is
     # (or becomes) leader can heal laggards from resident state
-    # (CatchUpLog). LIMIT: a replica lagging beyond `retention` must be
-    # resynced from the durable log (runtime/ stable store — the
-    # reference's replay, bareminpaxos.go:122-161); until that runs,
-    # such a laggard stays frozen and must not be elected leader (the
-    # master elects the highest-frontier replica for this reason).
-    # Size retention to cover the longest expected outage.
+    # (CatchUpLog). LIMIT of catch-up rows: a replica lagging beyond
+    # `retention` is out of their reach and is healed by a transfer of
+    # executed state instead. The served path ships it from the durable
+    # log (runtime/ stable store: COMMIT frames, or the retained
+    # snapshot once the log is truncated; the reference's replay,
+    # bareminpaxos.go:122-161); the fused pod dispatches ship the
+    # leader's KV table on the device (`state_transfer` below,
+    # parallel/sharded.py `transfer_round`). Only a path with neither
+    # (a pod stepped round by round through `sharded_step`, Mencius)
+    # leaves such a laggard frozen, and it must not be elected leader
+    # (the master elects the highest-frontier replica for this reason).
+    # Retention covers the outages that are healed row by row; longer
+    # ones cost one table copy.
     slide_window: bool = True
     retention: int = -1  # executed slots retained per replica; -1 = window//2
     # Gate the execute pipeline (sort/lookup/KV insert) behind
@@ -1326,6 +1334,150 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
             window_base=state.window_base + shift,
         )
     return state, Outbox(msgs=out, dst=dst, acked=ack_ok_row), execr
+
+
+# ---- state transfer: the device twin of the served path's snapshot ----
+#
+# Catch-up (7c) heals a follower from the leader's window; one that has
+# fallen below ``window_base`` it cannot reach. The served path then
+# ships a snapshot (runtime/replica.py ``_host_catchup`` ->
+# ``_send_snapshot`` -> ``_snap_rx_install`` ->
+# ``_install_snapshot_pairs``). A pod has no host path: the same
+# transfer is a section of the ROUND, run by the composition over the
+# replicas of a group before it steps them (parallel/sharded.py
+# ``transfer_round``), because it reads one replica's state and writes
+# another's.
+
+
+def transfer_needs(cfg: MinPaxosConfig, states: ReplicaState, alive):
+    """Which replicas of ONE group (leaves ``[R, ...]``) are due a state
+    transfer this round, and from whom: ``(need bool[R], donor i32)``.
+
+    The sender's side is ``_host_catchup``'s: the group's prepared
+    leader (the live one of the highest ballot, should a deposed one
+    not know yet) ships to a peer q whose frontier, as far as its
+    reports have told (``peer_commits[q]``, the last report it has; -1
+    before any), lies below what its window can still serve:
+    ``peer_commits[q] + 1 < window_base``. The receiver's side is
+    ``_snap_rx_install``'s "ahead of our own executed frontier",
+    tightened to the same line: q installs only while its own executed
+    prefix ends below the donor's window, so a stale report costs a
+    healthy replica nothing and one install is not followed by a
+    second while the leader still awaits q's next report. Both ends
+    under ``alive``: a dead replica neither ships nor installs.
+    Nothing here reads a fault schedule."""
+    reps = jnp.arange(cfg.n_replicas, dtype=jnp.int32)
+    can_ship = alive & (states.leader_id == states.me) & states.prepared
+    donor = jnp.argmax(jnp.where(can_ship, states.default_ballot,
+                                 NO_BALLOT - 1)).astype(jnp.int32)
+    base = states.window_base[donor]
+    need = (can_ship.any() & alive & (reps != donor)
+            & (states.peer_commits[donor] + 1 < base)
+            & (states.executed_upto + 1 < base))
+    return need, donor
+
+
+def transfer_gate(cfg: MinPaxosConfig, states: ReplicaState, alive):
+    """The section's whole-chip gate, over every group at once (leaves
+    ``[G, R, ...]``): open iff some replica is due a transfer (exact,
+    not a superset: the section acts on the same ``transfer_needs``)."""
+    return jax.vmap(functools.partial(transfer_needs, cfg))(
+        states, alive)[0].any()
+
+
+def state_transfer(cfg: MinPaxosConfig, states: ReplicaState, alive):
+    """One group's transfers of this round: ``(states', installs)``.
+
+    A replica q that ``transfer_needs`` names adopts the donor's
+    EXECUTED state at the donor's executed frontier f, exactly what
+    ``_install_snapshot_pairs`` leaves: the donor's KV table (same
+    capacity and hashing, so the arrays are copied whole, the drop
+    count with them), ``executed_upto`` = f, ``committed_upto`` =
+    max(own, f), ``window_base`` = f + 1, ``crt_inst`` / ``rec_cursor``
+    / ``tenure_start`` = max(own, f + 1). Only committed-and-executed
+    state crosses; votes, promises and in-flight slots never do.
+
+    What q keeps. Its identity and every ballot it has promised
+    (``default_ballot`` / ``max_recv_ballot`` = max(own, donor's); where
+    the donor's is higher q follows the donor, as a PREPARE of that
+    ballot would have made it, and is not leader). Its window is SLID
+    to f + 1, not zeroed: a slot above f that q has accepted may be one
+    of the votes its quorum stands on, and forgetting it could let a
+    later phase 1 find a majority of "empty" for a committed slot. Slots
+    at or below f are covered by the table, as in the served path, whose
+    installs only ever meet windows that lie wholly below f (the window
+    is then all fill: ``_install_snapshot_pairs``'s zeroed columns).
+    ``gossip_upto`` stays, so q's next step reports its new frontier
+    and the leader's ``peer_commits[q]`` follows that report; catch-up
+    rows and the ACCEPT stream close the last rounds' gap."""
+    S, R = cfg.window, cfg.n_replicas
+    need, donor = transfer_needs(cfg, states, alive)
+    d = jax.tree_util.tree_map(lambda x: x[donor], states)
+    f = d.executed_upto
+
+    def adopt(own, new):
+        return jnp.where(need.reshape((R,) + (1,) * (own.ndim - 1)), new, own)
+
+    def at_least(own, floor):  # a cursor or ballot never moves back
+        return adopt(own, jnp.maximum(own, floor))
+
+    shift = jnp.clip(f + 1 - states.window_base, 0, S)            # [R]
+    src = jnp.arange(S, dtype=jnp.int32)[None, :] + shift[:, None]
+    kept = src < S
+    src = jnp.minimum(src, S - 1)
+
+    def slide(col, fill):
+        return adopt(col, jnp.where(
+            kept, jnp.take_along_axis(col, src, axis=1), fill))
+
+    raised = need & (d.default_ballot > states.default_ballot)
+    return states._replace(
+        ballot=slide(states.ballot, NO_BALLOT),
+        status=slide(states.status, NONE),
+        op=slide(states.op, 0),
+        key_hi=slide(states.key_hi, 0),
+        key_lo=slide(states.key_lo, 0),
+        val_hi=slide(states.val_hi, 0),
+        val_lo=slide(states.val_lo, 0),
+        cmd_id=slide(states.cmd_id, 0),
+        client_id=slide(states.client_id, 0),
+        votes=slide(states.votes, 0),
+        pvotes=slide(states.pvotes, 0),
+        kv=jax.tree_util.tree_map(lambda own, new: adopt(own, new[None]),
+                                  states.kv, d.kv),
+        window_base=adopt(states.window_base, f + 1),
+        executed_upto=adopt(states.executed_upto, f),
+        committed_upto=at_least(states.committed_upto, f),
+        crt_inst=at_least(states.crt_inst, f + 1),
+        rec_cursor=at_least(states.rec_cursor, f + 1),
+        tenure_start=at_least(states.tenure_start, f + 1),
+        default_ballot=at_least(states.default_ballot, d.default_ballot),
+        max_recv_ballot=at_least(states.max_recv_ballot, d.default_ballot),
+        leader_id=jnp.where(raised | (need & (states.leader_id < 0)),
+                            donor, states.leader_id),
+        prepared=states.prepared & ~raised,
+    ), need.sum(dtype=jnp.int32)
+
+
+def transfer_bytes(cfg: MinPaxosConfig) -> int:
+    """Bytes one install copies from its donor: the KV table's arrays
+    (ops/kvstore.py ``KVState``; the window columns are slid in place
+    and the cursors are a few words)."""
+    kv = jax.eval_shape(lambda: kv_init(cfg.kv_pow2))
+    return sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(kv))
+
+
+#: sections of a ROUND that the composition runs over the replicas of a
+#: group, outside the vmap that steps them, each under a whole-chip
+#: gate of its own as ``recovery_gates`` has the step's: ``px.*`` scope
+#: -> (gate(cfg, states, alive) over all groups, section(cfg, states,
+#: alive) of one group -> (states', acts)). Classic Multi-Paxos is this
+#: step under another flag and has them too; Mencius has no leader to
+#: ship from and declares none.
+replica_step_impl.round_sections = {
+    "px.state_transfer": (transfer_gate, state_transfer),
+}
 
 
 # Single-replica entry point used by the host runtime (runtime/replica.py).

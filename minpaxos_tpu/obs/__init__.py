@@ -154,10 +154,19 @@ def process_pods() -> list[dict]:
     by its ``px.*`` scope, the rounds in which its gate was OPEN:
     ``{"px.retry": 0}``, of ``tiers["rounds"]``; or None) and, over the
     same rounds, a multi-owner (Mencius) pod's
-    ``command_commits`` and ``noop_slots`` (else None). Copies taken
-    now."""
+    ``command_commits`` and ``noop_slots`` (else None) and a
+    single-leader pod's recovery counts (else None): ``round_gates``
+    (as ``gates``, for the sections of the round itself:
+    ``{"px.state_transfer": 0}``), ``state_transfers`` (installs of a
+    leader's executed state in a follower its window could no longer
+    heal), ``state_transfer_bytes`` (what those copied) and
+    ``lagging_rounds`` (rounds at whose end a live replica's frontier
+    trailed its leader's by more than two rounds' proposals). Copies
+    taken now."""
     return [dict(p, tiers=p["tiers"] and dict(p["tiers"]),
-                 gates=p["gates"] and dict(p["gates"]))
+                 gates=p["gates"] and dict(p["gates"]),
+                 round_gates=p["round_gates"]
+                 and dict(p["round_gates"]))
             for p in list(_PROCESS_PODS)]
 
 

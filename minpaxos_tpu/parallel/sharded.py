@@ -42,6 +42,7 @@ from minpaxos_tpu.models.minpaxos import (
     become_leader,
     init_replica,
     replica_step_impl,
+    transfer_bytes,
 )
 from minpaxos_tpu.obs import register_pod
 from minpaxos_tpu.obs.recorder import (
@@ -192,6 +193,55 @@ def recovery_gates(cfg: MinPaxosConfig, step, ss: ClusterState,
 
     return {section: gate(cfg, ss.states, present) for section, gate
             in getattr(step, "recovery_gates", {}).items()}
+
+
+def round_sections(step) -> dict:
+    """The sections of a ROUND that ``step`` declares beside itself
+    (``step.round_sections``, models/minpaxos.py: ``px.state_transfer``),
+    by ``px.*`` scope, each a (gate, section) pair; {} for a step that
+    declares none (Mencius), whose programs then hold nothing of this."""
+    return getattr(step, "round_sections", {})
+
+
+def recovery_counts(step):
+    """Zeroed recovery counts of the resident loop for ``step``
+    (``sharded_run_resident``'s ``recovery``): int32[2 n + 1] for its n
+    round sections, None for a step that declares none."""
+    n = len(round_sections(step))
+    return jnp.zeros(2 * n + 1, jnp.int32) if n else None
+
+
+def transfer_round(cfg: MinPaxosConfig, step, ss: ClusterState):
+    """The round sections of ``step``, run before the round steps its
+    replicas: (ss', open bool[sections], acts int32[sections]).
+
+    Such a section reads one replica's state and writes another's (a
+    state transfer from a group's leader to a follower its window can
+    no longer heal), so it runs over the replicas of a group, OUTSIDE
+    the vmap that steps them, for every group at once. Its gate is a
+    whole-chip scalar of the kind ``recovery_gates`` takes, computed
+    from protocol state and the ``alive`` mask that silences a dead
+    replica's rows; while it is shut the section is skipped by a
+    ``lax.cond`` around the states alone, so a round with nothing to
+    transfer pays a reduction over [G, R] words. ``acts`` counts what
+    an open section did (installs)."""
+    opens, acts = [], []
+    for scope, (gate, section) in round_sections(step).items():
+        with jax.named_scope(scope):
+            is_open = gate(cfg, ss.states, ss.alive)
+
+            def run(states, alive, section=section):
+                states, n = jax.vmap(functools.partial(section, cfg))(
+                    states, alive)
+                return states, n.sum(dtype=jnp.int32)
+
+            states, n = jax.lax.cond(
+                is_open, run, lambda states, alive: (states, jnp.int32(0)),
+                ss.states, ss.alive)
+        ss = ss._replace(states=states)
+        opens.append(is_open)
+        acts.append(n)
+    return ss, jnp.stack(opens), jnp.stack(acts)
 
 
 def _takes_gates(step) -> bool:
@@ -579,6 +629,8 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
             before = _owner_cursors(ss.states, cursor_rep)
         ext = assemble_batch(cfg.n_replicas, n_shards, ext_rows,
                              n_proposals, leader, round0 + t, key_t, val_t)
+        if round_sections(step):
+            ss, _, _ = transfer_round(cfg, step, ss)
         ss, *_ = cstep(ss, ext)
         # drain-only sub-steps: deliver queued traffic, no new work —
         # the ext batch is ZERO-WIDTH, not zero-filled, so the kernel
@@ -601,13 +653,14 @@ def sharded_run(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
 
 # paxlint: resident-loop
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 13, 14, 15),
-                   donate_argnums=(4, 5, 6, 7, 8, 17, 18))
+                   donate_argnums=(4, 5, 6, 7, 8, 17, 18, 19))
 def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                          k_rounds: int, ss: ClusterState, inject_round,
                          lat_hist, telemetry, tiers, n_proposals, leader,
                          round0, seed=0, step_impl=None,
                          key_space: int = 1 << 20, substeps: int = 1,
-                         tel_base=0, counts=None, gate_opens=None):
+                         tel_base=0, counts=None, gate_opens=None,
+                         recovery=None):
     """k rounds in ONE dispatch with nothing read back but two scalars.
 
     The fully device-resident measured loop (ISSUE 8): workload rows
@@ -652,6 +705,14 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
       kernel with every section, else the steady one where its rows
       fit the small tier (``sharded_round``). Beside ``tiers`` and read
       with it: 0 over a window with no recovery in it.
+    * ``recovery`` int32[2 n + 1] for a step that declares n round
+      sections (``transfer_round``; None for any other, whose program
+      holds nothing of it): per section the rounds in which its gate
+      was open, then per section what it did while open (installs of
+      ``px.state_transfer``), then the rounds at whose END some LIVE
+      replica's ``committed_upto`` trailed its group leader's by more
+      than twice the round's proposals (a healthy follower trails by
+      one round's). Read with ``tiers``.
 
     A multi-owner pod (Mencius; a trace-time choice on the state's
     structure, ``_has_owners``, so the single-leader program is
@@ -665,7 +726,8 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     merged frontier (``_count_round`` has the definitions).
 
     Returns (ss', inject_round', lat_hist', telemetry', tiers',
-    committed_total, in_flight, counts' or None, gate_opens') — the two
+    committed_total, in_flight, counts' or None, gate_opens',
+    recovery' or None) — the two
     scalars are the per-dispatch cursors (committed frontier for
     throughput progress, assigned-but-uncommitted count for the drain
     loop's exactness check).
@@ -679,6 +741,8 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     if gate_opens is None:
         gate_opens = jnp.zeros(
             len(recovery_sections(step)), jnp.int32)
+    if recovery is None:
+        recovery = recovery_counts(step)
     w = cfg.window
     pos = jnp.arange(w, dtype=jnp.int32)[None, :]  # [1, W] ring positions
     ts = jnp.arange(k_rounds, dtype=jnp.int32)
@@ -694,9 +758,11 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
     has_prepared = getattr(ss.states, "prepared", None) is not None
 
     def body(carry, xs):
-        ss, inj, hist, tel, tiers, counts, gate_opens = carry
+        ss, inj, hist, tel, tiers, counts, gate_opens, recovery = carry
         t, key_t, val_t = xs
         r = round0 + t
+        if recovery is not None:
+            ss, rs_open, rs_acts = transfer_round(cfg, step, ss)
         u_prev = ss.states.committed_upto[:, cursor_rep]
         c_prev = ss.states.crt_inst[:, cursor_rep]
         if owners:
@@ -737,6 +803,13 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
         gate_opens = gate_opens + gate_open.astype(gate_opens.dtype)
         with jax.named_scope("px.lat_hist"):
             u_new = ss.states.committed_upto[:, cursor_rep]
+            if recovery is not None:
+                lagging = (ss.alive & (u_new[:, None]
+                                       - ss.states.committed_upto
+                                       > 2 * n_proposals)).any()
+                recovery = recovery + jnp.concatenate([
+                    rs_open.astype(recovery.dtype), rs_acts,
+                    lagging.astype(recovery.dtype)[None]])
             c_new = ss.states.crt_inst[:, cursor_rep]
             if owners:
                 counts, inj, hist = _count_round(
@@ -782,19 +855,22 @@ def sharded_run_resident(cfg: MinPaxosConfig, n_shards: int, ext_rows: int,
                 tel = jax.lax.dynamic_update_index_in_dim(
                     tel, row,
                     jnp.mod(r - tel_base, telemetry.shape[0]), 0)
-        return (ss, inj, hist, tel, tiers, counts, gate_opens), None
+        return (ss, inj, hist, tel, tiers, counts, gate_opens,
+                recovery), None
 
     (ss, inject_round, lat_hist, telemetry, tiers, counts,
-     gate_opens), _ = jax.lax.scan(
+     gate_opens, recovery), _ = jax.lax.scan(
         body, (ss, inject_round, lat_hist, telemetry, tiers, counts,
-               gate_opens), (ts, keys, vals))
+               gate_opens, recovery), (ts, keys, vals))
     if owners:
         return (ss, inject_round, lat_hist, telemetry, tiers,
-                counts[0], counts[2] - counts[0], counts, gate_opens)
+                counts[0], counts[2] - counts[0], counts, gate_opens,
+                recovery)
     upto = ss.states.committed_upto[:, cursor_rep]
     crt = ss.states.crt_inst[:, cursor_rep]
     return (ss, inject_round, lat_hist, telemetry, tiers,
-            (upto + 1).sum(), (crt - 1 - upto).sum(), None, gate_opens)
+            (upto + 1).sum(), (crt - 1 - upto).sum(), None, gate_opens,
+            recovery)
 
 
 @functools.partial(jax.jit, static_argnums=0, donate_argnums=1)
@@ -874,6 +950,9 @@ class ShardedCluster:
                           tiers=False)
         # the step's recovery sections, in ``sharded_round``'s order
         self._sections = recovery_sections(self._step_impl)
+        # and its round sections (``transfer_round``)
+        self._round_sections = tuple(round_sections(self._step_impl))
+        self._recovery = None
         self._seed = 0
         # a multi-owner pod counts COMMANDS beside the log's slots
         # (``N_COUNTS``), on the device, in every path that steps it
@@ -888,7 +967,9 @@ class ShardedCluster:
             "working_capacity": small_tier_rows(
                 cfg, ext_rows, _has_owners(self.ss.states)),
             "tiers": None, "gates": None, "command_commits": None,
-            "noop_slots": None})
+            "noop_slots": None, "round_gates": None,
+            "state_transfers": None, "state_transfer_bytes": None,
+            "lagging_rounds": None})
 
     def _replicated(self, x):
         """A cross-shard reduction's buffer: replicated on the mesh, to
@@ -971,8 +1052,9 @@ class ShardedCluster:
         """Arm the resident loop's device-side bookkeeping: a fresh
         inject-round ring (all -1: slots already in flight are excluded
         from the latency sample, mirroring the host path's pre-phase
-        cursor row), a zeroed latency histogram, zeroed tier and
-        section-gate counts and — when ``telemetry_rounds`` > 0 — the
+        cursor row), a zeroed latency histogram, zeroed tier,
+        section-gate and recovery counts and — when
+        ``telemetry_rounds`` > 0 — the
         paxray telemetry ring (one row per round, round column -1 =
         never written; 0 rows compiles the telemetry-free PR-8
         dispatch)."""
@@ -984,6 +1066,7 @@ class ShardedCluster:
                                    jnp.int32)
         self._tiers = jnp.zeros(3, jnp.int32)
         self._gate_opens = jnp.zeros(len(self._sections), jnp.int32)
+        self._recovery = recovery_counts(self._step_impl)
         # the window's command counts are those since this arming (a
         # copy: the dispatches donate the live buffer)
         self._counts_armed = (None if self._counts is None
@@ -1008,6 +1091,8 @@ class ShardedCluster:
             self._telemetry = self._replicated(self._telemetry)
             self._tiers = self._replicated(self._tiers)
             self._gate_opens = self._replicated(self._gate_opens)
+            if self._recovery is not None:
+                self._recovery = self._replicated(self._recovery)
 
     # paxlint: resident-loop
     def run_resident(self, k_rounds: int, n_proposals,
@@ -1016,7 +1101,8 @@ class ShardedCluster:
         (committed_total, in_flight) — the sanctioned per-dispatch
         scalar readbacks (progress cursor + drain check). Everything
         else (state, inject ring, latency histogram, telemetry ring,
-        tier and gate counts) stays on device in donated buffers until
+        tier, gate and recovery counts) stays on device in donated
+        buffers until
         ``end_resident``. For a multi-owner pod ``n_proposals`` is per
         OWNER (one number for all, or a sequence of R) and the scalars
         count commands (``sharded_run_resident``)."""
@@ -1027,14 +1113,14 @@ class ShardedCluster:
         with phase(PH_POD_DISPATCH):
             (self.ss, self._inject_round, self._lat_hist, self._telemetry,
              self._tiers, committed, in_flight, self._counts,
-             self._gate_opens) = sharded_run_resident(
+             self._gate_opens, self._recovery) = sharded_run_resident(
                 self.cfg, self.n_shards, self.ext_rows, k_rounds, self.ss,
                 self._inject_round, self._lat_hist, self._telemetry,
                 self._tiers, n_prop,
                 jnp.int32(self.leader), jnp.int32(self._seed),
                 jnp.int32(self.seed), self._step_impl, self.key_space,
                 substeps, jnp.int32(self._tel_base), self._counts,
-                self._gate_opens)
+                self._gate_opens, self._recovery)
         self._seed += k_rounds
         # the per-dispatch scalar readback — the ONLY host sync in the
         # measured steady state (paxlint's resident-loop rule keeps it
@@ -1063,7 +1149,15 @@ class ShardedCluster:
         of this call,
         in ``obs.process_pods()`` — with, for a multi-owner pod, the
         ``command_commits`` and ``noop_slots`` the cursor replica's
-        frontier passed over the same rounds."""
+        frontier passed over the same rounds. A pod whose step declares
+        round sections (``transfer_round``) adds its recovery counts
+        over the same rounds: ``round_gates`` (as ``gates``, for the
+        round's own sections: ``px.state_transfer``),
+        ``state_transfers`` (installs done), ``state_transfer_bytes``
+        (what they copied from their donors: installs x the KV table's
+        bytes) and ``lagging_rounds`` (rounds at whose end a live
+        replica trailed its leader by more than two rounds'
+        proposals)."""
         kernel_small, route_small, rounds = np.asarray(self._tiers).tolist()
         self._pod["tiers"] = {"kernel_small_rounds": kernel_small,
                               "route_small_rounds": route_small,
@@ -1074,9 +1168,22 @@ class ShardedCluster:
             commands, noops, _ = (np.asarray(self._counts)
                                   - np.asarray(self._counts_armed)).tolist()
             self._pod.update(command_commits=commands, noop_slots=noops)
-        return {**self._pod["tiers"], "gates": dict(self._pod["gates"]),
-                "working_capacity": self._pod["working_capacity"],
-                "inbox": self._pod["inbox"]}
+        out = {**self._pod["tiers"], "gates": dict(self._pod["gates"]),
+               "working_capacity": self._pod["working_capacity"],
+               "inbox": self._pod["inbox"]}
+        if self._recovery is not None:
+            n = len(self._round_sections)
+            *counts, lagging = np.asarray(self._recovery).tolist()
+            installs = dict(zip(self._round_sections,
+                                counts[n:]))["px.state_transfer"]
+            recovery = {
+                "round_gates": dict(zip(self._round_sections, counts[:n])),
+                "state_transfers": installs,
+                "state_transfer_bytes": installs * transfer_bytes(self.cfg),
+                "lagging_rounds": lagging}
+            self._pod.update(recovery)
+            out.update(recovery, round_gates=dict(recovery["round_gates"]))
+        return out
 
     def command_counts(self) -> dict:
         """A multi-owner pod's counts, cumulative from boot, at the
@@ -1107,7 +1214,7 @@ class ShardedCluster:
         self._inject_round = None
         self._lat_hist = None
         self._telemetry = None
-        self._tiers = self._gate_opens = None
+        self._tiers = self._gate_opens = self._recovery = None
         return hist
 
     def kill(self, replica: int) -> None:

@@ -41,7 +41,8 @@ STEP_SCOPES = (
     "px.outbox", "px.exec", "px.kv.sort", "px.kv.scan", "px.kv.lookup",
     "px.kv.output", "px.kv.insert", "px.window_slide", "px.pack")
 POD_SCOPES = ("px.deliver", "px.route.plan", "px.route.gather",
-              "px.workload", "px.lat_hist", "px.telemetry")
+              "px.workload", "px.lat_hist", "px.telemetry",
+              "px.state_transfer")
 COL = {name: i for i, name in enumerate(R.FIELD_NAMES)}
 PHASES = ("wait_us", "drain_us", "enqueue_us", "readback_us", "persist_us",
           "dispatch_us", "reply_us")

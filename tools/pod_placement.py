@@ -114,7 +114,11 @@ def main() -> int:
         "leader": i32(), "round0": i32(), "seed": i32(), "step_impl": step,
         "key_space": c["key_space"], "substeps": 1, "tel_base": i32(),
         "counts": i32(sharded.N_COUNTS) if owners else None,
-        "gate_opens": i32(len(getattr(step, "recovery_gates", ())))}
+        "gate_opens": i32(len(getattr(step, "recovery_gates", ()))),
+        # a step with round sections (px.state_transfer) carries their
+        # counts; a tree or a step without them takes none
+        "recovery": (i32(2 * len(step.round_sections) + 1)
+                     if getattr(step, "round_sections", None) else None)}
     params = inspect.signature(sharded.sharded_run_resident).parameters
     t0 = time.monotonic()
     lowered = sharded.sharded_run_resident.lower(
