@@ -59,6 +59,7 @@ from minpaxos_tpu.models.minpaxos import (
     MinPaxosConfig,
     MsgBatch,
     Outbox,
+    SLOT_FIELDS,
     _concat_rows,
     _rel,
     make_ballot,
@@ -78,7 +79,7 @@ from minpaxos_tpu.ops.kvstore import (
 from minpaxos_tpu.ops.rankselect import rank_select
 from minpaxos_tpu.ops.scan import commit_frontier, segmented_scan_max
 from minpaxos_tpu.ops.sections import Sections
-from minpaxos_tpu.ops.winner import gather_const, gather_row, slot_winner
+from minpaxos_tpu.ops.winner import gather_cols, gather_const, slot_winner
 from minpaxos_tpu.wire.messages import MsgKind, Op
 
 
@@ -249,16 +250,20 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
              & (rank_p < csum_p[-1]))
     win_p = rank_select(csum_p, jnp.clip(rank_p, 0, M - 1) + 1)
     win_p = jnp.where(hit_p, win_p, -1)
-    state = state._replace(
+    # a slot's winning row is fetched ONCE a pass, all its columns
+    # together (ops/winner.py gather_cols; until PR 34 seven or eight
+    # element gathers a section); sections 1, 2, 6 and 7b
+
+    def slot_write(st, win, hit, fields=SLOT_FIELDS):
+        """The winning rows' ``fields`` into ``st``'s slots."""
+        return st._replace(**dict(zip(fields, gather_cols(
+            win, hit, [getattr(inbox, f) for f in fields],
+            [getattr(st, f) for f in fields]))))
+
+    # a proposal's ballot is not its row's
+    state = slot_write(state, win_p, hit_p, SLOT_FIELDS[1:])._replace(
         ballot=gather_const(hit_p, 0, state.ballot),
         status=gather_const(hit_p, ACCEPTED, state.status),
-        op=gather_row(win_p, hit_p, inbox.op, state.op),
-        key_hi=gather_row(win_p, hit_p, inbox.key_hi, state.key_hi),
-        key_lo=gather_row(win_p, hit_p, inbox.key_lo, state.key_lo),
-        val_hi=gather_row(win_p, hit_p, inbox.val_hi, state.val_hi),
-        val_lo=gather_row(win_p, hit_p, inbox.val_lo, state.val_lo),
-        cmd_id=gather_row(win_p, hit_p, inbox.cmd_id, state.cmd_id),
-        client_id=gather_row(win_p, hit_p, inbox.client_id, state.client_id),
         votes=gather_const(hit_p, me_bit, state.votes),
     )
     n_prop = jnp.where(fits, 1, 0).sum()
@@ -304,16 +309,8 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         jnp.where(acc_pre, rel_a, S)].max(inbox.ballot, mode="drop")
     acc_ok = acc_pre & (inbox.ballot == ab_max[rel_a_safe])
     win_a, hit_a = slot_winner(S, rel_a, acc_ok)
-    state = state._replace(
-        ballot=gather_row(win_a, hit_a, inbox.ballot, state.ballot),
+    state = slot_write(state, win_a, hit_a)._replace(
         status=gather_const(hit_a, ACCEPTED, state.status),
-        op=gather_row(win_a, hit_a, inbox.op, state.op),
-        key_hi=gather_row(win_a, hit_a, inbox.key_hi, state.key_hi),
-        key_lo=gather_row(win_a, hit_a, inbox.key_lo, state.key_lo),
-        val_hi=gather_row(win_a, hit_a, inbox.val_hi, state.val_hi),
-        val_lo=gather_row(win_a, hit_a, inbox.val_lo, state.val_lo),
-        cmd_id=gather_row(win_a, hit_a, inbox.cmd_id, state.cmd_id),
-        client_id=gather_row(win_a, hit_a, inbox.client_id, state.client_id),
         # crt_inst ("max slot seen + 1, any owner") advances from ANY
         # owner-plausible ACCEPT — including beyond-window ones a
         # revived laggard can't apply. Without this its in_flight stays
@@ -471,17 +468,9 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     rel_c, in_win_c = _rel(state, inbox.inst, S)
     com_ok = is_commit & in_win_c
     win_c, hit_c = slot_winner(S, rel_c, com_ok)
-    state = state._replace(
-        ballot=gather_row(win_c, hit_c, inbox.ballot, state.ballot),
+    state = slot_write(state, win_c, hit_c)._replace(
         status=jnp.where(hit_c, jnp.maximum(state.status, COMMITTED),
                          state.status),
-        op=gather_row(win_c, hit_c, inbox.op, state.op),
-        key_hi=gather_row(win_c, hit_c, inbox.key_hi, state.key_hi),
-        key_lo=gather_row(win_c, hit_c, inbox.key_lo, state.key_lo),
-        val_hi=gather_row(win_c, hit_c, inbox.val_hi, state.val_hi),
-        val_lo=gather_row(win_c, hit_c, inbox.val_lo, state.val_lo),
-        cmd_id=gather_row(win_c, hit_c, inbox.cmd_id, state.cmd_id),
-        client_id=gather_row(win_c, hit_c, inbox.client_id, state.client_id),
         # any COMMIT row advances crt_inst by both its inst and its
         # piggybacked sender frontier (last_committed): a healing
         # laggard otherwise thinks the log ends at each served chunk,
@@ -558,16 +547,8 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
             jnp.where(pir_ok, rel_v, S)].max(inbox.ballot, mode="drop")
         pir_win = pir_ok & (inbox.ballot == vb_max[rel_v_safe])
         win_v, hit_v = slot_winner(S, rel_v, pir_win)
-        state = state._replace(
-            ballot=gather_row(win_v, hit_v, inbox.ballot, state.ballot),
+        state = slot_write(state, win_v, hit_v)._replace(
             status=gather_const(hit_v, ACCEPTED, state.status),
-            op=gather_row(win_v, hit_v, inbox.op, state.op),
-            key_hi=gather_row(win_v, hit_v, inbox.key_hi, state.key_hi),
-            key_lo=gather_row(win_v, hit_v, inbox.key_lo, state.key_lo),
-            val_hi=gather_row(win_v, hit_v, inbox.val_hi, state.val_hi),
-            val_lo=gather_row(win_v, hit_v, inbox.val_lo, state.val_lo),
-            cmd_id=gather_row(win_v, hit_v, inbox.cmd_id, state.cmd_id),
-            client_id=gather_row(win_v, hit_v, inbox.client_id, state.client_id),
             votes=gather_const(hit_v, me_bit, state.votes),
         )
 

@@ -52,6 +52,7 @@ from minpaxos_tpu.ops.kvstore import (
 )
 from minpaxos_tpu.ops.scan import commit_frontier
 from minpaxos_tpu.ops.sections import Sections
+from minpaxos_tpu.ops.winner import gather_cols
 from minpaxos_tpu.wire.messages import MsgKind
 
 # Log-slot statuses (reference minpaxosproto.go:8-15 plus EXECUTED,
@@ -212,6 +213,12 @@ class MsgBatch(NamedTuple):
     def empty(m: int) -> "MsgBatch":
         z = jnp.zeros(m, dtype=jnp.int32)
         return MsgBatch(*([z] * 12))
+
+
+# What a slot write copies from its winning inbox row into the window:
+# the columns a message and the replica's state share by name.
+SLOT_FIELDS = ("ballot", "op", "key_hi", "key_lo", "val_hi", "val_lo",
+               "cmd_id", "client_id")
 
 
 class Outbox(NamedTuple):
@@ -528,8 +535,6 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     is_pir = k == int(MsgKind.PREPARE_INST_REPLY)
     # packed-bitmask identities for this replica / per-row senders
     me_bit = (jnp.int32(1) << state.me).astype(jnp.uint16)
-    src_bit = (jnp.int32(1) << jnp.clip(inbox.src, 0, R - 1)).astype(
-        jnp.uint16)
     rows_m = jnp.arange(M, dtype=jnp.int32)
     # every inst-addressed section (1c/2/2b/3) shares one window
     # translation of inbox.inst — computed once
@@ -603,20 +608,21 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     hitA = keyA >= 0
     secA_acc = keyA >= M  # winner came from the ACCEPT section
     rowA = jnp.mod(keyA, M)  # valid index even for keyA == -1 (masked)
+    # the winning row is fetched ONCE a pass, all its columns together
+    # (ops/winner.py gather_cols; until PR 34 nine element gathers by
+    # rowA here and eight by rowB in write B). The ninth column is the
+    # sender's bit, fetched into votes
+    slot_cols = [getattr(inbox, f) for f in SLOT_FIELDS]
+    src_bit = jnp.int32(1) << jnp.clip(inbox.src, 0, R - 1)
+    *colsA, votesA = gather_cols(
+        rowA, hitA, slot_cols + [src_bit],
+        [getattr(state, f) for f in SLOT_FIELDS] + [state.votes])
     state = state._replace(
-        ballot=jnp.where(hitA, inbox.ballot[rowA], state.ballot),
+        **dict(zip(SLOT_FIELDS, colsA)),
         status=jnp.where(hitA, jnp.uint8(ACCEPTED), state.status),
-        op=jnp.where(hitA, inbox.op[rowA].astype(state.op.dtype), state.op),
-        key_hi=jnp.where(hitA, inbox.key_hi[rowA], state.key_hi),
-        key_lo=jnp.where(hitA, inbox.key_lo[rowA], state.key_lo),
-        val_hi=jnp.where(hitA, inbox.val_hi[rowA], state.val_hi),
-        val_lo=jnp.where(hitA, inbox.val_lo[rowA], state.val_lo),
-        cmd_id=jnp.where(hitA, inbox.cmd_id[rowA], state.cmd_id),
-        client_id=jnp.where(hitA, inbox.client_id[rowA], state.client_id),
         # PIR adoption votes for itself; accepting a newer ballot
         # supersedes any older votes with the sender's bit
-        votes=jnp.where(hitA, jnp.where(secA_acc, src_bit[rowA], me_bit),
-                        state.votes),
+        votes=jnp.where(hitA & ~secA_acc, me_bit, votesA),
         default_ballot=jnp.maximum(state.default_ballot, acc_max_ballot),
         max_recv_ballot=jnp.maximum(state.max_recv_ballot, acc_max_ballot),
         # followers track the log extent so a later election starts
@@ -836,23 +842,18 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     hitB = keyB >= 0
     secB_prop = keyB >= M  # winner came from the PROPOSE section
     rowB = jnp.mod(keyB, M)
+    colsB = gather_cols(rowB, hitB, slot_cols,
+                        [getattr(state, f) for f in SLOT_FIELDS])
     state = state._replace(
+        **dict(zip(SLOT_FIELDS[1:], colsB[1:])),
         # propose stamps the serving ballot; commit keeps the row's
-        ballot=jnp.where(hitB, jnp.where(secB_prop, state.default_ballot,
-                                         inbox.ballot[rowB]), state.ballot),
+        ballot=jnp.where(hitB & secB_prop, state.default_ballot, colsB[0]),
         # commit never downgrades (max with COMMITTED); propose accepts
         status=jnp.where(
             hitB, jnp.where(secB_prop, jnp.uint8(ACCEPTED),
                             jnp.maximum(state.status,
                                         jnp.uint8(COMMITTED))),
             state.status),
-        op=jnp.where(hitB, inbox.op[rowB].astype(state.op.dtype), state.op),
-        key_hi=jnp.where(hitB, inbox.key_hi[rowB], state.key_hi),
-        key_lo=jnp.where(hitB, inbox.key_lo[rowB], state.key_lo),
-        val_hi=jnp.where(hitB, inbox.val_hi[rowB], state.val_hi),
-        val_lo=jnp.where(hitB, inbox.val_lo[rowB], state.val_lo),
-        cmd_id=jnp.where(hitB, inbox.cmd_id[rowB], state.cmd_id),
-        client_id=jnp.where(hitB, inbox.client_id[rowB], state.client_id),
         # only propose seeds votes (the leader votes for itself)
         votes=jnp.where(hitB & secB_prop, me_bit, state.votes),
         crt_inst=state.crt_inst + jnp.where(fits, 1, 0).sum(),

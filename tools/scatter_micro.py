@@ -19,6 +19,11 @@ M=1408 updates, S=2048 targets):
   g. rank-select (PR 29): the route's and Mencius's propose's "which
      row is the k-th" at the pod cells' shapes, device-timed:
      ``python tools/scatter_micro.py rankselect [part of a label]``
+  h. slot writes (PR 34): every column of a slot's winning inbox row
+     into the window, at the five call shapes of the cells, the
+     parent's element gathers beside each candidate formulation:
+     ``python tools/scatter_micro.py slotwrite [part of a label]
+     [leg=part of a formulation's name]``
 
 Run: python tools/scatter_micro.py (on the machine with the chip; one
 process owns it)
@@ -226,10 +231,151 @@ def rank_select_leg(shapes=RANK_SHAPES) -> None:
                   f"{temp / 1e6:7.1f} MB  equal {bool((got == ref).all())}")
 
 
+# -- slot writes (PR 34): "write the columns of each slot's winning
+# inbox row into the window", the pattern of MinPaxos's fused slot
+# writes A and B and of Mencius's propose / accept / commit rows
+# (ops/winner.py gather_cols). [G, R] x S slots from M kernel rows
+# (the tier's inbox rows + the round's proposal rows, as eval_shape
+# gives them for benchmarks/configs/*.json) x columns.
+SLOTWRITE_SHAPES = [
+    ("pod128 write A small", 128, 5, 640, 1024, 9),
+    ("pod128 write B small", 128, 5, 640, 1024, 8),
+    ("pod128 write A full", 128, 5, 1408, 1024, 9),
+    ("pod128 write B full", 128, 5, 1408, 1024, 8),
+    ("mencius64k propose small", 16, 5, 1216, 4096, 7),
+    ("mencius64k accept small", 16, 5, 1216, 4096, 8),
+    ("mencius64k propose full", 16, 5, 2112, 4096, 7),
+    ("mencius64k accept full", 16, 5, 2112, 4096, 8),
+    ("served3 write A", 3, 1, 1024, 2048, 9),
+    # longer inboxes, up to and beyond ops/winner.py ONEHOT_PAIRS
+    # (2**24 pairs of slot and row: the last of these is outside)
+    ("longer, 128 groups", 128, 5, 4096, 1024, 9),
+    ("longer, 128 groups", 128, 5, 6144, 1024, 9),
+    ("longer, 16 groups", 16, 5, 4096, 4096, 8),
+    ("longer, 16 groups", 16, 5, 8192, 4096, 8),
+]
+
+
+def slot_write_leg(shapes=SLOTWRITE_SHAPES) -> None:
+    """Leg h: one pass (fetch + the casts and selects under ``hit``)
+    in every formulation at every shape, device-timed; the last two
+    lines of a shape are what ``ops/winner.py`` does there, one pass
+    and two passes on one inbox."""
+    from minpaxos_tpu.ops import winner as w
+
+    def select(hit, got, olds):
+        return tuple(jnp.where(hit, g.astype(o.dtype), o)
+                     for g, o in zip(got, olds))
+
+    def elements(win, hit, cols, olds):
+        return select(hit, [c[win] for c in cols], olds)
+
+    def rows(lanes, barrier=False):
+        # without the barrier XLA folds pad, fetch and lane slices
+        # into ONE gather of [slots, columns]; with it the fetched
+        # [slots, lanes] plane is real
+        def f(win, hit, cols, olds):
+            got = jnp.stack(cols, -1)
+            got = jnp.pad(got, ((0, 0), (0, -len(cols) % lanes)))[win]
+            if barrier:
+                got = jax.lax.optimization_barrier(got)
+            return select(hit, [got[:, i] for i in range(len(cols))], olds)
+        return f
+
+    def stacked(win, hit, cols, olds):  # [cols, M], one gather along M
+        return select(hit, jnp.stack(cols)[:, win], olds)
+
+    def compare(win, hit, cols, olds):
+        eq = win[:, None] == jnp.arange(cols[0].shape[0])[None, :]
+        return select(hit, [jnp.where(eq, c[None, :], 0).sum(-1)
+                            for c in cols], olds)
+
+    def onehot_rows(win, hit, cols, olds):  # [slots, M] @ [M, bytes]
+        m = cols[0].shape[0]
+        oh = (win[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :]
+              ).astype(jnp.bfloat16)
+        by = jnp.stack([(c >> sh) & 0xFF for c in cols
+                        for sh in (0, 8, 16, 24)], -1).astype(jnp.bfloat16)
+        got = jnp.dot(oh, by, preferred_element_type=jnp.float32
+                      ).astype(jnp.int32)
+        return select(hit, [
+            got[:, 4 * i] | (got[:, 4 * i + 1] << 8)
+            | (got[:, 4 * i + 2] << 16) | (got[:, 4 * i + 3] << 24)
+            for i in range(len(cols))], olds)
+
+    def twice(win, hit, cols, olds):  # two passes on one inbox
+        a = w.gather_cols(win, hit, cols, olds)
+        return w.gather_cols(jnp.flip(win), hit, cols, a)
+
+    legs = {"element gathers (parent)": elements,
+            "stacked [cols, M]": stacked,
+            "rows (folded by XLA)": rows(128),
+            "rows, 16 lanes, barrier": rows(16, True),
+            "rows, 128 lanes, barrier": rows(128, True),
+            "compare": compare,
+            "one-hot matmul [S, bytes]": onehot_rows,
+            "gather_cols": w.gather_cols,
+            "gather_cols x2, one inbox": twice}
+    only = [a[4:] for a in sys.argv[2:] if a.startswith("leg=")]
+    rng = np.random.default_rng(0)
+    for label, g, r, m, s, ncol in shapes:
+        # a healthy round: an eighth of the window hit, by distinct
+        # rows; every other slot's index is the last row (MinPaxos's
+        # mod of a -1 key) and discarded under hit
+        n_hit = min(m, s // 8)
+        win = np.full((g, r, s), m - 1, np.int32)
+        hit = np.zeros((g, r, s), bool)
+        at = rng.integers(0, s - n_hit + 1)
+        win[..., at:at + n_hit] = rng.permuted(
+            np.broadcast_to(np.arange(m, dtype=np.int32), (g, r, m)),
+            axis=-1)[..., :n_hit]
+        hit[..., at:at + n_hit] = True
+        cols = tuple(jnp.asarray(rng.integers(
+            -2 ** 31, 2 ** 31, (g, r, m), dtype=np.int64).astype(np.int32))
+            for _ in range(ncol))
+        # the state's dtypes: op is uint8, MinPaxos's ninth (the
+        # sender's bit) uint16, every other column int32
+        dts = [jnp.int32, jnp.uint8] + [jnp.int32] * (ncol - 2)
+        if ncol == 9:
+            dts[-1] = jnp.uint16
+        olds = tuple(jnp.zeros((g, r, s), d) for d in dts)
+        win, hit = jnp.asarray(win), jnp.asarray(hit)
+        ref = None
+        print(f"h. slot write, {label}: [{g}, {r}] x {s} slots from "
+              f"{m} rows x {ncol} columns")
+        for name, f in legs.items():
+            if only and not any(o in name for o in only):
+                continue
+            fn = jax.jit(jax.vmap(jax.vmap(f)))
+            try:
+                temp = fn.lower(win, hit, cols, olds).compile() \
+                    .memory_analysis().temp_size_in_bytes
+                got = [np.asarray(x) for x in fn(win, hit, cols, olds)]
+            except Exception as e:  # a plane that does not fit
+                print(f"   {name:26s} failed: {type(e).__name__}: "
+                      f"{str(e)[:120]}")
+                continue
+            ref = got if ref is None else ref
+            same = name.startswith("gather_cols x2") or all(
+                (a == b).all() for a, b in zip(got, ref))
+            ms_dev = _device_ms(fn, win, hit, cols, olds)
+            dev = "not measured" if ms_dev is None else f"{ms_dev:9.3f} ms"
+            print(f"   {name:26s} device {dev}  host "
+                  f"{_time(fn, win, hit, cols, olds, iters=5):9.3f} ms  "
+                  f"temp {temp / 1e6:7.1f} MB  equal {same}", flush=True)
+
+
+def _labelled(shapes):
+    return [sh for sh in shapes
+            if all(a in sh[0] for a in sys.argv[2:] if "=" not in a)]
+
+
 if __name__ == "__main__":
     if sys.argv[1:2] == ["rankselect"]:  # leg g alone: [label part]
-        rank_select_leg([sh for sh in RANK_SHAPES
-                         if all(a in sh[0] for a in sys.argv[2:])])
+        rank_select_leg(_labelled(RANK_SHAPES))
+    elif sys.argv[1:2] == ["slotwrite"]:  # leg h: [label part] [leg=part]
+        slot_write_leg(_labelled(SLOTWRITE_SHAPES))
     else:
         main()
         rank_select_leg()
+        slot_write_leg()
