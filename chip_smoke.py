@@ -3,7 +3,7 @@
     python chip_smoke.py              # on a machine with a TPU: exit 0
     JAX_PLATFORMS=cpu python chip_smoke.py --dry-cpu   # debug the command
 
-ONE process owns the chip for the whole run and drives the system's two
+ONE process owns the chip for the whole run and drives the system's
 main paths through the entry points a user calls, at full width:
 
 * **Phase A — the device-resident consensus loop** (README "Sharded
@@ -24,6 +24,13 @@ main paths through the entry points a user calls, at full width:
   from the server binary's own flags at ``deployments.SERVER_SHAPE``;
   load arrives over localhost TCP from the normal client binary, a
   child that imports no JAX.
+
+* **Phase C — served Mencius** (``cli/server.py -m``, the benchmark's
+  configuration ``mencius3_durable``): three ``-m -durable`` replica
+  servers at ``deployments.MENCIUS_SERVER_SHAPE``, every one a proposer;
+  a few hundred requests on overlapping keys go round-robin over all
+  three owners (the upstream client's ``-e``) and every reply is held
+  to the merged log replayed slot by slot.
 
 Every check compares against something independent of the code under
 test: the proposal stream replayed on the host into a Python dict
@@ -92,6 +99,9 @@ DRY_CLIENT_Q, DRY_CLIENT_KEYS = 2_000, 1_000
 DRY_SERVER_SHAPE = ["-window", "1024", "-inbox", "1024", "-kvpow2", "12",
                     "-execbatch", "512"]
 BOOT_TIMEOUT_S = 600.0
+#: phase C load: enough for every owner to propose, cede and be ceded
+#: to; few keys, so that the three owners' writes collide
+MENCIUS_Q, MENCIUS_KEYS = 600, 48
 
 
 _T0 = time.monotonic()
@@ -342,14 +352,71 @@ def phase_a(meter: _CompileMeter, on_tpu: bool, seed: int) -> dict:
     return out
 
 
-# ------------------------------------------------------------ phase B
+# ------------------------------------------- phases B and C: served
+
+def _served_cluster(protocol_flag: str, shape: list, key_range: int):
+    """``(cluster, cfg, protocol)``: a master and three ``<protocol_flag>
+    -durable`` replica servers in this process, over a fresh store —
+    exactly what ``python -m minpaxos_tpu.cli.server <protocol_flag>
+    -durable <shape>`` would compile and run: the binary's own flag
+    parser. Returns once the cluster's own boot wait has held."""
+    from minpaxos_tpu.chaos.campaign import ChaosCluster
+    from minpaxos_tpu.cli import server as server_cli
+
+    store = ROOT / ".chip_smoke_store"
+    shutil.rmtree(store, ignore_errors=True)
+    store.mkdir()
+    args = server_cli.build_parser().parse_args(
+        [protocol_flag, "-durable", *shape, "-keyhint", str(key_range),
+         "-storedir", str(store)])
+    cfg = server_cli.config_from_args(args, 3)
+    flags = dataclasses.asdict(server_cli.flags_from_args(args))
+    for owned in ("durable", "store_dir"):  # ChaosCluster passes these
+        flags.pop(owned)
+    protocol = server_cli.protocol_from_args(args)
+    cluster = ChaosCluster(n=3, store_dir=str(store), durable=True,
+                           tick_s=flags.pop("tick_s"), flags=flags,
+                           cfg=cfg, boot_timeout_s=BOOT_TIMEOUT_S,
+                           protocol=protocol)
+    return cluster, cfg, protocol
+
+
+def _wait_first_ticks(cluster) -> None:
+    """warm_variants compiles every (k, narrow) step variant on each
+    protocol thread before its first tick: serve only once all three
+    have ticked."""
+    deadline = time.monotonic() + BOOT_TIMEOUT_S
+    while not all(s.stats["ticks"] > 0 for s in cluster.servers.values()):
+        if time.monotonic() > deadline:
+            raise TimeoutError("replicas never ticked after boot")
+        time.sleep(0.05)
+
+
+def _wait_converged(cluster) -> tuple[bool, list]:
+    """Quiesce: every replica committed AND executed everything (under
+    mencius: three equal MERGED frontiers)."""
+    deadline = time.monotonic() + 60
+    converged, snaps = False, []
+    while not converged and time.monotonic() < deadline:
+        time.sleep(0.05)
+        snaps = [s.snapshot for s in cluster.servers.values()]
+        converged = (len({s["frontier"] for s in snaps}) == 1
+                     and all(s.get("executed") == s["frontier"]
+                             for s in snaps))
+    time.sleep(0.3)  # no in-flight appends under the checker
+    return converged, snaps
+
+
+def _served_shape(cfg, **more) -> dict:
+    return {"n_replicas": cfg.n_replicas, "window": cfg.window,
+            "inbox": cfg.inbox, "kv_pow2": cfg.kv_pow2,
+            "exec_batch": cfg.exec_batch, "durable_fsync": True, **more}
+
 
 def phase_b(meter: _CompileMeter, on_tpu: bool) -> dict:
     import jax
     import numpy as np
 
-    from minpaxos_tpu.chaos.campaign import ChaosCluster
-    from minpaxos_tpu.cli import server as server_cli
     from minpaxos_tpu.deployments import SERVER_SHAPE
     from minpaxos_tpu.ops.kvstore import LIVE, kv_lookup
     from minpaxos_tpu.ops.packed import split_i64
@@ -357,45 +424,20 @@ def phase_b(meter: _CompileMeter, on_tpu: bool) -> dict:
     from minpaxos_tpu.verify.invariants import check_cluster
     from minpaxos_tpu.wire.messages import Op
 
-    n = 3
     q, key_range = ((CLIENT_Q, CLIENT_KEYS) if on_tpu
                     else (DRY_CLIENT_Q, DRY_CLIENT_KEYS))
-    shape = SERVER_SHAPE if on_tpu else DRY_SERVER_SHAPE
-    store = ROOT / ".chip_smoke_store"
-    shutil.rmtree(store, ignore_errors=True)
-    store.mkdir()
-    # exactly what `python -m minpaxos_tpu.cli.server -min -durable
-    # <shape>` would compile and run — the binary's own flag parser
-    args = server_cli.build_parser().parse_args(
-        ["-min", "-durable", *shape, "-keyhint", str(key_range),
-         "-storedir", str(store)])
-    cfg = server_cli.config_from_args(args, n)
-    flags = dataclasses.asdict(server_cli.flags_from_args(args))
-    for owned in ("durable", "store_dir"):  # ChaosCluster passes these
-        flags.pop(owned)
+    t_phase = time.perf_counter()
+    cluster, cfg, _ = _served_cluster(
+        "-min", SERVER_SHAPE if on_tpu else DRY_SERVER_SHAPE, key_range)
+    _log(f"B: leader prepared after {time.perf_counter() - t_phase:.1f}s")
     out: dict = {
-        "shape": {"n_replicas": n, "window": cfg.window,
-                  "inbox": cfg.inbox, "kv_pow2": cfg.kv_pow2,
-                  "exec_batch": cfg.exec_batch, "durable_fsync": True,
-                  "requests": q, "key_range": key_range, "write_pct": 50},
+        "shape": _served_shape(cfg, requests=q, key_range=key_range,
+                               write_pct=50),
         "checks": {}}
     checks = out["checks"]
-    t_phase = time.perf_counter()
-    cluster = ChaosCluster(n=n, store_dir=str(store), durable=True,
-                           tick_s=flags.pop("tick_s"), flags=flags,
-                           cfg=cfg, boot_timeout_s=BOOT_TIMEOUT_S)
-    _log(f"B: leader prepared after {time.perf_counter() - t_phase:.1f}s")
     cli = None
     try:
-        # warm_variants compiles every (k, narrow) step variant on each
-        # protocol thread before its first tick: serve only once all
-        # three have ticked
-        deadline = time.monotonic() + BOOT_TIMEOUT_S
-        while not all(s.stats["ticks"] > 0
-                      for s in cluster.servers.values()):
-            if time.monotonic() > deadline:
-                raise TimeoutError("replicas never ticked after boot")
-            time.sleep(0.05)
+        _wait_first_ticks(cluster)
         out["setup"] = dict(meter.take(),
                             wall_s=round(time.perf_counter() - t_phase, 1))
         _log(f"B: 3 replicas serving after {out['setup']['wall_s']}s")
@@ -434,17 +476,7 @@ def phase_b(meter: _CompileMeter, on_tpu: bool) -> dict:
         checks["readback_all_acked_once"] = (
             st["acked"] == rb and st["duplicates"] == 0)
 
-        # -- quiesce: every replica committed AND executed everything
-        deadline = time.monotonic() + 60
-        converged = False
-        while not converged and time.monotonic() < deadline:
-            time.sleep(0.05)
-            snaps = [s.snapshot for s in cluster.servers.values()]
-            converged = (len({s["frontier"] for s in snaps}) == 1
-                         and all(s.get("executed") == s["frontier"]
-                                 for s in snaps))
-        checks["replicas_converged"] = converged
-        time.sleep(0.3)  # no in-flight appends under the checker
+        checks["replicas_converged"], _ = _wait_converged(cluster)
 
         # the reply book the checker holds the log to: every PUT the
         # client binary reported acknowledged (an acked write absent
@@ -500,7 +532,96 @@ def phase_b(meter: _CompileMeter, on_tpu: bool) -> dict:
     out["serve"] = meter.take()
     out["ticks"] = {str(r): s.stats["ticks"]
                     for r, s in sorted(cluster.servers.items())}
-    shutil.rmtree(store, ignore_errors=True)
+    shutil.rmtree(cluster.store_dir, ignore_errors=True)
+    out["wall_s"] = round(time.perf_counter() - t_phase, 1)
+    return out
+
+
+# ------------------------------------------------------------ phase C
+
+def phase_c(meter: _CompileMeter, on_tpu: bool) -> dict:
+    import threading
+
+    import numpy as np
+
+    from minpaxos_tpu.deployments import MENCIUS_SERVER_SHAPE
+    from minpaxos_tpu.runtime.client import MultiClient, gen_workload
+    from minpaxos_tpu.verify.invariants import check_cluster
+    from minpaxos_tpu.wire.messages import Op
+
+    n, q = 3, MENCIUS_Q
+    t_phase = time.perf_counter()
+    cluster, cfg, protocol = _served_cluster(
+        "-m", MENCIUS_SERVER_SHAPE if on_tpu else DRY_SERVER_SHAPE,
+        MENCIUS_KEYS)
+    out: dict = {
+        "shape": _served_shape(cfg, protocol=protocol, requests=q,
+                               key_range=MENCIUS_KEYS, write_pct=50),
+        "checks": {}}
+    checks = out["checks"]
+    mc = None
+    try:
+        _wait_first_ticks(cluster)
+        out["setup"] = dict(meter.take(),
+                            wall_s=round(time.perf_counter() - t_phase, 1))
+        _log(f"C: 3 owners serving after {out['setup']['wall_s']}s")
+        checks["every_replica_runs_mencius"] = all(
+            s.protocol == "mencius" for s in cluster.servers.values())
+
+        # -- load: one connection an owner, in this process (the client
+        # imports no JAX); owner 0 gets half, so the others cede turns
+        ops, keys, vals = gen_workload(q, conflict_pct=0,
+                                       key_range=MENCIUS_KEYS, zipf_s=0.0,
+                                       write_pct=50, seed=35)
+        mc = MultiClient(cluster.maddr, check=True, mode="rr")
+        parts = [np.nonzero(np.isin(np.arange(q) % 4, own))[0]
+                 for own in ((0, 1), (2,), (3,))]
+        results: list = [None] * n
+        threads = [threading.Thread(
+            target=lambda r=r: results.__setitem__(
+                r, mc.clients[r].run_partition(parts[r], ops, keys, vals,
+                                               timeout_s=300.0)),
+            daemon=True) for r in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=330.0)
+        out["acked_by_owner"] = [r and r["acked"] for r in results]
+        checks["all_acked_once"] = (
+            out["acked_by_owner"] == [len(p) for p in parts]
+            and sum(r["duplicates"] for r in results) == 0)
+
+        checks["merged_frontiers_converged"], snaps = _wait_converged(
+            cluster)
+
+        # every reply against the merged log replayed slot by slot
+        replies: dict = {}
+        for c in mc.clients:
+            with c._lock:
+                replies.update(c.replies)
+        report = check_cluster(cluster.stores(), replies=replies,
+                               workload=(ops, keys, vals))
+        out["invariants"] = report.to_dict()
+        checks["invariants_hold"] = report.ok
+        checks["every_get_checked"] = (
+            report.checked_gets == int((ops == int(Op.GET)).sum()))
+        stats = [s.stats for _, s in sorted(cluster.servers.items())]
+        out["owner_counters"] = [
+            {k: st[k] for k in ("client_proposals", "command_slots",
+                                "noop_slots", "dispatches")} for st in stats]
+        checks["every_owner_proposed"] = (
+            [st["client_proposals"] for st in stats]
+            == [len(p) for p in parts])
+        checks["slots_add_up"] = all(
+            st["command_slots"] == q
+            and st["command_slots"] + st["noop_slots"]
+            == snaps[0]["frontier"] + 1 for st in stats)
+    finally:
+        if mc is not None:
+            mc.close()
+        cluster.stop()
+    out["serve"] = meter.take()
+    shutil.rmtree(cluster.store_dir, ignore_errors=True)
     out["wall_s"] = round(time.perf_counter() - t_phase, 1)
     return out
 
@@ -565,9 +686,11 @@ def main(argv=None) -> int:
     _log(f"A: checks {result['phase_a']['checks']}")
     result["phase_b"] = phase_b(meter, on_tpu)
     _log(f"B: checks {result['phase_b']['checks']}")
+    result["phase_c"] = phase_c(meter, on_tpu)
+    _log(f"C: checks {result['phase_c']['checks']}")
     result["wall_s"] = round(time.monotonic() - _T0, 1)
     held = all(all(result[ph]["checks"].values())
-               for ph in ("phase_a", "phase_b"))
+               for ph in ("phase_a", "phase_b", "phase_c"))
     print(json.dumps({"record": "chip_smoke", "checks_held": held,
                       **result}), flush=True)
     print(json.dumps(verdict(held, device, args.dry_cpu)), flush=True)

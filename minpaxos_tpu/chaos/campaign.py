@@ -219,12 +219,17 @@ class ChaosCluster:
                  durable: bool = False, tick_s: float = 0.001,
                  q1: int = 0, q2: int = 0,
                  flags: dict | None = None, cfg=None,
-                 boot_timeout_s: float = 20.0):
+                 boot_timeout_s: float = 20.0,
+                 protocol: str = "minpaxos"):
         """``cfg``: a full MinPaxosConfig in place of the 1,024-slot
         harness default (chip_smoke.py serves at
         ``deployments.SERVER_SHAPE``); ``boot_timeout_s``: how long the
         leader may take to report prepared — a first compile on a TPU
-        outlasts the CPU-calibrated 20 s."""
+        outlasts the CPU-calibrated 20 s; ``protocol``: what every
+        replica runs, restarts included (``cli.server``'s
+        ``protocol_from_args``). Under ``"mencius"`` the boot wait
+        below is true by convention (no leader, ``prepared`` always
+        set): wait for every replica's first tick instead."""
         # late imports: chaos/__init__ must stay importable without JAX
         from minpaxos_tpu.models.minpaxos import MinPaxosConfig
         from minpaxos_tpu.runtime.master import Master, register_with_master
@@ -262,13 +267,15 @@ class ChaosCluster:
             validate_config_quorums(self.cfg)
             # extra RuntimeFlags fields (e.g. paxsoak sizing the
             # ingress coalescer's row cap to the host's commit rate so
-            # the admission gate engages at realistic queue depths)
-            self._mk_flags = lambda: RuntimeFlags(
-                durable=durable, store_dir=store_dir, tick_s=tick_s,
-                **(flags or {}))
+            # the admission gate engages at realistic queue depths);
+            # one builder for the boot and for every restart
+            self._mk_server = lambda rid: ReplicaServer(
+                rid, self.addrs, self.cfg,
+                RuntimeFlags(durable=durable, store_dir=store_dir,
+                             tick_s=tick_s, **(flags or {})),
+                protocol=protocol)
             for i in range(n):
-                s = ReplicaServer(i, self.addrs, self.cfg,
-                                  self._mk_flags())
+                s = self._mk_server(i)
                 s.start()
                 self.servers[i] = s
             # "prepared" is leader state (replica 0 owns the initial
@@ -303,10 +310,8 @@ class ChaosCluster:
         kept the (host, port) registration; its ping loop sees the
         replica alive again once the listener is back (transport's
         bind retries cover the TIME_WAIT window)."""
-        from minpaxos_tpu.runtime.replica import ReplicaServer
-
         self.servers[rid].stop()  # idempotent after crash()
-        s = ReplicaServer(rid, self.addrs, self.cfg, self._mk_flags())
+        s = self._mk_server(rid)
         s.start()
         # single-key assignment, never a pop: the sampler thread
         # iterates this dict concurrently and must not see it resize

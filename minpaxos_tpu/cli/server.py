@@ -222,6 +222,13 @@ def config_from_args(args, n_replicas: int):
     return cfg
 
 
+def protocol_from_args(args) -> str:
+    """The protocol these flags name (``ReplicaServer``'s ``protocol``):
+    ``-m`` wins over ``-classic``, ``-min`` is the default."""
+    return ("mencius" if args.mencius
+            else "classic" if args.classic else "minpaxos")
+
+
 def flags_from_args(args, profile=None):
     """The RuntimeFlags these flags select — long-lived deployments
     precompile their step variants (warm_variants)."""
@@ -296,9 +303,7 @@ def main(argv=None) -> None:
     prof = cProfile.Profile() if args.cpuprofile else None
     server = ReplicaServer(my_id, [tuple(n) for n in nodes], cfg,
                            flags_from_args(args, profile=prof),
-                           protocol=("mencius" if args.mencius
-                                     else "classic" if args.classic
-                                     else "minpaxos"))
+                           protocol=protocol_from_args(args))
 
     server.start()
     print(f"server: replica {my_id} serving on {args.addr}:{args.port}",

@@ -27,6 +27,17 @@ CPU_SHAPE = (8, 512, 64, 8)
 SERVER_SHAPE = ["-window", "2048", "-inbox", "1024", "-kvpow2", "18",
                 "-execbatch", "128"]
 
+#: the served N=3 ``-m -durable`` cluster (upstream server flag ``-m``,
+#: clients round-robin over all owners, ``-e``): window 4096 / inbox
+#: 2048 / table 2^18 / exec batch 512. Every replica proposes, so an
+#: inbox holds its own clients' rows AND two peers' ACCEPT, COMMIT and
+#: SKIP rows, and the log takes a slot for every turn an owner cedes:
+#: ``SERVER_SHAPE``'s 2048 / 1024 starved it on the CPU. The chip's
+#: readings behind each size are in ``benchmarks/configs/
+#: mencius3_durable.json`` under ``assumed``.
+MENCIUS_SERVER_SHAPE = ["-window", "4096", "-inbox", "2048", "-kvpow2", "18",
+                        "-execbatch", "512"]
+
 
 def cpu_catchup_rows(p: int) -> int:
     """Catch-up rows of the CPU harness shape: 2p, held to [64, 512].

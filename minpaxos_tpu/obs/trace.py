@@ -111,15 +111,23 @@ def trace_id_for(cmd_id) -> np.ndarray | int:
 #: client's own measured send span and wins over ORIGIN when both were
 #: collected.
 (ST_SEND, ST_ORIGIN, ST_DECODE, ST_DRAIN, ST_COMMIT, ST_EXEC,
- ST_REPLY_SER, ST_REPLY_RECV) = range(8)
-N_STAGES = 8
+ ST_REPLY_SER, ST_REPLY_RECV, ST_OWN_COMMIT) = range(9)
+N_STAGES = 9
+#: OWN_COMMIT is Mencius's alone and optional in a chain (appended, so
+#: the older stages keep their numbers): the readback of the dispatch
+#: in which the owner's COMMIT row of the command's OWN slot left the
+#: device. COMMIT stays what it was under every protocol — the
+#: readback whose frontier covered the slot, which under Mencius is the
+#: MERGED frontier over all owners. OWN_COMMIT -> COMMIT is the
+#: ``merge_wait``: how long a command that its own quorum had settled
+#: waited for the slots the other owners hold below it.
 STAGE_NAMES = ("send", "origin", "decode", "drain", "commit", "exec",
-               "reply_ser", "reply_recv")
+               "reply_ser", "reply_recv", "own_commit")
 
 # span-row field layout: trace id, stage, start/end ns (monotonic),
 # aux (stage-specific: cmd_id for client/ingress stages, the log slot
-# for COMMIT, the owner's dispatch count for DRAIN/EXEC — the round-id
-# correlation into the flight recorder / paxray rows)
+# for COMMIT and OWN_COMMIT, the owner's dispatch count for DRAIN/EXEC
+# — the round-id correlation into the flight recorder / paxray rows)
 (SP_TRACE, SP_STAGE, SP_T0, SP_T1, SP_AUX) = range(5)
 N_SPAN_FIELDS = 5
 
@@ -354,7 +362,7 @@ def align_collections(collections: list[dict],
 #: (the reply that actually happened) and each earlier stage picks
 #: the newest duplicate that still FITS under the next boundary.
 _SELECT_ORDER = (ST_REPLY_RECV, ST_REPLY_SER, ST_EXEC, ST_COMMIT,
-                 ST_DRAIN, ST_DECODE, ST_SEND, ST_ORIGIN)
+                 ST_OWN_COMMIT, ST_DRAIN, ST_DECODE, ST_SEND, ST_ORIGIN)
 #: per-stage slack for the fit test (and stage_decomposition's stale
 #: guard): writer threads stamp independently, so adjacent boundaries
 #: can jitter ~µs out of order on a real host.
@@ -401,6 +409,15 @@ def span_chains(spans: np.ndarray) -> dict[int, dict[int, tuple]]:
             bound = pick[1]
         chains[tid] = sel
     return chains
+
+
+def merge_wait_ms(chain: dict[int, tuple]) -> float | None:
+    """One chain's ``merge_wait``: OWN_COMMIT -> COMMIT in ms, the tail
+    of its ``commit`` stage; None without both stamps."""
+    own, merged = chain.get(ST_OWN_COMMIT), chain.get(ST_COMMIT)
+    if own is None or merged is None:
+        return None
+    return (merged[1] - own[1]) / 1e6
 
 
 def stage_decomposition(chains: dict[int, dict[int, tuple]]) -> list[dict]:
@@ -452,6 +469,9 @@ def stage_decomposition(chains: dict[int, dict[int, tuple]]) -> list[dict]:
             "commit_dispatches": st[ST_EXEC][2] - st[ST_DRAIN][2],
             "total_ms": (bounds[-1] - bounds[0]) / 1e6,
             "stages": stages,
+            # the part of ``commit`` spent AFTER the command's own slot
+            # was settled (Mencius; None where no own_commit was stamped)
+            "merge_wait_ms": merge_wait_ms(st),
         })
     return out
 

@@ -69,6 +69,7 @@ from minpaxos_tpu.obs.trace import (
     ST_DRAIN,
     ST_EXEC,
     ST_ORIGIN,
+    ST_OWN_COMMIT,
     ST_REPLY_SER,
     TraceSink,
     protocol_ring_capacity,
@@ -427,6 +428,23 @@ class ReplicaServer:
             "the estimate high forever and an IDLE cluster looks "
             "permanently loaded to the stall detector")
         self._c_executed = m.counter("executed", "commands executed")
+        # what the log is made of, per replica (the served twins of the
+        # pod's command_commits / noop_slots): client rows this replica
+        # gave a slot of its OWN (every replica is a proposer under
+        # mencius, the leader alone otherwise), and the slots it
+        # executed by kind. Every slot executes once, so at quiesce
+        # noop_slots + command_slots is what the (merged) frontier
+        # passed, and the cluster's client_proposals sum to
+        # command_slots while no takeover re-drives a slot.
+        self._c_client_proposals = m.counter(
+            "client_proposals", "client command rows this replica "
+            "assigned a log slot and broadcast as its own ACCEPTs")
+        self._c_noop_slots = m.counter(
+            "noop_slots", "executed slots that held no command: ceded "
+            "(SKIP), takeover- or recovery-filled no-ops")
+        self._c_command_slots = m.counter(
+            "command_slots", "executed slots that held a client "
+            "command, whichever replica proposed it")
         self._g_committed = m.gauge("committed",
                                     "committed prefix length (frontier+1)")
         self._h_tick = m.histogram(
@@ -471,6 +489,10 @@ class ReplicaServer:
         # window; heap so the per-dispatch pop is O(covered), never a
         # scan of everything still above the frontier)
         self._trace_slots: list[tuple[int, int]] = []
+        # mencius only: sampled own slots whose COMMIT row has not left
+        # the device yet, slot -> cmd_id (the own_commit stamp; an
+        # entry goes when its row leaves or the frontier passes it)
+        self._trace_own: dict[int, int] = {}
         self._last_scals = None  # newest published scalar vector
         # ingress admission state — written by the protocol thread
         # (_update_burn), read lock-free by the coalescer's gate on
@@ -588,6 +610,11 @@ class ReplicaServer:
         # anchors through the full-width step)
         self._inflight: _InflightTick | None = None
         self._narrow_doubt = False
+        # mencius only (_skip_rows_that_fit): per ceding owner, the
+        # span of own slots its SKIP rows of the batch being gathered
+        # cover, and the frame (or rest of one) held over for the next
+        self._skip_span: dict[int, tuple[int, int]] = {}
+        self._held = None
 
     @property
     def stats(self) -> dict:
@@ -1272,7 +1299,7 @@ class ReplicaServer:
                 snap = self.snapshot
                 prev_exec = int(snap.get("executed", -1))
                 if (snap["frontier"] <= prev_exec or self.inbox.fill
-                        or not self.queue.empty()):
+                        or self._more_queued()):
                     break
                 self._device_tick(self.inbox)
                 if int(self.snapshot.get("executed", -1)) <= prev_exec:
@@ -1351,15 +1378,45 @@ class ReplicaServer:
     def _drain(self, timeout_s: float) -> bool:
         """Pull queued frames into the inbox buffer; returns whether a
         be_the_leader control event arrived."""
-        try:
-            # the blocking wait is its own phase: idle pacing is not
-            # drain cost, but it is where a loaded tick's wall can go
-            with phase(PH_WAIT, self._clock):
-                item = self.queue.get(timeout=timeout_s)
-        except queue.Empty:
-            return False
+        item, self._held = self._held, None
+        if item is None:
+            try:
+                # the blocking wait is its own phase: idle pacing is
+                # not drain cost, but it is where a loaded tick's wall
+                # can go
+                with phase(PH_WAIT, self._clock):
+                    item = self.queue.get(timeout=timeout_s)
+            except queue.Empty:
+                return False
         with phase(PH_DRAIN, self._clock):
             return self._drain_frames(item)
+
+    def _more_queued(self) -> bool:
+        """Whether the next dispatch already has a frame to carry."""
+        return self._held is not None or not self.queue.empty()
+
+    def _skip_rows_that_fit(self, rows) -> int:
+        """How many leading rows of a peer's SKIP frame may join the
+        batch being gathered (mencius). The kernel folds ALL of one
+        owner's SKIP rows of a batch into ONE range, least start to
+        greatest end (models/mencius.py section 4): exact while the
+        rows' ranges touch, wrong when the owner proposed between two
+        cedes — its value, lying between the ranges, would be
+        committed here as a no-op (served Mencius on three loaded
+        owners sends cede, ACCEPT, cede in one TCP read; the pod routes
+        one SKIP row an owner a round and never does). So a row joins
+        only if no own slot of its owner lies between its range and
+        what the batch already holds of that owner; the rest of the
+        frame waits for the next dispatch."""
+        r = self.cfg.n_replicas
+        for i, (owner, start, end) in enumerate(zip(
+                rows["leader_id"].tolist(), rows["start_inst"].tolist(),
+                rows["end_inst"].tolist())):
+            lo, hi = self._skip_span.get(owner, (start, end))
+            if start > hi + r or end < lo - r:
+                return i
+            self._skip_span[owner] = (min(lo, start), max(hi, end))
+        return len(rows)
 
     def _drain_frames(self, item) -> bool:
         """The work of a drain — decode, dedup, registration — from the
@@ -1522,6 +1579,13 @@ class ReplicaServer:
                     # sweep reaches slots beyond every follower's
                     # window (round-5 wedge hunt).
                     self._store_answer_sweep(rows)
+                if kind == MsgKind.SKIP and self.protocol == "mencius":
+                    n_fit = self._skip_rows_that_fit(rows)
+                    if n_fit < len(rows):
+                        self._held = (src_kind, conn_id, kind, rows[n_fit:])
+                        batches.frame_to_rows(self.inbox, kind,
+                                              rows[:n_fit], conn_id)
+                        break
                 batches.frame_to_rows(self.inbox, kind, rows, conn_id)
             if self.inbox.room() <= 0:
                 break
@@ -1730,6 +1794,7 @@ class ReplicaServer:
         clock = self._clock
         with phase(PH_ENQUEUE, clock):
             cols, n_rows = buf.drain()
+            self._skip_span.clear()  # the next batch starts empty
             inbox = MsgBatch(**{c: np.asarray(cols[c])
                                 for c in batches.COLS})
             k = self._choose_fuse(n_rows)
@@ -1754,7 +1819,7 @@ class ReplicaServer:
         # idle interval away — a serial op's reply must not wait for
         # it, so complete in place (this IS the pre-pipeline order).
         if not (self.flags.pipeline and persist and dispatch
-                and not self.queue.empty()):
+                and self._more_queued()):
             self._flush_inflight()
 
     def _read_back(self, cols: dict, n_rows: int, k: int, narrow: int,
@@ -1855,6 +1920,12 @@ class ReplicaServer:
         # lazy read later would block on — and read — the next step
         pc = None if mencius else np.asarray(self.state.peer_commits)
         rows_out = int((out_mats[:, 0, :] != 0).sum())  # col 0 = kind
+        # a client row that got a slot comes back as this replica's own
+        # ACCEPT broadcast at the same row (the alignment _persist uses)
+        if n_rows:
+            self._c_client_proposals.inc(int((
+                (cols["kind"][:n_rows] == int(MsgKind.PROPOSE))
+                & (out_mats[0, 0, :n_rows] == int(MsgKind.ACCEPT))).sum()))
         exec_total = int(scals[:, SCAL_EXEC_COUNT].sum())
         self._idle = (n_rows == 0 and rows_out == 0 and exec_total == 0)
         # KV saturation is a correctness failure, not a statistic: a
@@ -1995,7 +2066,8 @@ class ReplicaServer:
     # -- paxtrace: slot assignment + commit stamps (protocol thread) --
 
     def _trace_commits(self, rec: _InflightTick) -> None:
-        """Two paxtrace duties per dispatch, both O(sampled):
+        """Two paxtrace duties per dispatch (three under mencius: the
+        ST_OWN_COMMIT stamp between them), all O(sampled):
 
         1. learn the log slot of every SAMPLED proposal this tick
            admitted — the kernel's ACCEPT broadcast at outbox row i
@@ -2036,11 +2108,26 @@ class ReplicaServer:
                             continue
                         heapq.heappush(self._trace_slots,
                                        (int(out_inst[i]), int(ids[i])))
+                        if self.protocol == "mencius":
+                            self._trace_own[int(out_inst[i])] = int(ids[i])
+        own = self._trace_own
+        if own:
+            # mencius: the owner's COMMIT row of a tracked slot left
+            # the device in this dispatch — its own quorum is settled;
+            # what remains until ST_COMMIT below is the wait for the
+            # other owners' slots under it (the merge_wait)
+            kinds = rec.out_mats[:, 0, :]
+            sent = rec.out_mats[:, 3, :][kinds == int(MsgKind.COMMIT)]
+            ring = sink.ring()
+            for s in own.keys() & set(sent.tolist()):
+                ring.record(trace_id_for(own.pop(s)), ST_OWN_COMMIT,
+                            rec.t_rb_ns, rec.t_rb_ns, s)
         if self._trace_slots and self._trace_slots[0][0] <= rec.frontier:
             ring = sink.ring()
             while self._trace_slots and \
                     self._trace_slots[0][0] <= rec.frontier:
                 s, cmd = heapq.heappop(self._trace_slots)
+                own.pop(s, None)  # passed before its row left: no stamp
                 ring.record(trace_id_for(cmd), ST_COMMIT,
                             rec.t_rb_ns, rec.t_rb_ns, s)
 
@@ -2227,6 +2314,11 @@ class ReplicaServer:
         total = int(counts.sum())
         self._c_executed.inc(total)
         self._g_committed.set(frontier + 1)
+        if total:
+            noops = sum(int((exec_mats[i][3][:int(c)] == int(Op.NONE)).sum())
+                        for i, c in enumerate(counts) if c > 0)  # row 3 = op
+            self._c_noop_slots.inc(noops)
+            self._c_command_slots.inc(total - noops)
         if total == 0 or not self.flags.dreply:
             return
         if DLOG:

@@ -262,6 +262,13 @@ class Transport:
             ch.stop(flush=False)  # shutting down: nothing to heal into
         if self._listener is not None:
             try:
+                # wake the accept loop: a close alone leaves a blocked
+                # accept() holding the port, and a replica restarted on
+                # it (ChaosCluster.restart) fails its bind
+                self._listener.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
+            try:
                 self._listener.close()
             except OSError:
                 pass
