@@ -79,7 +79,12 @@ from minpaxos_tpu.ops.kvstore import (
 from minpaxos_tpu.ops.rankselect import rank_select
 from minpaxos_tpu.ops.scan import commit_frontier, segmented_scan_max
 from minpaxos_tpu.ops.sections import Sections
-from minpaxos_tpu.ops.winner import gather_cols, gather_const, slot_winner
+from minpaxos_tpu.ops.winner import (
+    gather_cols,
+    gather_const,
+    read_cols,
+    slot_winner,
+)
 from minpaxos_tpu.wire.messages import MsgKind, Op
 
 
@@ -299,15 +304,20 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     rel_a_safe = jnp.minimum(rel_a, S - 1)
     # only the slot's owner (or a takeover ballot > current) may write
     owner_ok = jnp.mod(inbox.inst, R) == inbox.src
+    # a row's slot is read ONCE per version of STATE, all the columns
+    # a section tests together (ops/winner.py read_cols; until PR 36 an
+    # element gather a column, here and in section 11)
+    ballot_a, status_a = read_cols(rel_a_safe, (state.ballot, state.status))
     acc_pre = (
         is_accept & in_win_a
         & (owner_ok | (inbox.ballot > 0))
-        & (inbox.ballot >= state.ballot[rel_a_safe])
-        & (state.status[rel_a_safe] < COMMITTED)
+        & (inbox.ballot >= ballot_a)
+        & (status_a < COMMITTED)
     )
     ab_max = jnp.full(S + 1, NO_BALLOT, jnp.int32).at[
         jnp.where(acc_pre, rel_a, S)].max(inbox.ballot, mode="drop")
-    acc_ok = acc_pre & (inbox.ballot == ab_max[rel_a_safe])
+    ab_max_a, = read_cols(rel_a_safe, (ab_max[:S],))
+    acc_ok = acc_pre & (inbox.ballot == ab_max_a)
     win_a, hit_a = slot_winner(S, rel_a, acc_ok)
     state = slot_write(state, win_a, hit_a)._replace(
         status=gather_const(hit_a, ACCEPTED, state.status),
@@ -330,16 +340,21 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     # ACCEPT arriving after a takeover committed a no-op here must NACK,
     # or the owner could assemble a majority for a conflicting value
     # (vote-for-the-decided-value rule, as in models/minpaxos.py)
+    # after the slot write: status and the seven payload columns
+    status_a, *slot_a = read_cols(
+        rel_a_safe,
+        (state.status,) + tuple(getattr(state, f) for f in SLOT_FIELDS[1:]))
+    slot_a = dict(zip(SLOT_FIELDS[1:], slot_a))
     acc_dup_ok = (
         is_accept & in_win_a
-        & (state.status[rel_a_safe] >= COMMITTED)
-        & (state.op[rel_a_safe] == inbox.op)
-        & (state.key_hi[rel_a_safe] == inbox.key_hi)
-        & (state.key_lo[rel_a_safe] == inbox.key_lo)
-        & (state.val_hi[rel_a_safe] == inbox.val_hi)
-        & (state.val_lo[rel_a_safe] == inbox.val_lo)
-        & (state.cmd_id[rel_a_safe] == inbox.cmd_id)
-        & (state.client_id[rel_a_safe] == inbox.client_id)
+        & (status_a >= COMMITTED)
+        & (slot_a["op"] == inbox.op)
+        & (slot_a["key_hi"] == inbox.key_hi)
+        & (slot_a["key_lo"] == inbox.key_lo)
+        & (slot_a["val_hi"] == inbox.val_hi)
+        & (slot_a["val_lo"] == inbox.val_lo)
+        & (slot_a["cmd_id"] == inbox.cmd_id)
+        & (slot_a["client_id"] == inbox.client_id)
     )
     # run-length compressed acks (same scheme as models/minpaxos.py
     # step 2; cmd_id = run length -> wire `count`) at the protocol's
@@ -583,6 +598,19 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     # unboundedly. commit_sent is the last own slot announced; foreign
     # commits are their owners' jobs (takeover commits: see 9b).
     K = cfg.catchup_rows
+
+    def slots_at(rel_safe, *more):
+        """status, the slot's ``SLOT_FIELDS`` (op widened to the wire's
+        int32) and ``more`` columns at the window indices ``rel_safe``,
+        in one fetch (read_cols): what 9, 9b, 9c and 9d each send of
+        the slots they announce."""
+        status, *cols = read_cols(
+            rel_safe, (state.status,)
+            + tuple(getattr(state, f) for f in SLOT_FIELDS) + more)
+        slot = dict(zip(SLOT_FIELDS, cols))
+        slot["op"] = slot["op"].astype(jnp.int32)
+        return status, slot, cols[len(SLOT_FIELDS):]
+
     # never let the cursor fall below the window (slid-out slots were
     # executed everywhere; pinning there would wedge the broadcast)
     state = state._replace(
@@ -594,21 +622,14 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     cb_rel_safe = jnp.clip(cb_rel, 0, S - 1)
     # no-op commits (ceded slots) broadcast too: harmless duplicate of
     # their SKIP; receivers' status guards make both idempotent.
-    cb_ok = ((cb_rel >= 0) & (cb_rel < S)
-             & (state.status[cb_rel_safe] >= COMMITTED))
+    cb_status, cb_slot, _ = slots_at(cb_rel_safe)
+    cb_ok = (cb_rel >= 0) & (cb_rel < S) & (cb_status >= COMMITTED)
     cb = MsgBatch(
         kind=jnp.where(cb_ok, int(MsgKind.COMMIT), 0).astype(jnp.int32),
         src=jnp.full(K, me, jnp.int32),
-        ballot=state.ballot[cb_rel_safe],
         inst=cb_slots,
         last_committed=jnp.full(K, state.committed_upto, jnp.int32),
-        op=state.op[cb_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[cb_rel_safe],
-        key_lo=state.key_lo[cb_rel_safe],
-        val_hi=state.val_hi[cb_rel_safe],
-        val_lo=state.val_lo[cb_rel_safe],
-        cmd_id=state.cmd_id[cb_rel_safe],
-        client_id=state.client_id[cb_rel_safe],
+        **cb_slot,
     )
     # advance through the committed prefix of my own-slot stride
     resolved = cb_ok
@@ -628,23 +649,17 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     ta_slots = state.tk_anchor + jnp.arange(K2b, dtype=jnp.int32)
     ta_rel = ta_slots - state.window_base
     ta_rel_safe = jnp.clip(ta_rel, 0, S - 1)
+    ta_status, ta_slot, _ = slots_at(ta_rel_safe)
     ta_ok = ((state.tk_anchor >= 0) & (ta_rel >= 0) & (ta_rel < S)
-             & (state.status[ta_rel_safe] >= COMMITTED)
-             & (state.ballot[ta_rel_safe] > 0)
-             & (jnp.mod(state.ballot[ta_rel_safe], 16) == me))
+             & (ta_status >= COMMITTED)
+             & (ta_slot["ballot"] > 0)
+             & (jnp.mod(ta_slot["ballot"], 16) == me))
     ta = MsgBatch(
         kind=jnp.where(ta_ok, int(MsgKind.COMMIT), 0).astype(jnp.int32),
         src=jnp.full(K2b, me, jnp.int32),
-        ballot=state.ballot[ta_rel_safe],
         inst=ta_slots,
         last_committed=jnp.full(K2b, state.committed_upto, jnp.int32),
-        op=state.op[ta_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[ta_rel_safe],
-        key_lo=state.key_lo[ta_rel_safe],
-        val_hi=state.val_hi[ta_rel_safe],
-        val_lo=state.val_lo[ta_rel_safe],
-        cmd_id=state.cmd_id[ta_rel_safe],
-        client_id=state.client_id[ta_rel_safe],
+        **ta_slot,
     )
 
     # 9c. own-slot accept RETRY (mirror of models/minpaxos.py 7d).
@@ -664,24 +679,19 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     rt_slots = state.committed_upto + 1 + jnp.arange(K3, dtype=jnp.int32)
     rt_rel = rt_slots - state.window_base
     rt_rel_safe = jnp.clip(rt_rel, 0, S - 1)
+    rt_status, rt_slot, (rt_driven, rt_votes) = slots_at(
+        rt_rel_safe, driven_by_me, n_votes)
     rt_ok = ((state.stall_ticks >= 4) & (rt_rel >= 0) & (rt_rel < S)
              & (rt_slots < state.crt_inst)
-             & driven_by_me[rt_rel_safe]
-             & (state.status[rt_rel_safe] == ACCEPTED)
-             & (n_votes[rt_rel_safe] < quorum2))
+             & rt_driven
+             & (rt_status == ACCEPTED)
+             & (rt_votes < quorum2))
     rt = MsgBatch(
         kind=jnp.where(rt_ok, int(MsgKind.ACCEPT), 0).astype(jnp.int32),
         src=jnp.full(K3, me, jnp.int32),
-        ballot=state.ballot[rt_rel_safe],
         inst=rt_slots,
         last_committed=jnp.full(K3, state.committed_upto, jnp.int32),
-        op=state.op[rt_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[rt_rel_safe],
-        key_lo=state.key_lo[rt_rel_safe],
-        val_hi=state.val_hi[rt_rel_safe],
-        val_lo=state.val_lo[rt_rel_safe],
-        cmd_id=state.cmd_id[rt_rel_safe],
-        client_id=state.client_id[rt_rel_safe],
+        **rt_slot,
     )
 
     # 9d. frontier catch-up (the minpaxos 7c scheme, which mencius
@@ -706,22 +716,16 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         K4, dtype=jnp.int32)
     cu_rel = cu_slots - state.window_base
     cu_rel_safe = jnp.clip(cu_rel, 0, S - 1)
+    cu_status, cu_slot, _ = slots_at(cu_rel_safe)
     cu_ok = (do_cu & (cu_slots <= state.committed_upto)
              & (cu_rel >= 0) & (cu_rel < S)
-             & (state.status[cu_rel_safe] >= COMMITTED))
+             & (cu_status >= COMMITTED))
     cu = MsgBatch(
         kind=jnp.where(cu_ok, int(MsgKind.COMMIT), 0).astype(jnp.int32),
         src=jnp.full(K4, me, jnp.int32),
-        ballot=state.ballot[cu_rel_safe],
         inst=cu_slots,
         last_committed=jnp.full(K4, state.committed_upto, jnp.int32),
-        op=state.op[cu_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[cu_rel_safe],
-        key_lo=state.key_lo[cu_rel_safe],
-        val_hi=state.val_hi[cu_rel_safe],
-        val_lo=state.val_lo[cu_rel_safe],
-        cmd_id=state.cmd_id[cu_rel_safe],
-        client_id=state.client_id[cu_rel_safe],
+        **cu_slot,
     )
 
     sec("px.takeover")
@@ -864,10 +868,14 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         # of the same key via a segmented running max.
         rows_w = jnp.arange(S, dtype=jnp.int32)
         order = jnp.lexsort((rows_w, st.key_lo, st.key_hi))
-        s_status = st.status[order]
-        s_op = st.op[order]
-        s_key_hi = st.key_hi[order]
-        s_key_lo = st.key_lo[order]
+        # the window in execution order, six columns in one fetch
+        # (read_cols): the order is a permutation of the window, S x S
+        # pairs, which at the pod's and the served window of 4,096 is
+        # exactly ONEHOT_PAIRS and so inside the bound
+        (s_status, s_op, s_key_hi, s_key_lo, s_executed,
+         s_in_prefix) = read_cols(order, (st.status, st.op, st.key_hi,
+                                          st.key_lo, st.executed,
+                                          in_prefix))
         pos = jnp.arange(S, dtype=jnp.int32)
         seg_start = (pos == 0) | (s_key_hi != jnp.roll(s_key_hi, 1)) | (
             s_key_lo != jnp.roll(s_key_lo, 1))
@@ -880,7 +888,7 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         # conservative rule: blocked if any same-key slot with smaller
         # slot number is not yet executed and not in this step's
         # in-order prefix
-        not_done = live & ~st.executed[order] & ~in_prefix[order]
+        not_done = live & ~s_executed & ~s_in_prefix
         poison = jnp.where(not_done | uncommitted_write, pos, -1)
         last_poison = segmented_scan_max(poison, seg_start)
         # slot is clear if no poison strictly before it in its segment
@@ -910,35 +918,35 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
             jnp.where(take, exec_rank, E)].min(idx, mode="drop")
         evalid = slot_of < S
         slot_of_safe = jnp.clip(slot_of, 0, S - 1)
-        op_e = jnp.where(evalid, st.op[slot_of_safe].astype(jnp.int32), 0)
+        # the batch's seven payload columns in one fetch (the apply
+        # writes none of them)
+        (op_s, key_hi_e, key_lo_e, val_hi_e, val_lo_e, cmd_id_e,
+         client_id_e) = read_cols(
+            slot_of_safe, tuple(getattr(st, f) for f in SLOT_FIELDS[1:]))
+        op_e = jnp.where(evalid, op_s.astype(jnp.int32), 0)
         # the vmapped compositions share one trace of the apply
         apply = kv_apply_batch if cfg.gate_exec else kv_apply_batch_shared
         kv, o_hi, o_lo, o_found = apply(
-            st.kv,
-            op_e,
-            st.key_hi[slot_of_safe],
-            st.key_lo[slot_of_safe],
-            st.val_hi[slot_of_safe],
-            st.val_lo[slot_of_safe],
-            evalid,
-        )
+            st.kv, op_e, key_hi_e, key_lo_e, val_hi_e, val_lo_e, evalid)
         newly_exec = jnp.zeros(S, bool).at[
             jnp.where(evalid, slot_of, S)].set(True, mode="drop")
-        return kv, newly_exec, slot_of_safe, evalid, op_e, o_hi, o_lo, o_found
+        return (kv, newly_exec, evalid, op_e, o_hi, o_lo, o_found,
+                jnp.where(evalid, cmd_id_e, 0),
+                jnp.where(evalid, client_id_e, 0))
 
     def _no_exec(st):
         z = jnp.zeros(E, jnp.int32)
-        return (st.kv, jnp.zeros(S, bool), jnp.zeros(E, jnp.int32),
-                jnp.zeros(E, bool), z, z, z, jnp.zeros(E, bool))
+        return (st.kv, jnp.zeros(S, bool), jnp.zeros(E, bool), z, z, z,
+                jnp.zeros(E, bool), z, z)
 
     if cfg.gate_exec:
-        (kv, newly_exec, slot_of_safe, evalid, op_e, o_hi, o_lo,
-         o_found) = jax.lax.cond(
+        (kv, newly_exec, evalid, op_e, o_hi, o_lo, o_found, cmd_id_e,
+         client_id_e) = jax.lax.cond(
             (state.status == COMMITTED).any(), _exec_pipeline, _no_exec,
             state)
     else:  # vmapped composition: cond would run both branches anyway
-        (kv, newly_exec, slot_of_safe, evalid, op_e, o_hi, o_lo,
-         o_found) = _exec_pipeline(state)
+        (kv, newly_exec, evalid, op_e, o_hi, o_lo, o_found, cmd_id_e,
+         client_id_e) = _exec_pipeline(state)
     state = state._replace(
         kv=kv,
         executed=state.executed | newly_exec,
@@ -954,8 +962,8 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         lo=exec_lo, count=evalid.sum(),
         val_hi=o_hi, val_lo=o_lo, found=o_found,
         op=op_e,
-        cmd_id=jnp.where(evalid, state.cmd_id[slot_of_safe], 0),
-        client_id=jnp.where(evalid, state.client_id[slot_of_safe], 0),
+        cmd_id=cmd_id_e,
+        client_id=client_id_e,
     )
 
     sec("px.window_slide")

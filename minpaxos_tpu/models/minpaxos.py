@@ -52,7 +52,7 @@ from minpaxos_tpu.ops.kvstore import (
 )
 from minpaxos_tpu.ops.scan import commit_frontier
 from minpaxos_tpu.ops.sections import Sections
-from minpaxos_tpu.ops.winner import gather_cols
+from minpaxos_tpu.ops.winner import gather_cols, read_cols
 from minpaxos_tpu.wire.messages import MsgKind
 
 # Log-slot statuses (reference minpaxosproto.go:8-15 plus EXECUTED,
@@ -549,22 +549,29 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     state = state._replace(
         pvotes=state.pvotes | scatter_vote_bits(S, rel_i, inbox.src,
                                                 pv_ok, R))
+    # a row's slot is read ONCE per version of STATE, all the columns
+    # a section compares together (ops/winner.py read_cols; until PR 36
+    # an element gather a column: 1c, 2, 2b, 7c, 7e and 8). Before
+    # write A: status and ballot, which 1c and 2 both test
+    status0_i, ballot0_i = read_cols(rel_i_safe,
+                                     (state.status, state.ballot))
     pir_ok = (
         pv_ok
-        & (state.status[rel_i_safe] < COMMITTED)
-        & (inbox.ballot > state.ballot[rel_i_safe])
+        & (status0_i < COMMITTED)
+        & (inbox.ballot > ballot0_i)
     )
     # max-vballot wins per slot within the batch
     vb_max = jnp.full(S + 1, NO_BALLOT, jnp.int32).at[
         jnp.where(pir_ok, rel_i, S)].max(inbox.ballot, mode="drop")
-    pir_win = pir_ok & (inbox.ballot == vb_max[rel_i_safe])
+    vb_max_i, = read_cols(rel_i_safe, (vb_max[:S],))
+    pir_win = pir_ok & (inbox.ballot == vb_max_i)
     # PIR's would-be ballot write as a closed form: a hit slot's new
     # ballot IS vb_max (pir_win requires equality), and pir_ok requires
     # inbox.ballot > state.ballot[rel] >= NO_BALLOT, so vb_max >
     # NO_BALLOT detects hits exactly — no winner scatter needed for
-    # the view the ACCEPT predicates read
-    hit_v = vb_max[:S] > NO_BALLOT
-    ballot1 = jnp.where(hit_v, vb_max[:S], state.ballot)
+    # the view the ACCEPT predicates read, and no further read: a
+    # row's view of its slot follows from the two it already holds
+    ballot1_i = jnp.where(vb_max_i > NO_BALLOT, vb_max_i, ballot0_i)
 
     sec("px.accept")
     # ---- 2. ACCEPT (handleAccept :753-806) ----
@@ -583,15 +590,16 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
         is_accept
         & in_win_i
         & (inbox.ballot >= state.default_ballot)
-        & (inbox.ballot >= ballot1[rel_i_safe])  # post-PIR ballot view
-        & (state.status[rel_i_safe] < COMMITTED)
+        & (inbox.ballot >= ballot1_i)  # post-PIR ballot view
+        & (status0_i < COMMITTED)
     )
     # duplicate rows for one slot (old + new leader in one pooled
     # inbox): only the max-ballot row may write, or per-field scatter
     # could tear the slot (ballot from one row, value from another)
     ab_max = jnp.full(S + 1, NO_BALLOT, jnp.int32).at[
         jnp.where(acc_pre, rel_i, S)].max(inbox.ballot, mode="drop")
-    acc_ok = acc_pre & (inbox.ballot == ab_max[rel_i_safe])
+    ab_max_i, = read_cols(rel_i_safe, (ab_max[:S],))
+    acc_ok = acc_pre & (inbox.ballot == ab_max_i)
 
     sec("px.slot_write_a")
     # ---- fused slot write A (PIR + ACCEPT) ----
@@ -640,16 +648,22 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     # a new leader re-driving slots it learned from a partial quorum
     # needs these votes to reach majority (second half of the
     # elected-laggard livelock fix; value mismatch still NACKs).
+    # after write A: the nine columns this section compares and 2b
+    # echoes, fetched once for both (no slot is written in between)
+    status_i, *slot_i = read_cols(
+        rel_i_safe,
+        (state.status,) + tuple(getattr(state, f) for f in SLOT_FIELDS))
+    slot_i = dict(zip(SLOT_FIELDS, slot_i))
     acc_com_match = (
         is_accept & in_win_i
-        & (state.status[rel_i_safe] >= COMMITTED)
-        & (state.op[rel_i_safe] == inbox.op)
-        & (state.key_hi[rel_i_safe] == inbox.key_hi)
-        & (state.key_lo[rel_i_safe] == inbox.key_lo)
-        & (state.val_hi[rel_i_safe] == inbox.val_hi)
-        & (state.val_lo[rel_i_safe] == inbox.val_lo)
-        & (state.cmd_id[rel_i_safe] == inbox.cmd_id)
-        & (state.client_id[rel_i_safe] == inbox.client_id)
+        & (status_i >= COMMITTED)
+        & (slot_i["op"] == inbox.op)
+        & (slot_i["key_hi"] == inbox.key_hi)
+        & (slot_i["key_lo"] == inbox.key_lo)
+        & (slot_i["val_hi"] == inbox.val_hi)
+        & (slot_i["val_lo"] == inbox.val_lo)
+        & (slot_i["cmd_id"] == inbox.cmd_id)
+        & (slot_i["client_id"] == inbox.client_id)
     )
     # ack every ACCEPT row (ok=0 NACK carries our promised ballot),
     # run-length compressed: one reply row per maximal contiguous
@@ -709,8 +723,7 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     # sweep no-op fill an acked slot). The promise is the global
     # default_ballot, already raised by steps 1-2.
     is_pinst = k == int(MsgKind.PREPARE_INST)
-    rel_pi_safe = rel_i_safe  # shared inst->window translation
-    in_win_pi = in_win_i
+    in_win_pi = in_win_i  # shared inst->window translation
     pi_answer = is_pinst & (inbox.ballot >= state.default_ballot) & (
         in_win_pi | (inbox.inst >= state.crt_inst))
     # Slots we already hold COMMITTED answer with a COMMIT row instead
@@ -720,9 +733,10 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     # elected laggard adopts peer values as ACCEPTED, re-broadcasts
     # ACCEPTs, and the committed peers NACK every one (acc_pre requires
     # status < COMMITTED) — a permanent livelock at frontier -1.
-    pi_com = pi_answer & in_win_pi & (state.status[rel_pi_safe] >= COMMITTED)
-    pi_occ = (pi_answer & ~pi_com & in_win_pi
-              & (state.status[rel_pi_safe] >= ACCEPTED))
+    # the slot's contents: section 2's read after write A (status_i,
+    # slot_i), the same index and the same version of STATE
+    pi_com = pi_answer & in_win_pi & (status_i >= COMMITTED)
+    pi_occ = pi_answer & ~pi_com & in_win_pi & (status_i >= ACCEPTED)
     pi_val = pi_com | pi_occ
     out = out._replace(
         kind=jnp.where(pi_com, int(MsgKind.COMMIT),
@@ -730,20 +744,19 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
                                  int(MsgKind.PREPARE_INST_REPLY), out.kind)),
         src=jnp.where(pi_answer, state.me, out.src),
         inst=jnp.where(pi_answer, inbox.inst, out.inst),
-        ballot=jnp.where(pi_val, state.ballot[rel_pi_safe],
+        ballot=jnp.where(pi_val, slot_i["ballot"],
                          jnp.where(pi_answer, NO_BALLOT, out.ballot)),
         last_committed=jnp.where(pi_com, state.committed_upto,
                                  jnp.where(pi_answer, inbox.ballot,
                                            out.last_committed)),
-        op=jnp.where(pi_val, state.op[rel_pi_safe],
+        op=jnp.where(pi_val, slot_i["op"],
                      jnp.where(pi_answer, 0, out.op)),
-        key_hi=jnp.where(pi_val, state.key_hi[rel_pi_safe], out.key_hi),
-        key_lo=jnp.where(pi_val, state.key_lo[rel_pi_safe], out.key_lo),
-        val_hi=jnp.where(pi_val, state.val_hi[rel_pi_safe], out.val_hi),
-        val_lo=jnp.where(pi_val, state.val_lo[rel_pi_safe], out.val_lo),
-        cmd_id=jnp.where(pi_val, state.cmd_id[rel_pi_safe], out.cmd_id),
-        client_id=jnp.where(pi_val, state.client_id[rel_pi_safe],
-                            out.client_id),
+        key_hi=jnp.where(pi_val, slot_i["key_hi"], out.key_hi),
+        key_lo=jnp.where(pi_val, slot_i["key_lo"], out.key_lo),
+        val_hi=jnp.where(pi_val, slot_i["val_hi"], out.val_hi),
+        val_lo=jnp.where(pi_val, slot_i["val_lo"], out.val_lo),
+        cmd_id=jnp.where(pi_val, slot_i["cmd_id"], out.cmd_id),
+        client_id=jnp.where(pi_val, slot_i["client_id"], out.client_id),
     )
     dst = jnp.where(pi_answer, inbox.src, dst)
     # track the sweep's extent so a later election here starts after it
@@ -1073,19 +1086,18 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     cu_ok = do_cu & (cu_slots <= state.committed_upto) & (cu_rel >= 0) & (
         cu_rel < S)
     cu_rel_safe = jnp.clip(cu_rel, 0, S - 1)
+    # the run's seven payload columns in one fetch (read_cols; the
+    # index is a clipped run of slots, read like any other)
+    cu_op, *cu_payload = read_cols(
+        cu_rel_safe, tuple(getattr(state, f) for f in SLOT_FIELDS[1:]))
     cu = MsgBatch(
         kind=jnp.where(cu_ok, int(MsgKind.ACCEPT), 0).astype(jnp.int32),
         src=jnp.full(K, state.me, jnp.int32),
         ballot=jnp.full(K, state.default_ballot, jnp.int32),
         inst=cu_slots,
         last_committed=jnp.full(K, state.committed_upto, jnp.int32),
-        op=state.op[cu_rel_safe].astype(jnp.int32),
-        key_hi=state.key_hi[cu_rel_safe],
-        key_lo=state.key_lo[cu_rel_safe],
-        val_hi=state.val_hi[cu_rel_safe],
-        val_lo=state.val_lo[cu_rel_safe],
-        cmd_id=state.cmd_id[cu_rel_safe],
-        client_id=state.client_id[cu_rel_safe],
+        op=cu_op.astype(jnp.int32),
+        **dict(zip(SLOT_FIELDS[2:], cu_payload)),
     )
 
     sec("px.retry")
@@ -1233,7 +1245,7 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
         # (slot s's source row is s - pi_rel[0]; no scatter)
         pvotes=state.pvotes | jnp.where(
             (pi_row >= 0) & (pi_row < K2)
-            & pi_ok[jnp.clip(pi_row, 0, K2 - 1)],
+            & read_cols(jnp.clip(pi_row, 0, K2 - 1), (pi_ok,))[0],
             me_bit, jnp.uint16(0)),
         rec_cursor=jnp.where(
             sweep_on, jnp.minimum(cursor + K2, eff_limit), cursor),
@@ -1258,16 +1270,20 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     rel_e = exec_lo - state.window_base + jnp.arange(E, dtype=jnp.int32)
     evalid = jnp.arange(E) < n_exec
     rel_e_safe = jnp.clip(rel_e, 0, S - 1)
-    op_e = jnp.where(evalid, state.op[rel_e_safe].astype(jnp.int32), 0)
+    # the batch's seven payload columns in one fetch, before the table
+    # is applied (the apply writes none of them)
+    (op_s, key_hi_e, key_lo_e, val_hi_e, val_lo_e, cmd_id_e,
+     client_id_e) = read_cols(
+        rel_e_safe, tuple(getattr(state, f) for f in SLOT_FIELDS[1:]))
+    op_e = jnp.where(evalid, op_s.astype(jnp.int32), 0)
 
     # the sort/lookup/insert pipeline is the step's most expensive
     # fixed block; steps with nothing to execute (pure propose/accept
     # traffic — 2 of the ~3 steps on a serial op's path) skip it
     # entirely via cond instead of running it over all-invalid rows
     def _exec_kv(kv, apply=kv_apply_batch):
-        return apply(
-            kv, op_e, state.key_hi[rel_e_safe], state.key_lo[rel_e_safe],
-            state.val_hi[rel_e_safe], state.val_lo[rel_e_safe], evalid)
+        return apply(kv, op_e, key_hi_e, key_lo_e, val_hi_e, val_lo_e,
+                     evalid)
 
     def _no_exec(kv):
         z = jnp.zeros(E, jnp.int32)
@@ -1290,8 +1306,8 @@ def _replica_step_sections(sec, cfg, state, inbox, tick_inc, gates):
     execr = ExecResult(
         lo=exec_lo, count=n_exec, val_hi=o_hi, val_lo=o_lo, found=o_found,
         op=op_e,
-        cmd_id=jnp.where(evalid, state.cmd_id[rel_e_safe], 0),
-        client_id=jnp.where(evalid, state.client_id[rel_e_safe], 0),
+        cmd_id=jnp.where(evalid, cmd_id_e, 0),
+        client_id=jnp.where(evalid, client_id_e, 0),
     )
 
     sec("px.window_slide")

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from minpaxos_tpu.ops.winner import read_cols
+
 
 def _shift1(x: jnp.ndarray, fill) -> jnp.ndarray:
     """x shifted right by one row (previous-row view), fill at row 0."""
@@ -58,7 +60,9 @@ def compress_ack_runs(is_accept: jnp.ndarray, src: jnp.ndarray,
     rid = jnp.cumsum(run_start.astype(jnp.int32)) - 1
     run_len = jnp.zeros(m + 1, jnp.int32).at[
         jnp.where(is_accept, rid, m)].add(1, mode="drop")
-    return run_start, run_len[jnp.clip(rid, 0, m)]
+    # each row's run length: one fetch by its run id (ops/winner.py
+    # read_cols; an element gather of M rows until PR 36)
+    return run_start, read_cols(jnp.clip(rid, 0, m), (run_len,))[0]
 
 
 def range_vote_coverage(valid: jnp.ndarray, src: jnp.ndarray,

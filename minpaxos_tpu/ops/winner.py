@@ -93,9 +93,80 @@ it measured as the slower (33.6 M pairs; 35.4 ms there now, the
 parent's eight gathers) is outside; the default server's 67 M pairs
 are outside too, and its step is the parent's. The bound is the CPU's:
 on the chip the matmul beats a gather a column on either side of it.
+
+``read_cols`` (PR 36) is the same select with slots and rows
+exchanged: where a section reads several WINDOW columns of the state
+at one index vector (``state.status[rel]``, ``state.key_hi[rel]``,
+...), the index is the one-hot's row side and the window the side
+summed over, and ``_fetch`` is already generic in which is which. The
+steps call it once per index and version of the state:
+models/minpaxos.py 1c and 2 (status and ballot before write A, then
+``vb_max`` and ``ab_max`` as each is made; the post-PIR ballot follows
+from the first two without a read), 2 and 2b (after write A, nine
+columns for ``px.accept_ack`` and ``px.prepare_inst`` together), 7c
+(catch-up's run of slots, seven columns), 7e (the sweep's chunk by
+window slot) and 8 (the exec batch, seven); models/mencius.py 2
+(ballot and status, ``ab_max``, then status and the seven payload
+columns), 9 / 9b / 9c / 9d (the slots each announces: status and the
+eight slot fields, 9c's two predicates with them) and 11 (six columns
+by the execution order, a permutation of the window, and the exec
+batch's seven); ops/ackruns.py (a row's run length by its run id, in
+both protocols' ack sections). The window's columns come in
+four widths, so a column is split into as many byte planes as it is
+wide: ``bool`` and ``uint8`` one, ``uint16`` two, ``int32`` four; an
+index may repeat (two ACCEPT rows of one slot, a clip's pile-up on an
+edge), because every OUTPUT row still matches exactly one slot. The
+same bound on the same observable, ``len(idx) * S`` pairs, and for the
+same reason: Mencius's order x window at 4,096 slots is exactly 2**24
+and inside it; the default server's reads by inbox row (4,096 x
+16,384) are outside and stay gathers.
+
+Device time of one read on a v5e, isolated, ms (my chip runs, PR 36;
+``tools/scatter_micro.py stateread``; [G, R] x N index rows into S
+slots x columns, a column's kind its dtype: i int32, b uint8, ? bool):
+
+========================================  ========  =======  =========
+shape                                     elements  stacked  read_cols
+========================================  ========  =======  =========
+pod128 status+ballot [128,5]x640<-1024 bi    10.74     3.40       0.50
+pod128 vb_max [128,5]x640<-1024 i             5.03     5.03       0.37
+pod128 ack+2b small x640<-1024 bibiiiiii     46.70     3.01       1.62
+pod128 ack+2b full x1408<-1024 bibiiiiii    102.73     6.49       1.61
+pod128 catchup x512<-1024 biiiiii (run)      31.23     2.32       1.29
+pod128 exec x128<-1024 biiiiii (run)          7.04     0.66       0.68
+pod128 sweep x1024<-64 ? (run)                7.57     6.69       0.04
+pod128 run_len x640<-641 i                    4.18     4.18       0.23
+mencius64k ballot+status x1216<-4096 ib       2.40     0.81       0.35
+mencius64k dup small x1216<-4096 bbiiiiii     9.56     0.71       0.63
+mencius64k dup full x2112<-4096 bbiiiiii     17.11     1.20       0.95
+mencius64k order x4096<-4096 bbii?? (perm)   25.08     2.25       1.49
+mencius64k exec x320<-4096 biiiiii            2.20     0.22       0.25
+mencius64k 9c x128<-4096 bibiiiiii?i (run)    1.37     0.14       0.26
+served3 ack+2b [3]x1024<-2048 bibiiiiii       0.29     0.05       0.02
+mencius3 order [3]x4096<-4096 bbii??          0.76     0.13       0.06
+========================================  ========  =======  =========
+
+``elements`` is the parent's gather a column (in the round they read
+4.05-5.05 ms apiece at pod128's 409,600 elements); ``stacked`` one
+gather of the columns stacked as ``int32[columns, S]``. Temporaries:
+0.0 MB everywhere but pod128's nine-column read (7.0 MB at 640 rows,
+117.0 MB at 1,408); the round's peak rose by 29.7 MB in the MinPaxos
+pod and 2.0 MB in the Mencius one. The table was read with a byte
+split of one shift a column; ``_fetch`` now splits columns of one
+width together, which XLA:CPU compiles in two thirds of the time and
+the chip runs a little faster still (``px.accept_ack`` 1.70 -> 0.92
+ms and ``px.catchup`` 1.13 -> 0.65 in ``pod128_steady``'s round). A
+clipped run (catch-up's, the exec batch's) is also a slice of the
+edge-padded column: under the [G, R] vmap that ``dynamic_slice`` has a
+start per replica and lowers to a gather, 5.92 ms for catch-up, 5.74
+for the exec batch, 0.85 for the sweep's chunk, 1.17 for Mencius's
+9c: slower than ``read_cols`` at every one of them.
 """
 
 from __future__ import annotations
+
+import functools
+import itertools
 
 import jax.numpy as jnp
 
@@ -129,23 +200,56 @@ def gather_row(win, hit, col, old):
     return _select(hit, col[jnp.clip(win, 0)], old)
 
 
-def _fetch(win, cols):
-    """``int32[columns, S]``: row ``win[s]`` of every ``int32[M]``
-    column, by a one-hot matmul over the columns' bytes."""
+_BYTE_SHIFTS = (0, 8, 16, 24)
+
+
+def _byte_planes(cols, width):
+    """``int32[n * width, M]``: the bytes of ``n`` columns ``width``
+    bytes wide, a column's low byte first."""
+    stacked = jnp.stack([c.astype(jnp.int32) for c in cols])
+    return jnp.stack([(stacked >> sh) & 0xFF
+                      for sh in _BYTE_SHIFTS[:width]],
+                     axis=1).reshape(-1, stacked.shape[-1])
+
+
+def _from_byte_planes(planes, width):
+    """``_byte_planes`` undone: ``int32[n * width, N]`` -> ``[n, N]``."""
+    planes = planes.reshape(-1, width, planes.shape[-1])
+    return functools.reduce(jnp.bitwise_or, [
+        planes[:, j] << _BYTE_SHIFTS[j] for j in range(width)])
+
+
+def _fetch(idx, cols):
+    """``col[idx]`` for every ``[M]`` column of ``cols``, each in its
+    own dtype, by ONE one-hot matmul over the columns' bytes; ``idx``
+    lies in ``[0, M)``."""
     m = cols[0].shape[0]
-    # the four bytes of every column as planes of their own: 0..255 is
-    # exact in bfloat16, sign bits ride in the top byte
-    cols = jnp.stack(cols)  # [columns, M]
-    planes = jnp.stack([(cols >> sh) & 0xFF for sh in (0, 8, 16, 24)],
-                       axis=1).reshape(-1, m).astype(jnp.bfloat16)
-    # exactly one row matches a slot's index, so each sum is one byte
+    # a column's bytes as planes of their own, as many as it is wide
+    # (bool and uint8 one, uint16 two, int32 four): 0..255 is exact in
+    # bfloat16, sign bits ride in the top byte. Columns of one width
+    # are split together (one shift a byte, not one a column: the
+    # lowered step and its compile stay near the parent's)
+    groups = [(w, [i for i, c in enumerate(cols) if c.dtype.itemsize == w])
+              for w in (1, 2, 4)]
+    groups = [(w, which) for w, which in groups if which]
+    planes = jnp.concatenate([
+        _byte_planes([cols[i] for i in which], w) for w, which in groups
+    ]).astype(jnp.bfloat16)  # [planes, M]
+    # exactly one source row matches an index, so each sum is one byte
     # times one: exact in the MXU's float32 accumulator
-    onehot = (win[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :]
+    onehot = (idx[:, None] == jnp.arange(m, dtype=jnp.int32)[None, :]
               ).astype(jnp.bfloat16)
     got = jnp.einsum("pm,sm->ps", planes, onehot,
-                     preferred_element_type=jnp.float32
-                     ).astype(jnp.int32).reshape(-1, 4, win.shape[0])
-    return got[:, 0] | (got[:, 1] << 8) | (got[:, 2] << 16) | (got[:, 3] << 24)
+                     preferred_element_type=jnp.float32).astype(jnp.int32)
+    # each group's planes shifted back together, then every column in
+    # its own place and dtype
+    ends = itertools.accumulate(w * len(which) for w, which in groups)
+    picked = {
+        i: col
+        for (w, which), e in zip(groups, ends)
+        for i, col in zip(which, _from_byte_planes(
+            got[e - w * len(which):e], w))}
+    return tuple(picked[i].astype(c.dtype) for i, c in enumerate(cols))
 
 
 def gather_cols(win, hit, cols, olds):
@@ -156,6 +260,18 @@ def gather_cols(win, hit, cols, olds):
         return tuple(gather_row(win, hit, c, o) for c, o in zip(cols, olds))
     got = _fetch(jnp.clip(win, 0, m - 1), cols)
     return tuple(_select(hit, picked, old) for picked, old in zip(got, olds))
+
+
+def read_cols(idx, cols):
+    """``col[idx]`` for each of the ``[S]`` columns ``cols`` (``int32``,
+    ``uint16``, ``uint8`` or ``bool``; each result in its column's
+    dtype), from ONE fetch by the index vector: the reads of STATE by
+    an inbox row's slot, by a run of slots or by the execution order.
+    ``idx`` is already clipped into ``[0, S)`` and may repeat: every
+    OUTPUT row matches one source slot."""
+    if idx.shape[0] * cols[0].shape[0] > ONEHOT_PAIRS:
+        return tuple(c[idx] for c in cols)
+    return _fetch(idx, cols)
 
 
 def gather_const(hit, value, old):

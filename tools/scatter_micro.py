@@ -24,6 +24,11 @@ M=1408 updates, S=2048 targets):
      parent's element gathers beside each candidate formulation:
      ``python tools/scatter_micro.py slotwrite [part of a label]
      [leg=part of a formulation's name]``
+  i. state reads (PR 36): several window columns, in their own
+     dtypes, by one index vector (an inbox row's slot, a run of
+     slots, the execution order), at the call shapes of the cells:
+     ``python tools/scatter_micro.py stateread [part of a label]
+     [leg=part of a formulation's name]``
 
 Run: python tools/scatter_micro.py (on the machine with the chip; one
 process owns it)
@@ -365,6 +370,102 @@ def slot_write_leg(shapes=SLOTWRITE_SHAPES) -> None:
                   f"temp {temp / 1e6:7.1f} MB  equal {same}", flush=True)
 
 
+# -- state reads (PR 36): "read these window columns at these slots",
+# the pattern of MinPaxos's 1c / 2 / 2b / 7c / 7e / 8 and of Mencius's
+# 2 and 11 (ops/winner.py read_cols). [G, R] x N index rows into S
+# slots; a column's kind is its dtype (i int32, h uint16, b uint8,
+# ? bool); the index is an inbox row's slot (repeats, clipped), a
+# clipped run of slots, or a permutation of the window.
+STATEREAD_SHAPES = [
+    ("pod128 status+ballot small", 128, 5, 640, 1024, "bi", "rows"),
+    ("pod128 vb_max small", 128, 5, 640, 1024, "i", "rows"),
+    ("pod128 ack+prepare_inst small", 128, 5, 640, 1024, "bibiiiiii", "rows"),
+    ("pod128 ack+prepare_inst full", 128, 5, 1408, 1024, "bibiiiiii",
+     "rows"),
+    ("pod128 catchup", 128, 5, 512, 1024, "biiiiii", "run"),
+    ("pod128 exec", 128, 5, 128, 1024, "biiiiii", "run"),
+    ("pod128 sweep", 128, 5, 1024, 64, "?", "run"),
+    ("pod128 run_len", 128, 5, 640, 641, "i", "rows"),
+    ("mencius64k ballot+status small", 16, 5, 1216, 4096, "ib", "rows"),
+    ("mencius64k dup small", 16, 5, 1216, 4096, "bbiiiiii", "rows"),
+    ("mencius64k dup full", 16, 5, 2112, 4096, "bbiiiiii", "rows"),
+    ("mencius64k order", 16, 5, 4096, 4096, "bbii??", "order"),
+    ("mencius64k exec", 16, 5, 320, 4096, "biiiiii", "rows"),
+    ("mencius64k retry rows", 16, 5, 128, 4096, "bibiiiiii?i", "run"),
+    ("served3 ack+prepare_inst", 3, 1, 1024, 2048, "bibiiiiii", "rows"),
+    ("mencius3 order", 3, 1, 4096, 4096, "bbii??", "order"),
+]
+_KIND = {"i": np.int32, "h": np.uint16, "b": np.uint8, "?": np.bool_}
+
+
+def state_read_leg(shapes=STATEREAD_SHAPES) -> None:
+    """Leg i: one read of every column in every formulation at every
+    shape, device-timed; a run is also read as a slice of the
+    edge-padded column (what a clipped run IS, and no gather)."""
+    from minpaxos_tpu.ops import winner as w
+
+    def elements(idx, start, cols):
+        return tuple(c[idx] for c in cols)
+
+    def stacked(idx, start, cols):  # [cols, S] as int32, one gather
+        got = jnp.stack([c.astype(jnp.int32) for c in cols])[:, idx]
+        return tuple(g.astype(c.dtype) for g, c in zip(got, cols))
+
+    def read_cols(idx, start, cols):
+        return w.read_cols(idx, cols)
+
+    def run_slice(idx, start, cols):
+        n, s = idx.shape[0], cols[0].shape[0]
+        at = jnp.clip(start, -n, s) + n
+        return tuple(jax.lax.dynamic_slice(
+            jnp.pad(c, (n, n), mode="edge"), (at,), (n,)) for c in cols)
+
+    legs = {"element gathers (parent)": elements,
+            "stacked [cols, S]": stacked,
+            "read_cols": read_cols,
+            "edge-padded dynamic_slice": run_slice}
+    only = [a[4:] for a in sys.argv[2:] if a.startswith("leg=")]
+    rng = np.random.default_rng(0)
+    for label, g, r, n, s, kinds, index in shapes:
+        # a run begins inside the source or a little before it; the
+        # sweep's chunk (shorter than its index) lies inside the window
+        lo, hi = (-n // 4, s - n // 2) if s > n else (s - n, 1)
+        start = rng.integers(lo, hi, (g, r))
+        if index == "rows":  # an eighth beyond the window, clipped
+            idx = np.clip(rng.integers(0, s + s // 8, (g, r, n)), 0, s - 1)
+        elif index == "run":
+            idx = np.clip(start[..., None] + np.arange(n), 0, s - 1)
+        else:
+            idx = rng.permuted(np.broadcast_to(np.arange(s), (g, r, s)),
+                               axis=-1)
+        cols = tuple(jnp.asarray(
+            rng.random((g, r, s)) < 0.5 if k == "?" else rng.integers(
+                np.iinfo(_KIND[k]).min, np.iinfo(_KIND[k]).max, (g, r, s),
+                dtype=np.int64, endpoint=True).astype(_KIND[k]))
+            for k in kinds)
+        idx = jnp.asarray(idx.astype(np.int32))
+        start = jnp.asarray(start.astype(np.int32))
+        ref = None
+        print(f"i. state read, {label}: [{g}, {r}] x {n} rows into {s} "
+              f"slots x {len(kinds)} columns ({kinds}), by {index}")
+        for name, f in legs.items():
+            if (only and not any(o in name for o in only)) or (
+                    f is run_slice and index != "run"):
+                continue
+            fn = jax.jit(jax.vmap(jax.vmap(f)))
+            temp = fn.lower(idx, start, cols).compile() \
+                .memory_analysis().temp_size_in_bytes
+            got = [np.asarray(x) for x in fn(idx, start, cols)]
+            ref = got if ref is None else ref
+            same = all(a.dtype == b.dtype and (a == b).all()
+                       for a, b in zip(got, ref))
+            ms_dev = _device_ms(fn, idx, start, cols)
+            dev = "not measured" if ms_dev is None else f"{ms_dev:9.3f} ms"
+            print(f"   {name:26s} device {dev}  host "
+                  f"{_time(fn, idx, start, cols, iters=5):9.3f} ms  "
+                  f"temp {temp / 1e6:7.1f} MB  equal {same}", flush=True)
+
+
 def _labelled(shapes):
     return [sh for sh in shapes
             if all(a in sh[0] for a in sys.argv[2:] if "=" not in a)]
@@ -375,7 +476,10 @@ if __name__ == "__main__":
         rank_select_leg(_labelled(RANK_SHAPES))
     elif sys.argv[1:2] == ["slotwrite"]:  # leg h: [label part] [leg=part]
         slot_write_leg(_labelled(SLOTWRITE_SHAPES))
+    elif sys.argv[1:2] == ["stateread"]:  # leg i: [label part] [leg=part]
+        state_read_leg(_labelled(STATEREAD_SHAPES))
     else:
         main()
         rank_select_leg()
         slot_write_leg()
+        state_read_leg()
