@@ -37,6 +37,8 @@ the trace field glossary.
 
 import collections
 
+import numpy as np
+
 from minpaxos_tpu.obs.metrics import (
     Counter,
     Gauge,
@@ -161,13 +163,33 @@ def process_pods() -> list[dict]:
     leader's executed state in a follower its window could no longer
     heal), ``state_transfer_bytes`` (what those copied) and
     ``lagging_rounds`` (rounds at whose end a live replica's frontier
-    trailed its leader's by more than two rounds' proposals). Copies
-    taken now."""
+    trailed its leader's by more than two rounds' proposals). And the
+    resident loop's HOST side since the last ``begin_resident``:
+    ``dispatches`` it made and, for each of the newest 4,096 of them,
+    oldest first, ``dispatch_ns`` (the host interval of span
+    ``paxos.pod.dispatch``: the host-made scalars and the jitted call,
+    until it returns) and ``readback_ns`` (span ``paxos.pod.readback``:
+    the host blocked on the two scalars). Copies taken now."""
     return [dict(p, tiers=p["tiers"] and dict(p["tiers"]),
                  gates=p["gates"] and dict(p["gates"]),
                  round_gates=p["round_gates"]
-                 and dict(p["round_gates"]))
+                 and dict(p["round_gates"]),
+                 dispatch_ns=_ring_oldest_first(p, "dispatch_ns"),
+                 readback_ns=_ring_oldest_first(p, "readback_ns"))
             for p in list(_PROCESS_PODS)]
+
+
+def _ring_oldest_first(pod: dict, key: str):
+    """A copy of what a pod's host ring holds, oldest dispatch first
+    (None for an entry registered without one)."""
+    ring = pod.get(key)
+    if ring is None:
+        return None
+    n = pod["dispatches"]
+    if n <= len(ring):
+        return ring[:n].copy()
+    at = n % len(ring)
+    return np.concatenate([ring[at:], ring[:at]])
 
 
 __all__ = [

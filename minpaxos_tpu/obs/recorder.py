@@ -70,8 +70,18 @@ import numpy as np
 #: are appended after coal_wake: ``wait_us`` (the blocking queue wait,
 #: which earlier rows threw away), ``fsync_us`` / ``fsync_bytes`` (the
 #: ``os.fsync`` inside persist_us and the bytes it made durable) and
-#: ``cpu_us`` (the protocol thread's own CPU time over the row).)
-SCHEMA_VERSION = 8
+#: ``cpu_us`` (the protocol thread's own CPU time over the row). v9:
+#: where inside enqueue and egress the work happens, and every phase's
+#: CPU time beside its wall — appended after cpu_us, so the 22 fields of
+#: v8 keep their places: four sub-phase walls (``assemble_us`` /
+#: ``call_us`` nested in enqueue_us, ``peer_send_us`` / ``flush_us``
+#: nested in dispatch_us), then one ``*_cpu_us`` per phase field: the
+#: protocol thread's ``thread_time_ns`` over exactly the interval the
+#: wall field measures, on the rows whose ``cpu_sampled`` is 1 (the
+#: thread clock is a system call, 6-52 us on the chip's host: the
+#: runtime reads it per phase for one row in ``CPU_SAMPLE_EVERY``).
+#: On those rows the seven tiling phases' CPU adds up to ``cpu_us``.)
+SCHEMA_VERSION = 9
 
 # dispatch regimes (runtime/replica.py classifies one per tick:
 # narrow > fused > full; idle-skip never reaches the device)
@@ -89,20 +99,43 @@ KIND_NAMES = ("full", "fused", "narrow", "idle_skip")
  F_DRAIN_US, F_ENQUEUE_US, F_READBACK_US, F_OVERLAP_US, F_PERSIST_US,
  F_DISPATCH_US, F_REPLY_US, F_T_RB_NS, F_CHAOS, F_COAL_OCC,
  F_COAL_WAKE, F_WAIT_US, F_FSYNC_US, F_FSYNC_BYTES,
- F_CPU_US) = range(22)
-N_FIELDS = 22
+ F_CPU_US,
+ # v9: the sub-phase walls, then a CPU field a phase (wall order)
+ F_ASSEMBLE_US, F_CALL_US, F_PEER_SEND_US, F_FLUSH_US,
+ F_WAIT_CPU_US, F_DRAIN_CPU_US, F_ENQUEUE_CPU_US, F_READBACK_CPU_US,
+ F_PERSIST_CPU_US, F_FSYNC_CPU_US, F_DISPATCH_CPU_US, F_REPLY_CPU_US,
+ F_ASSEMBLE_CPU_US, F_CALL_CPU_US, F_PEER_SEND_CPU_US,
+ F_FLUSH_CPU_US, F_CPU_SAMPLED) = range(39)
+N_FIELDS = 39
 FIELD_NAMES = ("t_ns", "kind", "k", "rows_in", "rows_out", "frontier",
                "exec_backlog", "drain_us", "enqueue_us", "readback_us",
                "overlap_us", "persist_us", "dispatch_us", "reply_us",
                "t_rb_ns", "chaos_faults", "coal_occ", "coal_wake",
-               "wait_us", "fsync_us", "fsync_bytes", "cpu_us")
+               "wait_us", "fsync_us", "fsync_bytes", "cpu_us",
+               "assemble_us", "call_us", "peer_send_us", "flush_us",
+               "wait_cpu_us", "drain_cpu_us", "enqueue_cpu_us",
+               "readback_cpu_us", "persist_cpu_us", "fsync_cpu_us",
+               "dispatch_cpu_us", "reply_cpu_us", "assemble_cpu_us",
+               "call_cpu_us", "peer_send_cpu_us", "flush_cpu_us",
+               "cpu_sampled")
 
 # ---------------------------------------------------------------- phases
 # The tick loop's spans (schema v8): constant names, so a reduction of
-# the xplane finds them after any refactor, each feeding one row field.
-# fsync nests inside persist (persist_us includes it); the others tile
-# the protocol thread's wall. The two pod spans feed no row: the
-# resident loop's host does nothing but dispatch and read two scalars.
+# the xplane finds them after any refactor, each feeding one wall field
+# of the row and (v9) one CPU field. Seven TILE the protocol thread's
+# wall; five nest, each inside the parent its name extends and included
+# in that parent's field: fsync in persist, assemble and call in
+# enqueue (they cover all of it but a line of glue), peers and flush in
+# egress, whose SELF time (dispatch_us - peer_send_us - flush_us) is
+# ``_host_catchup``. The two pod spans feed no row but a ring of the
+# pod's own (parallel/sharded.py run_resident, ``process_pods()``).
+#: the runtime measures the per-phase CPU times of one row in this many
+#: (runtime/replica.py): a read of the thread's CPU clock is a system
+#: call that took 6 us alone and 52 us beside eight busy threads on the
+#: chip's host (0.5 us on the sandbox; PERF.md section 6, PR 37), and a
+#: row needs nineteen
+CPU_SAMPLE_EVERY = 8
+
 PH_WAIT = "paxos.tick.wait"
 PH_DRAIN = "paxos.tick.drain"
 PH_ENQUEUE = "paxos.tick.enqueue"
@@ -111,14 +144,31 @@ PH_PERSIST = "paxos.tick.persist"
 PH_FSYNC = "paxos.tick.fsync"
 PH_EGRESS = "paxos.tick.egress"
 PH_REPLY = "paxos.tick.reply"
+PH_ASSEMBLE = "paxos.tick.enqueue.assemble"
+PH_CALL = "paxos.tick.enqueue.call"
+PH_PEERS = "paxos.tick.egress.peers"
+PH_FLUSH = "paxos.tick.egress.flush"
 PH_POD_DISPATCH = "paxos.pod.dispatch"
 PH_POD_READBACK = "paxos.pod.readback"
 PHASE_FIELDS = {PH_WAIT: F_WAIT_US, PH_DRAIN: F_DRAIN_US,
                 PH_ENQUEUE: F_ENQUEUE_US, PH_READBACK: F_READBACK_US,
                 PH_PERSIST: F_PERSIST_US, PH_FSYNC: F_FSYNC_US,
-                PH_EGRESS: F_DISPATCH_US, PH_REPLY: F_REPLY_US}
+                PH_EGRESS: F_DISPATCH_US, PH_REPLY: F_REPLY_US,
+                PH_ASSEMBLE: F_ASSEMBLE_US, PH_CALL: F_CALL_US,
+                PH_PEERS: F_PEER_SEND_US, PH_FLUSH: F_FLUSH_US}
+#: nested phase -> the phase it runs inside; the others tile the wall
+NESTED_IN = {PH_FSYNC: PH_PERSIST, PH_ASSEMBLE: PH_ENQUEUE,
+             PH_CALL: PH_ENQUEUE, PH_PEERS: PH_EGRESS, PH_FLUSH: PH_EGRESS}
+TILING_PHASES = tuple(p for p in PHASE_FIELDS if p not in NESTED_IN)
+#: phase -> the row field of its CPU time: the wall field's name with
+#: ``_cpu`` before the unit
+PHASE_CPU_FIELDS = {
+    name: FIELD_NAMES.index(FIELD_NAMES[f][:-3] + "_cpu_us")
+    for name, f in PHASE_FIELDS.items()}
 
 _annotation_cls = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+_wall_ns = time.perf_counter_ns  # bound once: a phase reads them 3-4 times
+_cpu_ns = time.thread_time_ns
 
 
 def _annotate(name: str, replica: int | None):
@@ -141,7 +191,11 @@ def _annotate(name: str, replica: int | None):
 
 class PhaseClock:
     """What one tick loop has spent in each phase since the row that
-    phase belongs to was last cut. Single-writer (the protocol thread).
+    phase belongs to was last cut, on two clocks: ``ns`` the wall,
+    ``cpu`` the owning thread's ``thread_time_ns`` over the same
+    interval (a blocked wait, a held GIL, a disk and a device burn
+    none of it, so wall - cpu is what the thread spent OFF the CPU in
+    that phase). Single-writer (the protocol thread).
 
     The outermost phases TILE the thread's wall: one that opens is
     charged from the instant the one before it closed, so the glue
@@ -150,42 +204,78 @@ class PhaseClock:
     that follows, and a wakeup that cuts no row leaves its time for
     the next row. The rows of a recorder therefore add up to the wall
     they span, exactly. A nested phase (fsync inside persist) is
-    charged its own interval only."""
+    charged its own interval only.
 
-    __slots__ = ("replica", "ns", "_cpu0", "_depth", "_last_end")
+    The CPU clock is read only while ``sample`` is set (the owner
+    turns it on for the phases of the rows it samples): the read is a
+    system call, dear on some hosts. A phase that runs with it off
+    adds nothing to ``cpu``."""
+
+    __slots__ = ("replica", "ns", "cpu", "sample", "_row_cpu_us",
+                 "_cpu_cut", "_depth", "_last_end", "_last_cpu")
 
     def __init__(self, replica: int):
         self.replica = replica
         self.ns = dict.fromkeys(PHASE_FIELDS, 0)
-        self._cpu0: int | None = None
+        self.cpu = dict.fromkeys(PHASE_FIELDS, 0)
+        self.sample = True
+        self._row_cpu_us = 0  # tiling phases' CPU taken for the open row
+        self._cpu_cut: int | None = None  # the CPU clock at the last row
         self._depth = 0
         self._last_end = 0  # when the last outermost phase closed
+        # and the thread's CPU clock then (None: it was not read)
+        self._last_cpu: int | None = None
+
+    def adopt(self) -> None:
+        """The calling thread owns the clock from here on: CPU time is
+        counted on ITS clock, from now (a recovery that ran phases on
+        the starting thread leaves their wall for the first row, not
+        another thread's CPU clock)."""
+        self.cpu = dict.fromkeys(PHASE_FIELDS, 0)
+        self._row_cpu_us = 0
+        self._last_cpu = None
+        self._cpu_cut = _cpu_ns()
 
     def take_us(self, name: str) -> int:
         us, self.ns[name] = self.ns[name] // 1000, 0
         return us
 
-    def cpu_us(self) -> int:
-        """The calling thread's CPU time since the last call (0 on the
-        first): a blocked wait burns none, so over a row this is how
-        much of its wall the protocol thread actually ran."""
-        now = time.thread_time_ns()
-        prev, self._cpu0 = self._cpu0, now
+    def take_cpu_us(self, name: str) -> int:
+        us, self.cpu[name] = self.cpu[name] // 1000, 0
+        if name not in NESTED_IN:
+            self._row_cpu_us += us
+        return us
+
+    def cpu_us(self, sampled: bool = False) -> int:
+        """The row's ``cpu_us``, at the moment it is cut: the thread's
+        CPU time since the last row (0 for the first), ONE clock read;
+        for a ``sampled`` row, whose phases ran with ``sample`` set,
+        what ``take_cpu_us`` handed out of its TILING phases, so exactly
+        the sum of the row's seven tiling ``*_cpu_us`` fields (a nested
+        phase's CPU is inside its parent's). A blocked wait burns none,
+        so over a row this is how much of its wall the thread ran."""
+        now = _cpu_ns()
+        prev, self._cpu_cut = self._cpu_cut, now
+        taken, self._row_cpu_us = self._row_cpu_us, 0
+        if sampled:
+            return taken
         return 0 if prev is None else (now - prev) // 1000
 
 
 class phase:
     """``with phase(PH_PERSIST, clock): ...`` — one measurement, two
     readers: the interval is added to ``clock`` (which the next
-    recorder row drains) and, while a JAX profile is being taken,
-    recorded as a TraceAnnotation of that constant name with
+    recorder row drains), the wall always and the thread's CPU time
+    while ``clock.sample`` is set, and, while a JAX profile is being
+    taken, recorded as a TraceAnnotation of that constant name with
     ``replica=<id>``, on the device trace's clock. ``clock=None``
-    annotates only. With no profile running the cost is two clock
-    reads and one ``is_enabled`` call. ``ns`` is the block's own
-    interval; what the clock is charged may start earlier (see
-    ``PhaseClock``)."""
+    annotates only. With no profile running the cost is two wall-clock
+    reads and one ``is_enabled`` call, and under ``sample`` one or two
+    reads of the thread's CPU clock (an outermost phase starts where
+    the last one ended). ``ns`` is the block's own interval; what the
+    clock is charged may start earlier (see ``PhaseClock``)."""
 
-    __slots__ = ("name", "clock", "ns", "_t0", "_from", "_ann")
+    __slots__ = ("name", "clock", "ns", "_t0", "_from", "_cpu_from", "_ann")
 
     def __init__(self, name: str, clock: PhaseClock | None = None):
         self.name = name
@@ -195,11 +285,18 @@ class phase:
     def __enter__(self):
         # the annotation opens and closes INSIDE the measured interval:
         # what a profile costs the loop shows in the rows, not between
-        self._t0 = self._from = time.perf_counter_ns()
+        self._t0 = self._from = _wall_ns()
         clock = self.clock
         if clock is not None:
-            if clock._depth == 0 and clock._last_end:
+            tiles = clock._depth == 0 and clock._last_end
+            if tiles:
                 self._from = clock._last_end
+            if not clock.sample:
+                self._cpu_from = None
+            elif tiles and clock._last_cpu is not None:
+                self._cpu_from = clock._last_cpu
+            else:
+                self._cpu_from = _cpu_ns()
             clock._depth += 1
         self._ann = _annotate(
             self.name, None if clock is None else clock.replica)
@@ -208,14 +305,19 @@ class phase:
     def __exit__(self, *exc) -> None:
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        end = time.perf_counter_ns()
+        end = _wall_ns()
         self.ns = end - self._t0
         clock = self.clock
         if clock is not None:
+            cpu = None
+            if self._cpu_from is not None:
+                cpu = _cpu_ns()
+                clock.cpu[self.name] += cpu - self._cpu_from
             clock.ns[self.name] += end - self._from
             clock._depth -= 1
             if clock._depth == 0:
                 clock._last_end = end
+                clock._last_cpu = cpu
 
 # dispatch-side phases, laid end-to-end ENDING at t_rb_ns (tid 0),
 # and host-side phases ending at t_ns (tid 1 — their own track, so a
@@ -227,6 +329,17 @@ _DISPATCH_PHASES = (("drain", F_DRAIN_US), ("enqueue", F_ENQUEUE_US),
                     ("readback", F_READBACK_US))
 _HOST_PHASES = (("persist", F_PERSIST_US), ("dispatch", F_DISPATCH_US),
                 ("reply", F_REPLY_US))
+# nested phases, drawn inside their parent's slice: (name, field, at
+# the parent's END?). assemble opens enqueue and the call closes it;
+# the peer frames open egress and the flushes close it (the row holds
+# egress's two halves as one sum, so what lies between peers and flush
+# in the drawing is _host_catchup, its self time); fsync closes persist
+_NESTED_PHASES = {
+    F_ENQUEUE_US: (("assemble", F_ASSEMBLE_US, False),
+                   ("call", F_CALL_US, True)),
+    F_PERSIST_US: (("fsync", F_FSYNC_US, True),),
+    F_DISPATCH_US: (("peers", F_PEER_SEND_US, False),
+                    ("flush", F_FLUSH_US, True))}
 
 _EVENT_PHASES = frozenset("XBEiICMsnbe")  # trace-event ph codes we accept
 
@@ -372,7 +485,15 @@ class FlightRecorder:
                t_rb_ns: int = 0, chaos_faults: int = 0,
                coal_occ: int = 0, coal_wake: int = 0, wait_us: int = 0,
                fsync_us: int = 0, fsync_bytes: int = 0,
-               cpu_us: int = 0) -> None:
+               cpu_us: int = 0, assemble_us: int = 0, call_us: int = 0,
+               peer_send_us: int = 0, flush_us: int = 0,
+               wait_cpu_us: int = 0, drain_cpu_us: int = 0,
+               enqueue_cpu_us: int = 0, readback_cpu_us: int = 0,
+               persist_cpu_us: int = 0, fsync_cpu_us: int = 0,
+               dispatch_cpu_us: int = 0, reply_cpu_us: int = 0,
+               assemble_cpu_us: int = 0, call_cpu_us: int = 0,
+               peer_send_cpu_us: int = 0, flush_cpu_us: int = 0,
+               cpu_sampled: int = 0) -> None:
         """``t_ns``: when the tick's host phases completed. ``t_rb_ns``:
         when its readback completed (0 = unknown; to_events then lays
         the dispatch phases contiguously before the host phases, which
@@ -386,14 +507,25 @@ class FlightRecorder:
         unchanged). Schema v8: ``wait_us`` the blocking queue wait since
         the last row, ``fsync_us`` / ``fsync_bytes`` the ``os.fsync``
         inside ``persist_us`` and the bytes it made durable, ``cpu_us``
-        the protocol thread's CPU time since the last row."""
+        the protocol thread's CPU time since the last row. Schema v9:
+        ``assemble_us`` / ``call_us`` the two halves of ``enqueue_us``
+        (inbox assembly and the fuse / narrow choice; the jitted call),
+        ``peer_send_us`` / ``flush_us`` the peer frames and the socket
+        flushes inside ``dispatch_us`` (what is left of it is
+        ``_host_catchup``), and per wall field ``x_us`` the thread's
+        CPU time over the same interval, ``x_cpu_us``, measured where
+        ``cpu_sampled`` is 1 (else 0)."""
         with self._lock:
             self._buf[self.total % self.capacity] = (
                 t_ns, kind, k, rows_in, rows_out, frontier, backlog,
                 drain_us, enqueue_us, readback_us, overlap_us,
                 persist_us, dispatch_us, reply_us, t_rb_ns, chaos_faults,
                 coal_occ, coal_wake, wait_us, fsync_us, fsync_bytes,
-                cpu_us)
+                cpu_us, assemble_us, call_us, peer_send_us, flush_us,
+                wait_cpu_us, drain_cpu_us, enqueue_cpu_us,
+                readback_cpu_us, persist_cpu_us, fsync_cpu_us,
+                dispatch_cpu_us, reply_cpu_us, assemble_cpu_us,
+                call_cpu_us, peer_send_cpu_us, flush_cpu_us, cpu_sampled)
             self.total += 1
 
     def snapshot(self, last: int | None = None) -> np.ndarray:
@@ -454,28 +586,24 @@ class FlightRecorder:
                                "dur": int(r[F_WAIT_US]),
                                "pid": pid, "tid": 0})
             if int(r[F_KIND]) != KIND_IDLE_SKIP:
-                t = t0
-                for name, i in _DISPATCH_PHASES:
-                    d = int(r[i])
-                    if d > 0:
-                        events.append({"name": name, "cat": "phase",
-                                       "ph": "X", "ts": t, "dur": d,
-                                       "pid": pid, "tid": 0})
-                    t += d
-                t = t_end - host_dur
-                for name, i in _HOST_PHASES:
-                    d = int(r[i])
-                    if d > 0:
-                        events.append({"name": name, "cat": "phase",
-                                       "ph": "X", "ts": t, "dur": d,
-                                       "pid": pid, "tid": 1})
-                    t += d
-                    if i == F_PERSIST_US and r[F_FSYNC_US] > 0:
-                        # the fsync closes persist: drawn as its child
-                        fs = min(int(r[F_FSYNC_US]), d)
-                        events.append({"name": "fsync", "cat": "phase",
-                                       "ph": "X", "ts": t - fs, "dur": fs,
-                                       "pid": pid, "tid": 1})
+                for phases, t, tid in (
+                        (_DISPATCH_PHASES, t0, 0),
+                        (_HOST_PHASES, t_end - host_dur, 1)):
+                    for name, i in phases:
+                        d = int(r[i])
+                        if d > 0:
+                            events.append({"name": name, "cat": "phase",
+                                           "ph": "X", "ts": t, "dur": d,
+                                           "pid": pid, "tid": tid})
+                        for child, j, at_end in _NESTED_PHASES.get(i, ()):
+                            c = min(int(r[j]), d)  # never past its parent
+                            if c > 0:
+                                events.append({
+                                    "name": child, "cat": "phase",
+                                    "ph": "X", "dur": c, "pid": pid,
+                                    "tid": tid,
+                                    "ts": t + d - c if at_end else t})
+                        t += d
             events.append({"name": "frontier", "ph": "C", "ts": t_end,
                            "pid": pid, "tid": 0,
                            "args": {"frontier": int(r[F_FRONTIER])}})

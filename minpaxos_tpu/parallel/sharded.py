@@ -65,6 +65,10 @@ from minpaxos_tpu.wire.messages import Op
 #: (with a drained run and sane shapes it is 0).
 LATENCY_BINS = 512
 
+#: dispatches whose host intervals the resident loop keeps (a 30 s
+#: window makes some hundreds; beyond this the ring overwrites)
+POD_HOST_RING = 4096
+
 #: which jitted entry points of the fused dispatch path donate their
 #: round-state argument (in-place buffer reuse instead of a fresh
 #: allocation per dispatch). Asserted against reality by
@@ -961,7 +965,13 @@ class ShardedCluster:
             self._counts = self._replicated(jnp.zeros(N_COUNTS, jnp.int32))
         # what the resident loop leaves for a reader after the run
         # (obs.process_pods()): filled on the post-window path only
+        # the resident loop's host side, a dispatch a column: what the
+        # two pod spans measured (obs.process_pods(): dispatch_ns,
+        # readback_ns); restarts with every begin_resident
+        self._host_ns = np.zeros((2, POD_HOST_RING), np.int64)
         self._pod = register_pod({
+            "dispatches": 0, "dispatch_ns": self._host_ns[0],
+            "readback_ns": self._host_ns[1],
             "protocol": protocol, "n_shards": n_shards,
             "n_replicas": cfg.n_replicas, "inbox": cfg.inbox,
             "working_capacity": small_tier_rows(
@@ -1075,6 +1085,7 @@ class ShardedCluster:
         # time, so re-arming (bench: warmup, then measured phase)
         # restarts the ring at row 0
         self._tel_base = int(self._seed)
+        self._pod["dispatches"] = 0  # the host ring restarts too
         if self.mesh is not None:
             # ring rides the shard axis like the state; the histogram,
             # the telemetry rows and the tier and gate counts are
@@ -1106,11 +1117,14 @@ class ShardedCluster:
         ``end_resident``. For a multi-owner pod ``n_proposals`` is per
         OWNER (one number for all, or a sequence of R) and the scalars
         count commands (``sharded_run_resident``)."""
-        if self._counts is None:
-            n_prop = jnp.int32(min(n_proposals, self.ext_rows))
-        else:
-            n_prop = self._owner_counts(n_proposals)
-        with phase(PH_POD_DISPATCH):
+        # the span holds all the device waits for between two
+        # dispatches on this side of the call: the host-made scalars
+        # (each a small transfer) and the call itself
+        with phase(PH_POD_DISPATCH) as sent:
+            if self._counts is None:
+                n_prop = jnp.int32(min(n_proposals, self.ext_rows))
+            else:
+                n_prop = self._owner_counts(n_proposals)
             (self.ss, self._inject_round, self._lat_hist, self._telemetry,
              self._tiers, committed, in_flight, self._counts,
              self._gate_opens, self._recovery) = sharded_run_resident(
@@ -1125,9 +1139,15 @@ class ShardedCluster:
         # the per-dispatch scalar readback — the ONLY host sync in the
         # measured steady state (paxlint's resident-loop rule keeps it
         # that way; this suppression marks the sanctioned boundary)
-        with phase(PH_POD_READBACK):
+        with phase(PH_POD_READBACK) as read:
             # paxlint: disable=resident-loop -- sanctioned scalar readback
-            return int(committed), int(in_flight)
+            out = int(committed), int(in_flight)
+        pod = self._pod
+        at = pod["dispatches"] % POD_HOST_RING
+        self._host_ns[0, at] = sent.ns
+        self._host_ns[1, at] = read.ns
+        pod["dispatches"] += 1
+        return out
 
     def resident_hist(self) -> np.ndarray:
         """Snapshot the device histogram WITHOUT disarming — the

@@ -112,8 +112,6 @@ class IngressCoalescer:
         self._h_batch = self.metrics.histogram(
             "coalesce_batch_rows", "client rows coalesced per blocking "
             "drain", bounds=COALESCE_ROW_BUCKETS)
-        self.metrics.fn_gauge("coalesce_pending_rows",
-                              lambda: self._pending_rows)
 
     @staticmethod
     def _client_rows(item) -> int:

@@ -52,10 +52,15 @@ from minpaxos_tpu.obs.recorder import (
     KIND_FUSED,
     KIND_IDLE_SKIP,
     KIND_NARROW,
+    CPU_SAMPLE_EVERY,
+    PH_ASSEMBLE,
+    PH_CALL,
     PH_DRAIN,
     PH_EGRESS,
     PH_ENQUEUE,
+    PH_FLUSH,
     PH_FSYNC,
+    PH_PEERS,
     PH_PERSIST,
     PH_READBACK,
     PH_REPLY,
@@ -205,9 +210,20 @@ class _InflightTick:
     drain_us: int
     enqueue_us: int
     readback_us: int
+    wait_cpu_us: int      # the protocol thread's CPU time in each,
+    drain_cpu_us: int     # measured if the row is ``sampled``
+    sampled: bool
     t_rb_ns: int          # monotonic_ns at readback (trace anchoring)
     coal_occ: int = 0     # rows the ingress coalescer batched for this tick
     coal_wake: int = 0    # cumulative coalescer wakeup kicks at this tick
+    # enqueue's two halves, and the CPU time of the dispatch phases:
+    # the caller's, once the readback has ended
+    assemble_us: int = 0
+    call_us: int = 0
+    enqueue_cpu_us: int = 0
+    readback_cpu_us: int = 0
+    assemble_cpu_us: int = 0
+    call_cpu_us: int = 0
 
 
 class FatalReplicaError(RuntimeError):
@@ -410,9 +426,6 @@ class ReplicaServer:
         self._c_idle_skips = m.counter(
             "idle_skips", "timer wakeups the idle fast path answered "
             "without touching the device")
-        self._c_pipelined = m.counter(
-            "pipelined_ticks", "dispatches whose host phases ran "
-            "deferred, under the NEXT dispatch's device compute")
         self._c_narrow_fallbacks = m.counter(
             "narrow_fallbacks", "narrow dispatches whose post-readback "
             "anchor validation failed; the next dispatch recounts "
@@ -451,16 +464,26 @@ class ReplicaServer:
             "tick_wall_ms", "whole-dispatch host wall (drain work + "
             "enqueue + readback + persist + dispatch + reply, wherever "
             "the host phases ran)", TICK_MS_BUCKETS)
-        self._h_step = m.histogram(
-            "device_step_ms", "host-visible dispatch wall (enqueue + "
-            "readback; device compute hidden under the previous tick's "
-            "host phases does not appear here)", TICK_MS_BUCKETS)
+        # who ran instead: the protocol thread's own CPU time, row by
+        # row, beside its reader threads' (transport.py ingress_cpu_us)
+        # and how many threads share the one GIL
+        self._c_proto_cpu = m.counter(
+            "proto_cpu_us", "the protocol thread's CPU time "
+            "(thread_time_ns), the sum of every recorder row's cpu_us")
+        self._g_threads = m.gauge(
+            "threads_alive", "threading.active_count() at the newest "
+            "recorder row: the threads that share this process's GIL")
         self.recorder = (FlightRecorder(self.flags.recorder_ring)
                          if self.flags.recorder else None)
         # the tick loop's phase clock (obs/recorder.py phase): every
         # interval the loop measures goes through it, into the row and,
         # under a profile, into the xplane as a paxos.tick.* span
         self._clock = PhaseClock(me)
+        # the per-phase CPU times are measured for one row in
+        # CPU_SAMPLE_EVERY: the clock's ``sample`` is set for the phases
+        # of that row, dispatch side and host side (_sample_next_row)
+        self._clock.sample = False
+        self._rows_cut = 0
         # paxtrace sink: one per replica, shared with the transport's
         # reader threads (each thread gets its own ring inside). The
         # sink exists even when disabled so every touch point stays
@@ -554,8 +577,6 @@ class ReplicaServer:
         # is (paxtop's SNAP column reads these; -1 = never snapshotted)
         m.fn_gauge("store_log_bytes", self.store.log_bytes)
         m.fn_gauge("snap_count", lambda: self.store.snapshots_taken)
-        m.fn_gauge("store_truncated_bytes",
-                   lambda: self.store.truncated_bytes)
         m.fn_gauge("snap_age_s", self._snap_age_s)
         # snapshot policy state (protocol thread only): next log size
         # that triggers the size policy, last snapshot wall time, and
@@ -1088,6 +1109,7 @@ class ReplicaServer:
         if prof is not None:
             prof.enable()
         try:
+            self._clock.adopt()  # CPU time is this thread's from here
             # this thread stamps four of a command's stages: its ring
             # is sized to hold a whole benchmark window of them
             self.trace_sink.ring(protocol_ring_capacity(
@@ -1247,8 +1269,13 @@ class ReplicaServer:
             self._flush_inflight()  # see the idle-throttle note above
             self._c_idle_skips.inc()
             self._c_ticks.inc(tick_inc)
+            clock = self._clock
+            wait_cpu_us = clock.take_cpu_us(PH_WAIT)
+            drain_cpu_us = clock.take_cpu_us(PH_DRAIN)
+            sampled = clock.sample
+            cpu_us = clock.cpu_us(sampled)
+            self._c_proto_cpu.inc(cpu_us)
             if self.recorder is not None:
-                clock = self._clock
                 self.recorder.record(
                     monotonic_ns(), KIND_IDLE_SKIP, 0, 0, 0,
                     self.snapshot["frontier"], 0,
@@ -1257,7 +1284,9 @@ class ReplicaServer:
                     coal_wake=(self.coalescer._c_wakeups.value
                                if self.coalescer is not None else 0),
                     wait_us=clock.take_us(PH_WAIT),
-                    cpu_us=clock.cpu_us())
+                    cpu_us=cpu_us, wait_cpu_us=wait_cpu_us,
+                    drain_cpu_us=drain_cpu_us, cpu_sampled=sampled)
+            self._sample_next_row()
             # skipping IS being idle: without this the next poll waits
             # only tick_s (2 ms) and a quiet replica spins the skip
             # check at 500 Hz instead of idle_s pacing
@@ -1374,6 +1403,13 @@ class ReplicaServer:
         self._snap_last_s = time.monotonic()
         dlog(f"replica {self.me}: snapshot@{exec_upto} "
              f"({len(keys)} pairs, freed {freed} B, log {lb} B)")
+
+    def _sample_next_row(self) -> None:
+        """A row's dispatch-side fields were just cut: whatever the
+        loop measures from here on belongs to the next row, whose
+        per-phase CPU times are read or not as a whole."""
+        self._rows_cut += 1
+        self._clock.sample = self._rows_cut % CPU_SAMPLE_EVERY == 0
 
     def _drain(self, timeout_s: float) -> bool:
         """Pull queued frames into the inbox buffer; returns whether a
@@ -1793,18 +1829,23 @@ class ReplicaServer:
             dlog(f"replica {self.me}: tick start fill={buf.fill}")
         clock = self._clock
         with phase(PH_ENQUEUE, clock):
-            cols, n_rows = buf.drain()
-            self._skip_span.clear()  # the next batch starts empty
-            inbox = MsgBatch(**{c: np.asarray(cols[c])
-                                for c in batches.COLS})
-            k = self._choose_fuse(n_rows)
-            narrow, off = self._choose_narrow(cols, n_rows)
+            # host work that grows with the rows of the batch
+            with phase(PH_ASSEMBLE, clock):
+                cols, n_rows = buf.drain()
+                self._skip_span.clear()  # the next batch starts empty
+                inbox = MsgBatch(**{c: np.asarray(cols[c])
+                                    for c in batches.COLS})
+                k = self._choose_fuse(n_rows)
+                narrow, off = self._choose_narrow(cols, n_rows)
             view_lo = self.snapshot.get("window_base", 0) + off
             # enqueue: on an async backend the call returns with the
             # outputs still in flight; everything until the np.asarray
-            # below overlaps device compute
-            self.state, out_mats_d, exec_mats_d, scals_d = self.step(
-                self.state, inbox, k, narrow, off)
+            # below overlaps device compute. The span is the call
+            # alone: the columns' transfers and the jit dispatch,
+            # whose cost the inbox's padded shape sets, not its rows
+            with phase(PH_CALL, clock):
+                self.state, out_mats_d, exec_mats_d, scals_d = self.step(
+                    self.state, inbox, k, narrow, off)
         # the previous tick's host phases, hidden under this compute
         self._flush_inflight(overlapped=True)
         with phase(PH_READBACK, clock):
@@ -1813,6 +1854,13 @@ class ReplicaServer:
                 (out_mats_d, exec_mats_d, scals_d), persist, dispatch)
         rec.enqueue_us = clock.take_us(PH_ENQUEUE)
         rec.readback_us = clock.take_us(PH_READBACK)
+        rec.assemble_us = clock.take_us(PH_ASSEMBLE)
+        rec.call_us = clock.take_us(PH_CALL)
+        rec.enqueue_cpu_us = clock.take_cpu_us(PH_ENQUEUE)
+        rec.readback_cpu_us = clock.take_cpu_us(PH_READBACK)
+        rec.assemble_cpu_us = clock.take_cpu_us(PH_ASSEMBLE)
+        rec.call_cpu_us = clock.take_cpu_us(PH_CALL)
+        self._sample_next_row()
         # defer only when the next dispatch is imminent (traffic
         # already queued): its enqueue is what the host phases hide
         # under. With an empty queue the next wakeup may be a full
@@ -1964,6 +2012,9 @@ class ReplicaServer:
             wait_us=self._clock.take_us(PH_WAIT),
             drain_us=self._clock.take_us(PH_DRAIN),
             enqueue_us=0, readback_us=0,  # the caller's, once this ends
+            wait_cpu_us=self._clock.take_cpu_us(PH_WAIT),
+            drain_cpu_us=self._clock.take_cpu_us(PH_DRAIN),
+            sampled=self._clock.sample,
             t_rb_ns=t_rb_ns, coal_occ=coal_occ, coal_wake=coal_wake)
         if self.trace_sink.enabled:
             self._trace_commits(rec)
@@ -1987,6 +2038,9 @@ class ReplicaServer:
         happens before any buffered reply frame reaches a socket
         (flush_all is last)."""
         clock = self._clock
+        # this row's host phases are measured as its dispatch phases
+        # were, whatever the row being gathered meanwhile is
+        clock.sample, resume = rec.sampled, clock.sample
         cols, n_rows, k = rec.cols, rec.n_rows, rec.k
         out_mats, exec_mats, scals = rec.out_mats, rec.exec_mats, rec.scals
         ncols = len(batches.COLS)
@@ -2025,15 +2079,18 @@ class ReplicaServer:
                     # them only when there are live rows to scatter —
                     # backlog-drain ticks execute commands without
                     # emitting any
-                    flat = {c: out_mats[:, j, :].reshape(-1)
-                            for j, c in enumerate(batches.COLS)}
-                    self._dispatch(flat,
-                                   out_mats[:, ncols, :].reshape(-1))
+                    with phase(PH_PEERS, clock):
+                        flat = {c: out_mats[:, j, :].reshape(-1)
+                                for j, c in enumerate(batches.COLS)}
+                        self._dispatch(flat,
+                                       out_mats[:, ncols, :].reshape(-1))
             with phase(PH_REPLY, clock):
                 self._reply_stacked(exec_mats, scals, k, rec.frontier)
             with phase(PH_EGRESS, clock):
+                # egress's SELF time (it less peers and flush)
                 self._host_catchup(rec.peer_commits, rec.snap)
-                self.transport.flush_all()
+                with phase(PH_FLUSH, clock):
+                    self.transport.flush_all()
         # flight-recorder row + latency histograms: the per-phase wall
         # decomposition for THIS dispatch, wall-honest under fusion
         # (one row per dispatch, carrying k — a fused burst is one
@@ -2044,15 +2101,21 @@ class ReplicaServer:
         egress_us = clock.take_us(PH_EGRESS)
         reply_us = clock.take_us(PH_REPLY)
         host_us = persist_us + egress_us + reply_us
-        if overlapped:
-            self._c_pipelined.inc()
-        step_us = rec.enqueue_us + rec.readback_us
-        self._h_tick.observe((rec.drain_us + step_us + host_us) / 1e3)
-        self._h_step.observe(step_us / 1e3)
+        self._h_tick.observe((rec.drain_us + rec.enqueue_us
+                              + rec.readback_us + host_us) / 1e3)
+        persist_cpu_us = clock.take_cpu_us(PH_PERSIST)
+        egress_cpu_us = clock.take_cpu_us(PH_EGRESS)
+        reply_cpu_us = clock.take_cpu_us(PH_REPLY)
+        # the seven tiling takes of THIS row are done (the dispatch
+        # phases' at its readback): a sampled row's cpu_us is their sum
+        cpu_us = clock.cpu_us(rec.sampled)
+        self._c_proto_cpu.inc(cpu_us)
+        clock.sample = resume
         if self.recorder is not None:
             flushed = self.store.flushed_bytes
             fsync_bytes, self._flushed_seen = (
                 flushed - self._flushed_seen, flushed)
+            self._g_threads.set(threading.active_count())
             self.recorder.record(
                 monotonic_ns(), rec.kind, k, n_rows, rec.rows_out,
                 rec.frontier, rec.backlog, rec.drain_us, rec.enqueue_us,
@@ -2061,7 +2124,22 @@ class ReplicaServer:
                 chaos_faults=self.transport.chaos_faults_total(),
                 coal_occ=rec.coal_occ, coal_wake=rec.coal_wake,
                 wait_us=rec.wait_us, fsync_us=clock.take_us(PH_FSYNC),
-                fsync_bytes=fsync_bytes, cpu_us=clock.cpu_us())
+                fsync_bytes=fsync_bytes, cpu_us=cpu_us,
+                assemble_us=rec.assemble_us, call_us=rec.call_us,
+                peer_send_us=clock.take_us(PH_PEERS),
+                flush_us=clock.take_us(PH_FLUSH),
+                wait_cpu_us=rec.wait_cpu_us,
+                drain_cpu_us=rec.drain_cpu_us,
+                enqueue_cpu_us=rec.enqueue_cpu_us,
+                readback_cpu_us=rec.readback_cpu_us,
+                persist_cpu_us=persist_cpu_us,
+                fsync_cpu_us=clock.take_cpu_us(PH_FSYNC),
+                dispatch_cpu_us=egress_cpu_us, reply_cpu_us=reply_cpu_us,
+                assemble_cpu_us=rec.assemble_cpu_us,
+                call_cpu_us=rec.call_cpu_us,
+                peer_send_cpu_us=clock.take_cpu_us(PH_PEERS),
+                flush_cpu_us=clock.take_cpu_us(PH_FLUSH),
+                cpu_sampled=rec.sampled)
 
     # -- paxtrace: slot assignment + commit stamps (protocol thread) --
 

@@ -103,10 +103,8 @@ class Transport:
         if metrics is not None:
             # wire visibility in the owner's registry: evaluated at
             # snapshot time (obs/metrics.py fn_gauge), so the per-frame
-            # hot path stays a plain attribute add on the _Conn
-            metrics.fn_gauge("peer_conns_alive", self._peers_alive)
-            metrics.fn_gauge("client_conns", lambda: len(self.clients))
-            # ingress depth: works for a plain Queue and for the
+            # hot path stays a plain attribute add on the _Conn.
+            # Ingress depth: works for a plain Queue and for the
             # IngressCoalescer (both expose qsize); sampled at snapshot
             metrics.fn_gauge("ingress_queue_depth", self.queue.qsize)
             for attr in ("frames_in", "rows_in", "bytes_in", "frames_out"):
@@ -119,6 +117,17 @@ class Transport:
                 metrics.fn_gauge(f"dials_{k}",
                                  lambda k=k: self._dial_tallies[k])
             metrics.fn_gauge("chaos_injected", self.chaos_faults_total)
+        # who runs while the protocol thread waits for the GIL: the
+        # reader threads' own CPU time (_read_loop). A plain counter
+        # and not a gauge over live connections: it is read after the
+        # server has stopped. Many writers, so added under a lock, but
+        # only every INGRESS_CPU_EVERY chunks and when a reader ends:
+        # a thread-clock read and a lock a chunk would cost each of
+        # some 3,000 chunks a second a microsecond for nothing
+        self._c_ingress_cpu = (None if metrics is None else metrics.counter(
+            "ingress_cpu_us", "CPU time of the connection reader "
+            "threads (thread_time_ns): recv, frame decode, queue put"))
+        self._cpu_lock = threading.Lock()
         # Client connection ids are globally unique across replicas
         # (replica id in the high bits): command provenance travels
         # through the log as (client_id, cmd_id), and a follower
@@ -129,14 +138,6 @@ class Transport:
         self._listener: socket.socket | None = None
         self._stop = threading.Event()
         self._last_dial: dict[int, float] = {}
-
-    def _conns(self) -> list:
-        with self._lock:
-            return list(self.peers.values()) + list(self.clients.values())
-
-    def _peers_alive(self) -> int:
-        with self._lock:
-            return sum(c.alive for c in self.peers.values())
 
     def _net_total(self, attr: str) -> int:
         with self._lock:
@@ -351,9 +352,24 @@ class Transport:
         threading.Thread(target=self._read_loop,
                          args=(FROM_PEER, q, conn), daemon=True).start()
 
+    #: a reader thread adds its CPU time to ``ingress_cpu_us`` once in
+    #: this many chunks (and when it ends)
+    INGRESS_CPU_EVERY = 32
+
+    def _add_ingress_cpu(self, since_ns: int) -> int:
+        """Add the calling reader thread's CPU time since ``since_ns``
+        (its own ``thread_time_ns``) to ``ingress_cpu_us``; returns
+        the instant counted up to."""
+        us = (time.thread_time_ns() - since_ns) // 1000
+        if us and self._c_ingress_cpu is not None:
+            with self._cpu_lock:
+                self._c_ingress_cpu.inc(us)
+        return since_ns + us * 1000
+
     def _read_loop(self, src_kind: int, conn_id: int, conn: _Conn) -> None:
         dec = StreamDecoder()
         sock = conn.sock
+        cpu_ns, chunks = time.thread_time_ns(), 0
         while not self._stop.is_set():
             try:
                 chunk = sock.recv(1 << 16)
@@ -397,6 +413,10 @@ class Transport:
                     self.queue.put((src_kind, conn_id, kind, rows))
             if dec.error is not None:
                 break
+            chunks += 1
+            if chunks % self.INGRESS_CPU_EVERY == 0:
+                cpu_ns = self._add_ingress_cpu(cpu_ns)
+        self._add_ingress_cpu(cpu_ns)
         conn.alive = False
         j = self.journal
         if (j is not None and src_kind == FROM_PEER

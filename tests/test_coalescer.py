@@ -203,7 +203,7 @@ def test_paxmon_metrics_registered():
     counters = dict(snap.get("counters") or {})
     counters.update(snap.get("gauges") or {})
     assert counters.get("coalesce_deadline_hits") == 1
-    assert counters.get("coalesce_pending_rows") == 0
+    assert c._pending_rows == 0 and c.qsize() == 0
     hist = (snap.get("histograms") or {}).get("coalesce_batch_rows")
     assert hist and hist["count"] == 1
 

@@ -94,7 +94,7 @@ def test_recorder_ring_wraparound_keeps_newest_in_order():
         rec.record(1000 * i, KIND_FULL, 1, i, 0, i, 0, 1, 2, 3, 0, 4, 5, 6)
     assert rec.total == 20
     snap = rec.snapshot()
-    assert snap.shape == (8, 22)  # schema v8: + wait, fsync us/bytes, cpu
+    assert snap.shape == (8, 39)  # schema v9: + sub-phases, per-phase cpu
     # newest 8 rows, oldest-first (timestamps strictly increasing)
     np.testing.assert_array_equal(snap[:, 0],
                                   [1000 * i for i in range(12, 20)])
@@ -156,7 +156,7 @@ def test_trace_schema_version_stamped_and_checked():
     from minpaxos_tpu.obs.recorder import SCHEMA_VERSION
 
     tr = chrome_trace([])
-    assert tr["otherData"]["paxmonSchemaVersion"] == SCHEMA_VERSION == 8
+    assert tr["otherData"]["paxmonSchemaVersion"] == SCHEMA_VERSION == 9
     assert validate_chrome_trace(tr) == []
     stale = chrome_trace([])
     stale["otherData"]["paxmonSchemaVersion"] = 4

@@ -241,7 +241,7 @@ def test_merged_device_host_trace_validates_v4():
     events = rec.to_events(pid=0) + device_round_events(tel, disp,
                                                         n_shards=2)
     trace = chrome_trace(events)
-    assert trace["otherData"]["paxmonSchemaVersion"] == SCHEMA_VERSION == 8
+    assert trace["otherData"]["paxmonSchemaVersion"] == SCHEMA_VERSION == 9
     assert validate_chrome_trace(trace) == []
 
     dev = [e for e in events if e.get("cat") == "device_round"]

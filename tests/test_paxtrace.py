@@ -295,7 +295,7 @@ def test_schema_v5_pins_both_directions():
     events must ride the reserved pid (and nothing else may squat on
     it). (v6 bumped the stamp for paxwatch event tracks; the paxtrace
     pid reservation is unchanged.)"""
-    assert SCHEMA_VERSION == 8
+    assert SCHEMA_VERSION == 9
     spans = np.array(_chain(1, 10**9), np.int64)
     chains = T.span_chains(spans)
     decomp = T.stage_decomposition(chains)

@@ -43,6 +43,14 @@ def _mk_server(tmp_path, name: str, pipeline: bool,
     return ReplicaServer(0, [("127.0.0.1", 7077)], CFG, flags)
 
 
+def _deferred_rows(srv: ReplicaServer) -> int:
+    """Dispatches whose host phases ran deferred, under the next
+    dispatch's device compute: the recorder rows with an overlap."""
+    from minpaxos_tpu.obs.recorder import F_OVERLAP_US
+
+    return int((srv.recorder.snapshot()[:, F_OVERLAP_US] > 0).sum())
+
+
 def _capture_replies(srv: ReplicaServer, log: list) -> None:
     srv.transport.send_client = (  # type: ignore[method-assign]
         lambda cid, kind, rows: log.append((cid, int(kind), rows.copy()))
@@ -121,8 +129,8 @@ def test_pipelined_equals_serial_over_randomized_trace(tmp_path):
     try:
         rep_p = _run_trace(srv_p, trace)
         rep_s = _run_trace(srv_s, trace)
-        assert srv_p.stats["pipelined_ticks"] > 0, srv_p.stats
-        assert srv_s.stats["pipelined_ticks"] == 0, srv_s.stats
+        assert _deferred_rows(srv_p) > 0, srv_p.stats
+        assert _deferred_rows(srv_s) == 0, srv_s.stats
         # every admitted command was replied to, exactly once
         n_cmds = sum(len(rep[2]["cmd_id"]) for rep in rep_p
                      if rep[1] == int(MsgKind.PROPOSE_REPLY))
@@ -189,7 +197,7 @@ def test_durable_no_reply_precedes_its_ticks_fsync(tmp_path):
             srv._device_tick(srv.inbox)
         srv._flush_inflight()
         assert violations == []
-        assert srv.stats["pipelined_ticks"] > 0  # the deferred path ran
+        assert _deferred_rows(srv) > 0  # the deferred path ran
         assert srv.stats["executed"] == 4 * CFG.inbox
     finally:
         srv.store.close()
@@ -279,9 +287,12 @@ def test_tick_counters_and_recorder_fields(tmp_path, pipeline):
     """Both modes record schema-v2 rows: enqueue/readback always
     populated; overlap_us > 0 only where host phases were deferred."""
     from minpaxos_tpu.obs.recorder import (
+        F_DISPATCH_US,
         F_ENQUEUE_US,
         F_OVERLAP_US,
+        F_PERSIST_US,
         F_READBACK_US,
+        F_REPLY_US,
     )
 
     srv = _mk_server(tmp_path, f"rec{int(pipeline)}", pipeline=pipeline)
@@ -300,7 +311,11 @@ def test_tick_counters_and_recorder_fields(tmp_path, pipeline):
         overlapped = rows[:, F_OVERLAP_US] > 0
         if pipeline:
             assert overlapped.any()
-            assert int(overlapped.sum()) == srv.stats["pipelined_ticks"]
+            # a deferred row's overlap is its whole host-phase wall
+            host_us = (rows[:, F_PERSIST_US] + rows[:, F_DISPATCH_US]
+                       + rows[:, F_REPLY_US])
+            assert (rows[overlapped, F_OVERLAP_US]
+                    == host_us[overlapped]).all()
         else:
             assert not overlapped.any()
     finally:

@@ -32,7 +32,8 @@ from minpaxos_tpu.obs.trace import (
 )
 
 TICK_SPANS = (R.PH_WAIT, R.PH_DRAIN, R.PH_ENQUEUE, R.PH_READBACK,
-              R.PH_PERSIST, R.PH_FSYNC, R.PH_EGRESS, R.PH_REPLY)
+              R.PH_PERSIST, R.PH_FSYNC, R.PH_EGRESS, R.PH_REPLY,
+              R.PH_ASSEMBLE, R.PH_CALL, R.PH_PEERS, R.PH_FLUSH)
 STEP_SCOPES = (
     "px.prepare", "px.phase1_reply", "px.accept", "px.slot_write_a",
     "px.accept_ack", "px.prepare_inst", "px.commit_rows",
@@ -46,6 +47,13 @@ POD_SCOPES = ("px.deliver", "px.route.plan", "px.route.gather",
 COL = {name: i for i, name in enumerate(R.FIELD_NAMES)}
 PHASES = ("wait_us", "drain_us", "enqueue_us", "readback_us", "persist_us",
           "dispatch_us", "reply_us")
+V8_FIELD_NAMES = (
+    "t_ns", "kind", "k", "rows_in", "rows_out", "frontier", "exec_backlog",
+    "drain_us", "enqueue_us", "readback_us", "overlap_us", "persist_us",
+    "dispatch_us", "reply_us", "t_rb_ns", "chaos_faults", "coal_occ",
+    "coal_wake", "wait_us", "fsync_us", "fsync_bytes", "cpu_us")
+SUB_PHASES = {"assemble_us": "enqueue_us", "call_us": "enqueue_us",
+              "peer_send_us": "dispatch_us", "flush_us": "dispatch_us"}
 
 
 @pytest.fixture(scope="module")
@@ -137,7 +145,7 @@ def test_recorder_v8_rows_close_on_a_loaded_leader(profiled_cluster):
     up to the wall the rows span: the phases tile the protocol thread's
     time, so nothing a tick costs hides between them."""
     rows = _leader(profiled_cluster["collection"])["rows"]
-    assert rows.shape[1] == R.N_FIELDS == 22 and R.SCHEMA_VERSION == 8
+    assert rows.shape[1] == R.N_FIELDS == 39 and R.SCHEMA_VERSION == 9
     loaded = np.nonzero(rows[:, COL["coal_occ"]] > 0)[0]
     assert len(loaded) >= 50
     first, last = loaded[0], loaded[-1]
@@ -268,6 +276,227 @@ def test_v8_rows_render_wait_and_fsync_slices():
         fsync["ts"] + fsync["dur"] == persist["ts"] + persist["dur"]
     assert tick["args"]["fsync_bytes"] == 999
     assert tick["args"]["cpu_us"] == 600
+
+
+def test_v9_fields_follow_the_old_twenty_two_in_their_places():
+    assert R.SCHEMA_VERSION == 9
+    assert R.FIELD_NAMES[:22] == V8_FIELD_NAMES
+    assert R.N_FIELDS == len(R.FIELD_NAMES) == 39
+    assert R.FIELD_NAMES[-1] == "cpu_sampled"
+    # a wall field and a CPU field per phase; the CPU field is the wall
+    # field's name with _cpu before the unit
+    assert set(R.PHASE_FIELDS) == set(R.PHASE_CPU_FIELDS) == set(TICK_SPANS)
+    for name, f in R.PHASE_FIELDS.items():
+        assert R.FIELD_NAMES[R.PHASE_CPU_FIELDS[name]] == \
+            R.FIELD_NAMES[f][:-3] + "_cpu_us"
+    assert [R.FIELD_NAMES[R.PHASE_FIELDS[n]] for n in
+            (R.PH_ASSEMBLE, R.PH_CALL, R.PH_PEERS, R.PH_FLUSH)] == \
+        list(SUB_PHASES)
+    assert set(R.TILING_PHASES) | set(R.NESTED_IN) == set(TICK_SPANS)
+    assert {R.FIELD_NAMES[R.PHASE_FIELDS[n]] for n in R.TILING_PHASES} == \
+        set(PHASES)
+
+
+def _spin(ns: int) -> None:
+    end = R._cpu_ns() + ns
+    while R._cpu_ns() < end:
+        pass
+
+
+def test_sub_phases_nest_and_leave_their_parents_as_they_were():
+    """A parent's interval is its own, children or not: the children
+    are charged theirs beside it and never exceed it, on both clocks;
+    the tiling phases' CPU adds up to ``cpu_us``, the nested ones' does
+    not count twice."""
+    clock = R.PhaseClock(3)
+    clock.adopt()
+    with R.phase(R.PH_ENQUEUE, clock) as enq:
+        with R.phase(R.PH_ASSEMBLE, clock) as asm:
+            _spin(300_000)
+        with R.phase(R.PH_CALL, clock) as call:
+            _spin(200_000)
+    with R.phase(R.PH_EGRESS, clock) as eg1:
+        with R.phase(R.PH_PEERS, clock) as peers:
+            _spin(100_000)
+    with R.phase(R.PH_REPLY, clock):
+        pass
+    with R.phase(R.PH_EGRESS, clock) as eg2:
+        _spin(100_000)  # _host_catchup: egress's self time
+        with R.phase(R.PH_FLUSH, clock) as flush:
+            _spin(100_000)
+    ns, cpu = dict(clock.ns), dict(clock.cpu)
+    assert ns[R.PH_ENQUEUE] == enq.ns  # the first phase: no glue before it
+    assert ns[R.PH_ASSEMBLE] == asm.ns and ns[R.PH_CALL] == call.ns
+    assert asm.ns + call.ns <= enq.ns
+    assert ns[R.PH_PEERS] == peers.ns and ns[R.PH_FLUSH] == flush.ns
+    assert peers.ns + flush.ns <= eg1.ns + eg2.ns <= ns[R.PH_EGRESS]
+    # CPU: what was spun, where it was spun
+    assert cpu[R.PH_ASSEMBLE] >= 300_000 and cpu[R.PH_CALL] >= 200_000
+    assert cpu[R.PH_ASSEMBLE] + cpu[R.PH_CALL] <= cpu[R.PH_ENQUEUE]
+    assert cpu[R.PH_PEERS] >= 100_000 and cpu[R.PH_FLUSH] >= 100_000
+    assert cpu[R.PH_EGRESS] >= 300_000 + cpu[R.PH_FLUSH] - 100_000
+    for name in TICK_SPANS:
+        assert 0 <= cpu[name] <= ns[name] + 50_000, name  # clock grain
+    # a sampled row's cpu_us is what was taken of its TILING phases
+    taken = {n: clock.take_cpu_us(n) for n in TICK_SPANS}
+    assert taken == {n: cpu[n] // 1000 for n in TICK_SPANS}
+    assert clock.cpu_us(sampled=True) == sum(taken[n]
+                                             for n in R.TILING_PHASES)
+    assert clock.cpu_us(sampled=True) == 0 and not any(clock.cpu.values())
+    # with ``sample`` off no phase reads the thread's clock; the row's
+    # cpu_us is then the one read at its cut, since the cut before
+    clock.sample = False
+    with R.phase(R.PH_PERSIST, clock):
+        with R.phase(R.PH_FSYNC, clock):
+            _spin(200_000)
+    assert not any(clock.cpu.values()) and clock.ns[R.PH_FSYNC] > 0
+    assert clock.cpu_us() >= 200
+    # and a phase that opens after one which read nothing reads for itself
+    clock.sample = True
+    with R.phase(R.PH_REPLY, clock):
+        _spin(100_000)
+    assert 100_000 <= clock.cpu[R.PH_REPLY] < 100_000_000
+
+
+def test_v9_rows_of_a_loaded_leader_nest_and_their_cpu_adds_up(
+        profiled_cluster):
+    lead = _leader(profiled_cluster["collection"])
+    rows = lead["rows"]
+    loaded = rows[(rows[:, COL["coal_occ"]] > 0)
+                  & (rows[:, COL["kind"]] != R.KIND_IDLE_SKIP)]
+    assert len(loaded) >= 50
+    # children inside their parents, row by row (a microsecond of
+    # rounding a field), and covering enqueue but for a line of glue
+    for child_a, child_b, parent in (("assemble_us", "call_us", "enqueue_us"),
+                                     ("peer_send_us", "flush_us",
+                                      "dispatch_us")):
+        both = loaded[:, COL[child_a]] + loaded[:, COL[child_b]]
+        assert (both <= loaded[:, COL[parent]] + 2).all(), parent
+        assert (loaded[:, COL[child_a]] > 0).all(), child_a
+        assert (loaded[:, COL[child_b]] > 0).all(), child_b
+    enq = loaded[:, COL["enqueue_us"]].sum()
+    assert (loaded[:, COL["assemble_us"]].sum()
+            + loaded[:, COL["call_us"]].sum()) >= 0.9 * enq
+    # one row in CPU_SAMPLE_EVERY carries the per-phase CPU times (a
+    # read of the thread's clock is a system call); the others none
+    sampled = rows[:, COL["cpu_sampled"]] == 1
+    assert set(rows[:, COL["cpu_sampled"]].tolist()) == {0, 1}
+    assert abs(int(sampled.sum()) - len(rows) // R.CPU_SAMPLE_EVERY) <= 1
+    every_cpu = rows[:, list(R.PHASE_CPU_FIELDS.values())]
+    assert not every_cpu[~sampled].any() and every_cpu[sampled].any()
+    # every phase's CPU lies inside its wall (the thread clock's grain
+    # and a microsecond of rounding apart)
+    for name, f in R.PHASE_FIELDS.items():
+        cpu = rows[sampled, R.PHASE_CPU_FIELDS[name]]
+        assert (cpu >= 0).all() and cpu.sum() <= \
+            rows[sampled, f].sum() * 1.02 + 10_000, name
+    # on those rows the seven tiling phases' CPU is the row's cpu_us,
+    # and all rows' cpu_us is the counter's
+    tiling = sum(rows[:, R.PHASE_CPU_FIELDS[n]] for n in R.TILING_PHASES)
+    assert (tiling[sampled] == rows[sampled, COL["cpu_us"]]).all()
+    assert rows[:, COL["cpu_us"]].sum() > 0
+    counters = lead["metrics"]["counters"]
+    assert counters["proto_cpu_us"] == rows[:, COL["cpu_us"]].sum()
+
+
+def test_reader_threads_cpu_and_the_thread_count_are_in_the_registry(
+        profiled_cluster):
+    """Every replica's connection readers decoded frames: their CPU time
+    is a plain counter (it answers after ``stop()``), next to the
+    protocol thread's and the number of threads on the one GIL."""
+    for entry in profiled_cluster["collection"]:
+        counters = entry["metrics"]["counters"]
+        gauges = entry["metrics"]["gauges"]
+        assert counters["ingress_cpu_us"] > 0
+        assert counters["proto_cpu_us"] > 0
+        # three replicas in this process, each a protocol thread, an
+        # accept loop, a control thread and a reader a connection
+        assert gauges["threads_alive"] >= 9
+        for gone in ("device_step_ms", "pipelined_ticks",
+                     "store_truncated_bytes", "coalesce_pending_rows"):
+            assert gone not in counters and gone not in gauges
+            assert gone not in entry["metrics"]["histograms"]
+
+
+def test_the_four_sub_phase_spans_lie_inside_their_parents(profiled_cluster):
+    data = jax.profiler.ProfileData.from_file(profiled_cluster["xplane"])
+    checked = {name: 0 for name in R.NESTED_IN}
+    orphans = 0
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                   for ev in line.events if ev.name in TICK_SPANS]
+            for child, parent in R.NESTED_IN.items():
+                parents = [(a, b) for n, a, b in evs if n == parent]
+                for n, a, b in evs:
+                    if n == child:
+                        checked[child] += 1
+                        orphans += not any(pa <= a and b <= pb
+                                           for pa, pb in parents)
+    assert all(n > 100 for n in checked.values()), checked
+    # a phase that was open when the profile began has no span of its
+    # own, and its child has: at most one such a replica
+    assert orphans <= 3, orphans
+
+
+def test_v9_rows_render_the_sub_phases_inside_their_parents():
+    rec = R.FlightRecorder(8)
+    rec.record(9_000_000, R.KIND_FULL, 1, 4, 4, 10, 0, 50, 100, 300, 0,
+               400, 200, 30, t_rb_ns=8_000_000, wait_us=700, fsync_us=250,
+               fsync_bytes=999, cpu_us=600, assemble_us=60, call_us=38,
+               peer_send_us=90, flush_us=70, enqueue_cpu_us=80)
+    events = rec.to_events(pid=1)
+    assert R.validate_chrome_trace(R.chrome_trace(events)) == []
+    by_name = {e["name"]: e for e in events if e.get("ph") == "X"}
+
+    def span(name):
+        return by_name[name]["ts"], by_name[name]["ts"] + by_name[name]["dur"]
+
+    enq, egress = span("enqueue"), span("dispatch")
+    assert span("assemble") == (enq[0], enq[0] + 60)
+    assert span("call") == (enq[1] - 38, enq[1])
+    assert span("peers") == (egress[0], egress[0] + 90)
+    assert span("flush") == (egress[1] - 70, egress[1])
+    assert by_name["peers"]["tid"] == by_name["dispatch"]["tid"] == 1
+    assert by_name["call"]["tid"] == by_name["enqueue"]["tid"] == 0
+    # a v8 row (no sub-phase recorded) draws none
+    rec.record(19_000_000, R.KIND_FULL, 1, 4, 4, 10, 0, 50, 100, 300, 0,
+               400, 200, 30, t_rb_ns=18_000_000)
+    names = [e["name"] for e in rec.to_events(pid=1, last=1)]
+    assert not {"assemble", "call", "peers", "flush"} & set(names)
+
+
+def test_pod_ring_restarts_at_begin_resident_and_takes_one_entry_a_dispatch():
+    from minpaxos_tpu.parallel import sharded
+
+    sc = sharded.ShardedCluster(_small_cfg(), 2, ext_rows=8, key_space=64,
+                                seed=3)
+    sc.elect(0)
+    sc.begin_resident()
+    assert obs.process_pods()[-1]["dispatches"] == 0
+    assert len(obs.process_pods()[-1]["dispatch_ns"]) == 0
+    for _ in range(3):
+        sc.run_resident(2, 4)
+    pod = obs.process_pods()[-1]
+    assert pod["dispatches"] == 3
+    assert pod["dispatch_ns"].shape == pod["readback_ns"].shape == (3,)
+    assert (pod["dispatch_ns"] > 0).all() and (pod["readback_ns"] > 0).all()
+    # copies: the next dispatch does not write into what was handed out
+    before = pod["dispatch_ns"].copy()
+    sc.run_resident(2, 4)
+    assert (pod["dispatch_ns"] == before).all()
+    assert obs.process_pods()[-1]["dispatches"] == 4
+    sc.begin_resident()
+    assert obs.process_pods()[-1]["dispatches"] == 0
+    sc.run_resident(2, 4)
+    assert len(obs.process_pods()[-1]["readback_ns"]) == 1
+    # the ring wraps at its capacity, oldest first
+    sc._pod["dispatches"] = sharded.POD_HOST_RING + 2
+    sc._host_ns[0, :] = np.arange(sharded.POD_HOST_RING)
+    ring = obs.process_pods()[-1]["dispatch_ns"]
+    assert len(ring) == sharded.POD_HOST_RING
+    assert ring[0] == 2 and ring[-1] == 1
+    sc.end_resident()
 
 
 def test_protocol_thread_gets_the_sized_ring_and_keeps_it():

@@ -54,11 +54,13 @@ sys.path.insert(0, str(REPO))
 
 from minpaxos_tpu.obs.metrics import MetricsRegistry  # noqa: E402
 from minpaxos_tpu.obs.recorder import (  # noqa: E402
+    CPU_SAMPLE_EVERY,
     KIND_NAMES,
-    PH_DRAIN,
-    PH_FSYNC,
-    PH_WAIT,
+    FIELD_NAMES,
+    NESTED_IN,
+    PHASE_CPU_FIELDS,
     PHASE_FIELDS,
+    TILING_PHASES,
     FlightRecorder,
     PhaseClock,
     chrome_trace,
@@ -100,9 +102,14 @@ from minpaxos_tpu.runtime.master import (  # noqa: E402
 )
 from minpaxos_tpu.utils.netutil import CONTROL_OFFSET, free_ports  # noqa: E402
 
-# generous noise bound (seconds/tick): ~10x the measured cost on a
+# generous noise bound (seconds/command): ~10x the measured cost on a
 # slow shared core, ~10-30x under the dispatch floor it rides next to
 OVERHEAD_BOUND_S = 30e-6
+# the tick's own: twelve phases, the thread's CPU clock read per phase
+# for one row in eight, and a 39-field row read 21-25 us a tick on this
+# sandbox (PR 37; eight phases and 22 fields read 12.5); a served tick
+# is 15-50 ms
+TICK_OVERHEAD_BOUND_S = 60e-6
 N_ITERS = 20000
 
 
@@ -117,7 +124,8 @@ def overhead_guard() -> bool:
     c_ticks = reg.counter("ticks")
     c_disp = reg.counter("dispatches")
     h_tick = reg.histogram("tick_wall_ms")
-    h_step = reg.histogram("device_step_ms")
+    c_cpu = reg.counter("proto_cpu_us")
+    g_threads = reg.gauge("threads_alive")
     rec = FlightRecorder(4096)
 
     # warm both paths (allocator, bytecode caches), then measure.
@@ -141,33 +149,48 @@ def overhead_guard() -> bool:
     base_s = time.perf_counter() - t0
 
     # what one wakeup of the runtime pays with no profile running:
-    # every phase of the tick loop entered and left once (each an
-    # inactive annotation check + two clock reads), the row's fields
-    # drained from the phase clock, one thread-CPU read
+    # every phase of the tick loop entered and left once, nested as the
+    # runtime nests them (each an inactive annotation check and two
+    # wall-clock reads; for one row in CPU_SAMPLE_EVERY one or two reads
+    # of the thread's CPU clock too), the row's 39 fields drained from
+    # the phase clock, the row's own read of the thread's CPU clock, the
+    # protocol thread's CPU counter and the thread gauge
     clock = PhaseClock(0)
+    children = {p: [c for c, parent in NESTED_IN.items() if parent == p]
+                for p in TILING_PHASES}
+    names = {p: FIELD_NAMES[f] for p, f in PHASE_FIELDS.items()}
+    cpu_names = {p: FIELD_NAMES[f] for p, f in PHASE_CPU_FIELDS.items()}
     x = 1.0
     t0 = time.perf_counter()
     for i in range(N_ITERS):
         x = _tick_body(x)
+        clock.sample = sampled = i % CPU_SAMPLE_EVERY == 0
         c_ticks.inc(tick_inc)
         c_disp.inc()
         h_tick.observe(0.7)
-        h_step.observe(0.4)
-        for name in PHASE_FIELDS:
+        for name in TILING_PHASES:
             with phase(name, clock):
-                pass
-        rec.record(i, i % 4, 1, 8, 8, i, 0, clock.take_us(PH_DRAIN), 30,
-                   270, 60, 20, 30, 10, i,
-                   wait_us=clock.take_us(PH_WAIT),
-                   fsync_us=clock.take_us(PH_FSYNC), fsync_bytes=70,
-                   cpu_us=clock.cpu_us())
+                for child in children[name]:
+                    with phase(child, clock):
+                        pass
+        fields = {names[p]: clock.take_us(p) for p in PHASE_FIELDS}
+        fields.update({cpu_names[p]: clock.take_cpu_us(p)
+                       for p in PHASE_FIELDS})
+        cpu_us = clock.cpu_us(sampled)
+        c_cpu.inc(cpu_us)
+        g_threads.set(threading.active_count())
+        rec.record(i, i % 4, 1, 8, 8, i, 0, fields.pop("drain_us"),
+                   fields.pop("enqueue_us"), fields.pop("readback_us"), 60,
+                   fields.pop("persist_us"), fields.pop("dispatch_us"),
+                   fields.pop("reply_us"), i, fsync_bytes=70,
+                   cpu_us=cpu_us, cpu_sampled=sampled, **fields)
     inst_s = time.perf_counter() - t0
 
     per_tick = (inst_s - base_s) / N_ITERS
-    ok = per_tick < OVERHEAD_BOUND_S
+    ok = per_tick < TICK_OVERHEAD_BOUND_S
     print(f"[obs_smoke] recorder+registry overhead: "
           f"{per_tick * 1e6:.2f} us/tick over {N_ITERS} ticks "
-          f"(bound {OVERHEAD_BOUND_S * 1e6:.0f} us) — "
+          f"(bound {TICK_OVERHEAD_BOUND_S * 1e6:.0f} us) — "
           f"{'ok' if ok else 'FAIL'}", flush=True)
     assert c_ticks.value == N_ITERS + 2000 and rec.total == N_ITERS + \
         2000, "guard loops did not run instrumented"
@@ -310,7 +333,6 @@ def _seed_replica_obs() -> tuple[MetricsRegistry, FlightRecorder]:
     reg.counter("narrow_steps").inc(4)
     reg.counter("idle_skips").inc(10)
     reg.counter("fused_substeps").inc(42)
-    reg.counter("pipelined_ticks").inc(12)
     reg.gauge("committed").set(1234)
     h = reg.histogram("tick_wall_ms")
     for v in (0.4, 0.7, 1.5, 3.0, 9.0):

@@ -19,7 +19,17 @@ kernel and 11 of 13 a route in HBM, the rounds the chip had timed at
 487-507 ms against 424.5).
 
     JAX_PLATFORMS=cpu python tools/pod_placement.py \\
-        benchmarks/configs/minpaxos5_pod_share.json [--tree DIR] [--hlo OUT]
+        benchmarks/configs/minpaxos5_pod_share.json [--tree DIR] [--hlo OUT] \\
+        [--scopes] [--from-hlo OUT]
+
+``--scopes`` names the device time that a trace files "under no scope"
+(PERF.md section 5: the largest bucket of both pod rounds): a ``with
+jax.named_scope`` is not missing there, the compiler gives a fusion its
+ROOT's metadata or none, while the instructions fused into it keep
+theirs. So the listing gives, for every fusion whose own ``op_name`` has
+no ``px.`` component, its output shape and the ``px.*`` scopes of the
+instructions inside its fused computation. ``--from-hlo`` reads a text
+that ``--hlo`` wrote and compiles nothing.
 
 Prints one JSON line. It is a compile, never a measurement.
 """
@@ -60,12 +70,158 @@ def gather_placement(hlo: str) -> dict:
     return {b: dict(c) for b, c in sorted(table.items())}
 
 
+def _innermost_scope(op_name: str) -> str | None:
+    found = re.findall(r"px\.[a-z_0-9.]*[a-z_0-9]", op_name)
+    return found[-1] if found else None
+
+
+def _op_name(line: str) -> str:
+    op = re.search(r'op_name="([^"]*)"', line)
+    return op.group(1) if op else ""
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    comps: dict[str, list[str]] = {}
+    body = None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\) -> .*\{$", line)
+        if head:
+            body = comps[head.group(1)] = []
+        elif line.startswith("}"):
+            body = None
+        elif body is not None:
+            body.append(line)
+    return comps
+
+
+def unscoped_fusions(hlo: str) -> list[dict]:
+    """The device kernels whose OWN ``op_name`` holds no ``px.``
+    component (a trace's scope reduction files their time "under no
+    scope"): fusions, to which the compiler gives their root's metadata
+    or none, and the copies, scatters and sorts it leaves unfused. Each
+    kind of them once, largest compiler estimate first: ``op`` and
+    output ``shape``, the ``branch`` path of the computation it stands
+    in, how many such there are (``n``), the compiler's own
+    ``estimated_cycles`` for one run of one (an estimate, not a time;
+    0 where it gives none), ``root_op`` (the tail of its own
+    ``op_name``), under ``inside`` the innermost ``px.*`` scope of
+    every instruction fused into it that has one, nested fusions
+    included, with counts, under ``feeds`` the scopes of the kernels
+    that read its output (what a kernel with nothing inside, a copy,
+    works for) and under ``combines`` what its scatters and reductions
+    combine with (``scatter-add``): the one name no pass drops."""
+    comps = _computations(hlo)
+    fused = set(re.findall(r"calls=%([^,\s]+)", hlo))
+    parent: dict[str, tuple[str, str]] = {}  # computation -> (caller, step)
+    for name, lines in comps.items():
+        for line in lines:
+            for i, branch in enumerate(re.findall(
+                    r"%([^,\s}]+)", "".join(re.findall(
+                        r"branch_computations=\{([^}]*)\}", line)))):
+                parent[branch] = (name, f"branch_{i}")
+            for body in re.findall(r"body=%([^,\s}]+)", line):
+                parent[body] = (name, "")
+
+    def branch_of(comp: str) -> str:
+        steps = []
+        while comp in parent:
+            comp, step = parent[comp]
+            steps.append(step)
+        return "/".join(filter(None, reversed(steps))) or "-"
+
+    def scopes_inside(comp: str, into: collections.Counter) -> None:
+        for line in comps.get(comp, ()):
+            scope = _innermost_scope(_op_name(line))
+            if scope:
+                into[scope] += 1
+            call = re.search(r" fusion\(.*calls=%([^,\s]+)", line)
+            if call:
+                scopes_inside(call.group(1), into)
+
+    def combiners(comp: str) -> set:
+        """What the scatters and reductions fused into ``comp`` combine
+        with, by the name JAX gave the combiner's arguments
+        (``scatter-add``, ``reduce_or``): it outlives the metadata."""
+        found = set()
+        for line in comps.get(comp, ()):
+            for region in re.findall(r"to_apply=%([^,\s]+)", line):
+                arg = re.search(r"\((\D[\w-]*?)\.?\d*: ", hlo[hlo.index(
+                    "%" + region + " ("):][:200])
+                found.add(arg.group(1) if arg else region)
+            call = re.search(r" fusion\(.*calls=%([^,\s]+)", line)
+            if call:
+                found |= combiners(call.group(1))
+        return found
+
+    def scopes_of(line: str) -> collections.Counter:
+        found = collections.Counter()
+        scope = _innermost_scope(_op_name(line))
+        call = re.search(r" fusion\(.*calls=%([^,\s]+)", line)
+        if scope:
+            found[scope] += 1
+        elif call:
+            scopes_inside(call.group(1), found)
+        return found
+
+    table: dict[tuple, dict] = {}
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            head = re.match(r"\s*(?:ROOT )?%(\S+) = (\(?\w+\[[\d,]*\])\S* "
+                            r"(?:.*?\) )?([\w-]+)\(", line)
+            if not head or not ("estimated_cycles" in line
+                                or head.group(3) in ("fusion", "scatter",
+                                                     "sort")):
+                continue
+            if _innermost_scope(_op_name(line)):
+                continue
+            inside = scopes_of(line) if head.group(3) == "fusion" \
+                else collections.Counter()
+            feeds = collections.Counter()
+            uses = re.compile(r"[(\s]%" + re.escape(head.group(1)) + r"[,)]")
+            for other in lines:
+                if other is not line and uses.search(other.split(" = ", 1)[-1]):
+                    feeds.update(scopes_of(other).keys())
+            called = re.search(r"calls=%([^,\s]+)", line)
+            kind = re.search(r"kind=(\w+)", line)
+            cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
+            key = (head.group(3) + (":" + kind.group(1) if kind else ""),
+                   head.group(2), branch_of(comp),
+                   tuple(sorted(inside.items())), tuple(sorted(feeds)))
+            row = table.setdefault(key, {
+                "op": key[0], "shape": key[1], "branch": key[2], "n": 0,
+                "estimated_cycles": 0,
+                "root_op": _op_name(line).rsplit("/", 1)[-1],
+                "combines": sorted(combiners(called.group(1)))
+                if called else [],
+                "inside": dict(inside), "feeds": sorted(feeds)})
+            row["n"] += 1
+            row["estimated_cycles"] = max(
+                row["estimated_cycles"], int(cycles.group(1)) if cycles else 0)
+    return sorted(table.values(), key=lambda r: -r["estimated_cycles"])
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("config", help="a pod configuration of benchmarks/configs")
     ap.add_argument("--tree", help="import the program from this checkout")
     ap.add_argument("--hlo", help="write the optimised HLO text here")
+    ap.add_argument("--scopes", action="store_true",
+                    help="also list the fusions whose own op_name has no "
+                         "px.* scope, with the scopes of what they hold")
+    ap.add_argument("--from-hlo", help="read an optimised HLO text that "
+                                       "--hlo wrote, and compile nothing")
     args = ap.parse_args()
+    if args.from_hlo:
+        hlo = pathlib.Path(args.from_hlo).read_text()
+        out = {"config": pathlib.Path(args.config).stem,
+               "from_hlo": args.from_hlo,
+               "gather_fusions": gather_placement(hlo)}
+        if args.scopes:
+            out["unscoped_fusions"] = unscoped_fusions(hlo)
+        print(json.dumps(out))
+        return 0
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path.insert(0, args.tree or str(
         pathlib.Path(__file__).resolve().parent.parent))
@@ -128,14 +284,17 @@ def main() -> int:
     hlo = lowered.compile().as_text()
     if args.hlo:
         pathlib.Path(args.hlo).write_text(hlo)
-    print(json.dumps({
+    out = {
         "config": c["name"], "tree": args.tree or ".",
         "compiled_for": "v5e (described, not attached)",
         "lowered_bytes": len(text),
         "lowered_sha256": hashlib.sha256(text.encode()).hexdigest()[:16],
         "lower_s": round(t1 - t0, 1),
         "compile_s": round(time.monotonic() - t1, 1),
-        "gather_fusions": gather_placement(hlo)}))
+        "gather_fusions": gather_placement(hlo)}
+    if args.scopes:
+        out["unscoped_fusions"] = unscoped_fusions(hlo)
+    print(json.dumps(out))
     return 0
 
 
