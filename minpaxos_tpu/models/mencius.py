@@ -25,9 +25,13 @@ ideas, mapped to the reference:
   stalls on a dead owner's slot, that owner's successor ((o+1) % N)
   runs per-instance phase 1 (PREPARE_INST at a takeover ballot >
   ballot 0 that ownership implies) over the blocked range and no-op
-  fills slots a majority reports empty — the reference's
-  NB_INST_TO_SKIP bulk skip, but majority-audited per slot (the same
-  pvotes machinery as models/minpaxos.py step 7d/7e).
+  fills slots a majority reports empty, majority-audited per slot (the
+  same pvotes machinery as models/minpaxos.py step 7d/7e). The
+  successor also REVOKES a range of the silent owner's future turns
+  (section 7r; Mao et al., OSDI 2008), the reference's NB_INST_TO_SKIP
+  bulk skip done by Paxos: a range promise, no-ops accepted by a
+  majority above every value it holds, renewed while the owner stays
+  silent; its values that every answerer holds commit with them.
 * **Conflict-aware out-of-order execution** (:799-876): committed
   slots above the blocking frontier execute early when every earlier
   conflicting slot (same key, >= one PUT — state.go:55-62) inside the
@@ -43,6 +47,7 @@ driven through classic per-instance phase 1.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -88,6 +93,28 @@ from minpaxos_tpu.ops.winner import (
 from minpaxos_tpu.wire.messages import MsgKind, Op
 
 
+#: no revoked range (an rv_lo / rv_from beyond any slot)
+REVOKE_NONE = 2 ** 30
+#: ``client_id`` of the revocation's own answers and announcements (no
+#: client has it): a PREPARE_INST_REPLY that answers a revocation's
+#: phase 1 (``key_hi`` 0) or phase 2 (``key_hi`` 1), and a COMMIT that
+#: announces what a revocation chose. Its requests are PREPARE_INST
+#: rows with ``op`` (the wire's ``to_infinity``) 1 (phase 1: promise
+#: the range from ``inst``) or 2 (phase 2: accept no-ops from ``inst``
+#: to the promised range's end)
+REVOKE_MARK = -2
+
+
+def revoke_turns(cfg: MinPaxosConfig) -> int:
+    """How many of a silent owner's turns one revocation takes: a
+    window's worth, ``cfg.window`` turns = R windows of the merged log,
+    so a range always reaches past every replica's window. Upstream
+    skips NB_INST_TO_SKIP = 100,000 at once (mencius.go); here a range
+    is renewed while its owner stays silent (section 7r), and an owner
+    that returns resumes after R windows, not after 100,000 turns."""
+    return cfg.window
+
+
 class MenciusState(NamedTuple):
     """One Mencius replica's device state. Field names shared with
     ReplicaState where the host wrappers read them (committed_upto,
@@ -122,6 +149,39 @@ class MenciusState(NamedTuple):
     tick: jnp.ndarray
     stall_ticks: jnp.ndarray
     peer_commits: jnp.ndarray  # i32[R] last frontier reported per peer
+    # revocation (section 7r, ``px.revoke``), per OWNER o, as an
+    # acceptor: the range [rp_lo, rp_hi] of o's turns it promised at
+    # rp_bal (phase 2 is accepted at that ballot alone), the turns
+    # [rv_lo, rv_hi] accepted as
+    # no-ops at rv_bal, of which [rv_lo, rv_cm] are known chosen; and,
+    # as the revoker, the run [rv_from, rv_vend] of o's values that the
+    # revocation found held by all its answerers and committed
+    rp_lo: jnp.ndarray  # i32[R], REVOKE_NONE when there is no range
+    rp_hi: jnp.ndarray  # i32[R], -1
+    rp_bal: jnp.ndarray  # i32[R], NO_BALLOT
+    rv_lo: jnp.ndarray  # i32[R], REVOKE_NONE
+    rv_hi: jnp.ndarray  # i32[R], -1
+    rv_cm: jnp.ndarray  # i32[R], -1
+    rv_bal: jnp.ndarray  # i32[R], NO_BALLOT
+    rv_from: jnp.ndarray  # i32[R], REVOKE_NONE
+    rv_vend: jnp.ndarray  # i32[R], -1
+    heard: jnp.ndarray  # i32[R] my tick when a row of each peer last came
+    # i32[R] steps that brought rows of other peers and none of this
+    # one since its last: how long an owner has been silent, counted in
+    # its peers' traffic, not in ticks (a live owner of the served path
+    # ticks far less often than its successor wakes)
+    quiet: jnp.ndarray
+    # my revocation in flight: its first turn (-1: none), ballot, phase
+    # (1 prepare, 2 accept), the first turn it proposes as no-ops, the
+    # answerers of the phase, and what the phase-1 answers reported
+    rk_at: jnp.ndarray
+    rk_bal: jnp.ndarray
+    rk_phase: jnp.ndarray
+    rk_t: jnp.ndarray
+    rk_acks: jnp.ndarray  # u16
+    rk_low: jnp.ndarray  # highest start of an answerer's run of values
+    rk_top: jnp.ndarray  # highest value an answerer holds
+    rk_topmin: jnp.ndarray  # lowest such top: all hold [rk_low, rk_topmin]
     kv: KVState
 
 
@@ -157,13 +217,70 @@ def init_mencius(cfg: MinPaxosConfig, me: int) -> MenciusState:
         tick=jnp.int32(0),
         stall_ticks=jnp.int32(0),
         peer_commits=jnp.full(r, -1, dtype=jnp.int32),
+        rp_lo=jnp.full(r, REVOKE_NONE, dtype=jnp.int32),
+        rp_hi=jnp.full(r, -1, dtype=jnp.int32),
+        rp_bal=jnp.full(r, NO_BALLOT, dtype=jnp.int32),
+        rv_lo=jnp.full(r, REVOKE_NONE, dtype=jnp.int32),
+        rv_hi=jnp.full(r, -1, dtype=jnp.int32),
+        rv_cm=jnp.full(r, -1, dtype=jnp.int32),
+        rv_bal=jnp.full(r, NO_BALLOT, dtype=jnp.int32),
+        rv_from=jnp.full(r, REVOKE_NONE, dtype=jnp.int32),
+        rv_vend=jnp.full(r, -1, dtype=jnp.int32),
+        heard=jnp.zeros(r, dtype=jnp.int32),
+        quiet=jnp.zeros(r, dtype=jnp.int32),
+        rk_at=jnp.int32(-1),
+        rk_bal=jnp.int32(NO_BALLOT),
+        rk_phase=jnp.int32(0),
+        rk_t=jnp.int32(-1),
+        rk_acks=jnp.uint16(0),
+        rk_low=jnp.int32(-1),
+        rk_top=jnp.int32(-1),
+        rk_topmin=jnp.int32(-1),
         kv=kv_init(cfg.kv_pow2),
     )
 
 
+def _by_owner(arr, owner):
+    """``arr[owner]`` for an [R] array and an array of owners in range:
+    R selects, no gather."""
+    out = jnp.zeros(owner.shape, arr.dtype)
+    for r in range(arr.shape[0]):
+        out = jnp.where(owner == r, arr[r], out)
+    return out
+
+
+def _owner_max(owner_of, mask, vals, n_owners: int, empty):
+    """The largest ``vals`` over each owner's window slots where
+    ``mask``: [R], ``empty`` for an owner with none."""
+    hit = (owner_of[None, :] == jnp.arange(n_owners)[:, None]) & mask[None, :]
+    return jnp.max(jnp.where(hit, vals[None, :], empty), axis=1)
+
+
+def _merge_range(lo0, hi0, lo, hi, base, stride: int):
+    """An owner's range [lo0, hi0] joined with [lo, hi] where the two
+    touch (one owner's turns are ``stride`` apart); a range that lies
+    wholly below the window (``base``), or none, is replaced. Returns
+    (lo', hi', ok); ok is false where the two lie apart and nothing
+    changes."""
+    stale = hi0 < base
+    ok = stale | ((lo <= hi0 + stride) & (hi >= lo0 - stride))
+    return (jnp.where(stale, lo, jnp.minimum(lo0, lo)),
+            jnp.where(stale, hi, jnp.maximum(hi0, hi)), ok)
+
+
+def _heard_lately(cfg: MinPaxosConfig, states):
+    """Whether a replica heard any peer in the last ``cfg.noop_delay``
+    ticks, from its state as it stands (leading axes kept)."""
+    r = states.heard.shape[-1]
+    peers = jnp.where(jnp.arange(r) == states.me[..., None],
+                      jnp.int32(-(2 ** 30)), states.heard)
+    # paxlint: disable=wall-honesty -- an elapsed time, no update
+    return states.tick - peers.max(axis=-1) < cfg.noop_delay
+
+
 def mencius_step_impl(
     cfg: MinPaxosConfig, state: MenciusState, inbox: MsgBatch,
-    tick_inc=1, steady: bool = False,
+    tick_inc=1, steady: bool = False, revoke: bool = True,
 ) -> tuple[MenciusState, Outbox, ExecResult]:
     """Advance one Mencius replica by one message batch (pure; vmapped
     by the cluster wrapper below).
@@ -175,10 +292,28 @@ def mencius_step_impl(
 
     ``steady`` (static): trace the step without its recovery sections
     (those ``recovery_gates`` names), as models/minpaxos.py
-    ``replica_step_impl`` does."""
+    ``replica_step_impl`` does.
+
+    ``revoke`` (static): whether the step carries the revocation of a
+    silent owner's turns at all: sections 7r, 8r and 10r, and section
+    10's start of one. A served replica's does not
+    (``mencius_served_step_impl``), and so none of its peers begins one."""
     with Sections() as sec:
         return _mencius_step_sections(sec, cfg, state, inbox, tick_inc,
-                                      steady)
+                                      steady, revoke)
+
+
+#: the step of a served replica (runtime/replica.py). Its stable store
+#: keeps no revocation's accepted range: a replica restarted from it
+#: would forget a range of no-ops it accepted and could then accept the
+#: returning owner's value in a turn where the no-op was chosen. So a
+#: served replica steps without the revocation (sections 7r, 8r, 10r:
+#: with no peer that begins one there is nothing for them to do), and
+#: section 10's sweep is its takeover, as before section 7r. One
+#: module-level object: every replica of a process shares
+#: ``_packed_step``'s compiled variants.
+mencius_served_step_impl = functools.partial(mencius_step_impl,
+                                             revoke=False)
 
 
 #: the takeover's sections and their gates, as models/minpaxos.py
@@ -187,11 +322,18 @@ def mencius_step_impl(
 #:
 #: * 7a answers PREPARE_INST rows and 7b adopts from
 #:   PREPARE_INST_REPLY rows only.
-#: * 10 sweeps, fills and re-drives only where ``stall_ticks >=
-#:   cfg.noop_delay`` (the successor's threshold, the lowest), read
-#:   after section 8 has set the counter to its old value plus
-#:   ``tick_inc`` (1 wherever whole rounds are stepped) or to 0. The
-#:   reset that closes an episode is not part of it and always runs.
+#: * 10 sweeps, fills, re-drives and begins a revocation only where
+#:   ``stall_ticks >= cfg.noop_delay`` (the successor's threshold, the
+#:   lowest), read after section 8 has set the counter to its old value
+#:   plus ``tick_inc`` (1 wherever whole rounds are stepped) or to 0,
+#:   and only at a replica that heard a peer lately (``heard`` as it
+#:   stood before the step, so a dead replica of the pod, which hears
+#:   nobody, keeps it shut). The reset that closes an episode is not
+#:   part of it and always runs.
+#:
+#: A revocation's rows (7r) are PREPARE_INST and PREPARE_INST_REPLY
+#: rows and open the first gate; what it chose (8r) and its renewals
+#: and announcements (10r) are in every step.
 #:
 #: SKIP rows (section 4) are the normal path of an uneven load, not
 #: recovery: a steady step keeps them.
@@ -200,11 +342,13 @@ mencius_step_impl.recovery_gates = {
         present(MsgKind.PREPARE_INST) | present(MsgKind.PREPARE_INST_REPLY)),
     "px.takeover": lambda cfg, states, present: (
         # paxlint: disable=wall-honesty -- a bound on it, no update
-        states.stall_ticks + 1 >= cfg.noop_delay).any(),
+        (states.stall_ticks + 1 >= cfg.noop_delay)
+        & _heard_lately(cfg, states)).any(),
 }
 
 
-def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
+def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady,
+                           revoke):
     """``mencius_step_impl``'s body; ``sec(name)`` opens the ``px.*``
     scope of the section that follows (ops/sections.py). Sections that
     MinPaxos has too carry its names."""
@@ -219,7 +363,11 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     k = inbox.kind
     idx = jnp.arange(S, dtype=jnp.int32)
     idx_abs = state.window_base + idx
-    own_mask = jnp.mod(idx_abs, R) == me
+    owner_of = jnp.mod(idx_abs, R)
+    own_mask = owner_of == me
+
+    heard_lately = _heard_lately(cfg, state)
+    quiet0 = state.quiet
 
     is_propose = k == int(MsgKind.PROPOSE)
     is_accept = k == int(MsgKind.ACCEPT)
@@ -234,11 +382,24 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
 
     sec("px.propose")
     # ---- 1. PROPOSE into my owned slots (handlePropose :429-447) ----
+    # the cursor never points at a turn that is no longer mine: past
+    # own slots a takeover already filled, and, once it reaches them,
+    # past the turns a revocation took (section 7r). Proposing into
+    # either would overwrite a chosen no-op
+    taken = jnp.max(jnp.where(own_mask & (idx_abs >= state.crt_own)
+                              & (state.status != NONE), idx_abs, -1))
+    my_lo, my_hi = _by_owner(state.rv_lo, me), _by_owner(state.rv_hi, me)
+    in_rv = (my_lo <= state.crt_own) & (state.crt_own <= my_hi)
+    past = jnp.maximum(taken, jnp.where(in_rv, my_hi, -1))
+    state = state._replace(crt_own=jnp.where(past >= state.crt_own,
+                                             past + R, state.crt_own))
     csum_p = jnp.cumsum(is_propose.astype(jnp.int32))
     prefix = csum_p - 1
     slots_p = state.crt_own + R * prefix
     rel_p = slots_p - state.window_base
-    fits = is_propose & (rel_p >= 0) & (rel_p < S)
+    fits = (is_propose & (rel_p >= 0) & (rel_p < S)
+            & ((slots_p < my_lo) | (slots_p > my_hi)))
+    n_fit = fits.sum(dtype=jnp.int32)
     me_bit = (jnp.int32(1) << me).astype(jnp.uint16)
     # one winning row per slot + dense gathers instead of per-column
     # scatters (ops/winner.py rationale) — and the winner itself is
@@ -252,7 +413,7 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     off_p = idx_abs - state.crt_own
     rank_p = off_p // R
     hit_p = ((off_p >= 0) & (jnp.mod(off_p, R) == 0)
-             & (rank_p < csum_p[-1]))
+             & (rank_p < n_fit))
     win_p = rank_select(csum_p, jnp.clip(rank_p, 0, M - 1) + 1)
     win_p = jnp.where(hit_p, win_p, -1)
     # a slot's winning row is fetched ONCE a pass, all its columns
@@ -271,7 +432,7 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         status=gather_const(hit_p, ACCEPTED, state.status),
         votes=gather_const(hit_p, me_bit, state.votes),
     )
-    n_prop = jnp.where(fits, 1, 0).sum()
+    n_prop = n_fit
     state = state._replace(
         crt_own=state.crt_own + R * n_prop,
         crt_inst=jnp.maximum(state.crt_inst,
@@ -308,11 +469,23 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     # a section tests together (ops/winner.py read_cols; until PR 36 an
     # element gather a column, here and in section 11)
     ballot_a, status_a = read_cols(rel_a_safe, (state.ballot, state.status))
+    # a revocation (section 7r): the owner's turns in a range of no-ops
+    # I accepted take no value at any ballot (a no-op may have been
+    # chosen there: every later proposal must be that no-op). Its
+    # phase-1 promise alone blocks nothing: phase 2 is accepted only by
+    # an acceptor that holds no value of the range, so a value accepted
+    # after the promise and a chosen range never meet, and a revocation
+    # abandoned after its phase 1 leaves a live owner unhindered
+    o_a = jnp.mod(inbox.inst, R)
+    revoked_a = ((inbox.inst >= _by_owner(state.rv_lo, o_a))
+                 & (inbox.inst <= _by_owner(state.rv_hi, o_a))
+                 & (inbox.op != int(Op.NONE)))
     acc_pre = (
         is_accept & in_win_a
         & (owner_ok | (inbox.ballot > 0))
         & (inbox.ballot >= ballot_a)
         & (status_a < COMMITTED)
+        & ~revoked_a
     )
     ab_max = jnp.full(S + 1, NO_BALLOT, jnp.int32).at[
         jnp.where(acc_pre, rel_a, S)].max(inbox.ballot, mode="drop")
@@ -434,7 +607,6 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
                                              mode="drop")
     ends = jnp.full(R, jnp.int32(-1)).at[
         jnp.where(is_skip, skip_src, R)].max(inbox.inst, mode="drop")
-    owner_of = jnp.mod(idx_abs, R)
     skipped = ((idx_abs >= starts[owner_of]) & (idx_abs <= ends[owner_of])
                & (state.status < COMMITTED))
     state = state._replace(
@@ -468,6 +640,10 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     # last_committed. Adopt the batch-max report per peer rather than
     # a running max so a crash-revived peer's LOWER report un-pins
     # catch-up (reports are TCP-ordered within one process lifetime).
+    # ANY row of a peer says it is there (a takeover's phase-1 answer
+    # among them: the revocation waits to see one, section 10)
+    row_from = ((jnp.arange(R)[:, None] == inbox.src[None, :])
+                & (k != 0)[None, :]).any(axis=1)
     rep_row = (is_accept | is_areply | is_commit) & (inbox.src >= 0)
     rep_src = jnp.where(rep_row, jnp.clip(inbox.src, 0, R - 1), R)
     pc_seen = jnp.full(R + 1, jnp.int32(-(2 ** 30))).at[rep_src].max(
@@ -476,12 +652,16 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     state = state._replace(
         votes=state.votes | pack_vote_bits(
             vote_cov & drv_slot[:, None]),
-        peer_commits=jnp.where(replied, pc_seen[:R], state.peer_commits))
+        peer_commits=jnp.where(replied, pc_seen[:R], state.peer_commits),
+        heard=jnp.where(row_from, state.tick, state.heard),
+        quiet=jnp.where(row_from, 0,
+                        state.quiet + row_from.any().astype(jnp.int32)))
 
     sec("px.commit_rows")
     # ---- 6. COMMIT rows (explicit commit transfer, bcastCommit) ----
     rel_c, in_win_c = _rel(state, inbox.inst, S)
-    com_ok = is_commit & in_win_c
+    is_revoked = is_commit & (inbox.client_id == REVOKE_MARK)
+    com_ok = is_commit & in_win_c & ~is_revoked
     win_c, hit_c = slot_winner(S, rel_c, com_ok)
     state = slot_write(state, win_c, hit_c)._replace(
         status=jnp.where(hit_c, jnp.maximum(state.status, COMMITTED),
@@ -490,12 +670,54 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         # piggybacked sender frontier (last_committed): a healing
         # laggard otherwise thinks the log ends at each served chunk,
         # in_flight drops, and its takeover sweep stops one chunk in
+        # (a revocation's announcement is no proposal: it leaves
+        # crt_inst alone, or idle owners' turns would look in flight)
         crt_inst=jnp.maximum(
             state.crt_inst,
             jnp.max(jnp.where(
-                is_commit,
+                is_commit & ~is_revoked,
                 jnp.maximum(inbox.inst, inbox.last_committed), -1)) + 1),
     )
+
+    # the revocation's helpers (sections 7r, 8r, 10r)
+    L = revoke_turns(cfg)
+    # the takeover sweep's span (section 10) in whole turns of R slots
+    sweep_span = R * -(-cfg.recovery_rows // R)
+
+    def held_run(st):
+        """Per owner: the highest window slot that holds one of its
+        values (-1: none) and where the unbroken run of its held turns
+        that ends there begins."""
+        held = (st.status >= ACCEPTED) & (st.op != int(Op.NONE))
+        top = _owner_max(owner_of, held, idx_abs, R, -1)
+        below = ~held & (idx_abs < _by_owner(top, owner_of))
+        gap = _owner_max(owner_of, below, idx_abs, R, -1)
+        return top, jnp.where(gap >= 0, gap + R, st.window_base)
+
+    def begin_revoke(st, go, owner, lo, bal):
+        """Start my revocation of ``owner``'s turns from ``lo`` at
+        ``bal`` where ``go`` and the range touches any I promised: my
+        own phase-1 answer is in it at once."""
+        top, low = held_run(st)
+        mp_lo, mp_hi, mp_ok = _merge_range(st.rp_lo, st.rp_hi, lo,
+                                           lo + R * (L - 1), st.window_base,
+                                           R)
+        at = (jnp.arange(R) == owner) & go & mp_ok
+        go = at.any()
+        return st._replace(
+            rp_lo=jnp.where(at, mp_lo, st.rp_lo),
+            rp_hi=jnp.where(at, mp_hi, st.rp_hi),
+            rp_bal=jnp.where(at, jnp.maximum(st.rp_bal, bal), st.rp_bal),
+            rk_at=jnp.where(go, lo, st.rk_at),
+            rk_bal=jnp.where(go, bal, st.rk_bal),
+            rk_phase=jnp.where(go, 1, st.rk_phase),
+            rk_acks=jnp.where(go, me_bit, st.rk_acks),
+            rk_low=jnp.where(go, _by_owner(low, owner), st.rk_low),
+            rk_top=jnp.where(go, _by_owner(top, owner), st.rk_top),
+            rk_topmin=jnp.where(go, _by_owner(top, owner), st.rk_topmin),
+            max_recv_ballot=jnp.maximum(st.max_recv_ballot,
+                                        jnp.where(go, bal, 0)),
+        )
 
     sec("px.takeover_phase1")
     # ---- 7. takeover phase 1 (forceCommit :244-257, :878-897) ----
@@ -506,13 +728,154 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     # a steady step has no such row: no answer, no promise, no adoption
     # paxlint: disable=trace-hazard -- `steady` is a static bool
     if not steady:
+        rq = is_pinst & (inbox.op > 0)
+        # paxlint: disable=trace-hazard -- `revoke` is a static bool
+        if revoke:
+            # ---- 7r. revocation (Mao et al., OSDI 2008: a suspected
+            # server's future turns revoked by Paxos at a higher ballot;
+            # upstream's forceCommit skip, mencius.go:878-897) ----
+            # Phase 1 (a PREPARE_INST with op 1) asks every acceptor to
+            # promise owner o's turns [inst, inst + R (L - 1)] to the
+            # ballot, and to say which of o's values it holds: the highest
+            # (cmd_id) and where the unbroken run of them ending there
+            # begins (key_lo). Phase 2 (op 2) proposes no-ops from the
+            # first turn above every answerer's highest value to the end of
+            # the promise, under that very promise. Values all answerers
+            # hold were accepted by a q2 majority, so are chosen: they
+            # commit with their values. An acceptor refuses phase 2 where
+            # it holds a value at or above its first turn; refusing is
+            # always safe.
+            o_q = jnp.mod(inbox.inst, R)
+            top_o, low_o = held_run(state)
+            at_q = (o_q[None, :] == jnp.arange(R)[:, None]) & rq[None, :]
+            q_bal = jnp.where(at_q, inbox.ballot[None, :],
+                              NO_BALLOT).max(axis=1)
+            win_q = rq & (inbox.ballot == _by_owner(q_bal, o_q))
+            at_w = at_q & win_q[None, :]
+            q_at = jnp.where(at_w, inbox.inst[None, :], -1).max(axis=1)
+            q_ph = jnp.where(at_w, inbox.op[None, :], 0).max(axis=1)
+            has_q = q_bal > NO_BALLOT
+            p_lo, p_hi, p_ok = _merge_range(state.rp_lo, state.rp_hi, q_at,
+                                            q_at + R * (L - 1),
+                                            state.window_base, R)
+            ok1 = has_q & (q_ph == 1) & (q_bal >= state.rp_bal) & p_ok
+            a_lo, a_hi, a_ok = _merge_range(state.rv_lo, state.rv_hi, q_at,
+                                            state.rp_hi, state.window_base, R)
+            ok2 = (has_q & (q_ph == 2) & (q_bal == state.rp_bal)
+                   & (q_at >= state.rp_lo) & (q_at <= state.rp_hi)
+                   & (top_o < q_at) & a_ok)
+            state = state._replace(
+                rp_lo=jnp.where(ok1, p_lo, state.rp_lo),
+                rp_hi=jnp.where(ok1, p_hi, state.rp_hi),
+                rp_bal=jnp.where(ok1, q_bal, state.rp_bal),
+                rv_lo=jnp.where(ok2, a_lo, state.rv_lo),
+                rv_hi=jnp.where(ok2, a_hi, state.rv_hi),
+                rv_cm=jnp.where(ok2 & (state.rv_hi < state.window_base), -1,
+                                state.rv_cm),
+                rv_bal=jnp.where(ok2, q_bal, state.rv_bal),
+                max_recv_ballot=jnp.maximum(
+                    state.max_recv_ballot,
+                    jnp.max(jnp.where(rq, inbox.ballot, 0))),
+            )
+            ok_row = win_q & _by_owner(jnp.where(q_ph == 1, ok1, ok2), o_q)
+            out = out._replace(
+                kind=jnp.where(rq, int(MsgKind.PREPARE_INST_REPLY), out.kind),
+                src=jnp.where(rq, me, out.src),
+                inst=jnp.where(rq, inbox.inst, out.inst),
+                ballot=jnp.where(rq, _by_owner(state.rp_bal, o_q), out.ballot),
+                last_committed=jnp.where(rq, inbox.ballot, out.last_committed),
+                op=jnp.where(rq, ok_row.astype(jnp.int32), out.op),
+                cmd_id=jnp.where(rq, _by_owner(top_o, o_q), out.cmd_id),
+                key_lo=jnp.where(rq, _by_owner(low_o, o_q), out.key_lo),
+                key_hi=jnp.where(rq, inbox.op - 1, out.key_hi),
+                val_hi=jnp.where(rq, 0, out.val_hi),
+                val_lo=jnp.where(rq, 0, out.val_lo),
+                client_id=jnp.where(rq, REVOKE_MARK, out.client_id),
+            )
+            dst = jnp.where(rq, inbox.src, dst)
+            # my revocation's answers
+            o_k = jnp.mod(jnp.maximum(state.rk_at, 0), R)
+            ph1 = state.rk_phase == 1
+            ans = (is_pir & (inbox.client_id == REVOKE_MARK)
+                   & (state.rk_at >= 0)
+                   & (inbox.last_committed == state.rk_bal)
+                   & (inbox.key_hi == state.rk_phase - 1)
+                   & (inbox.inst == jnp.where(ph1, state.rk_at, state.rk_t)))
+            yes = ans & (inbox.op == 1)
+            said = (yes[:, None]
+                    & (inbox.src[:, None] == jnp.arange(R))).any(0)
+            acks = state.rk_acks | jnp.sum(
+                jnp.where(said, jnp.left_shift(1, jnp.arange(R)), 0)
+            ).astype(jnp.uint16)
+            n_ack = jax.lax.population_count(acks).astype(jnp.int32)
+            low = jnp.maximum(state.rk_low,
+                              jnp.max(jnp.where(yes, inbox.key_lo, -1)))
+            top = jnp.maximum(state.rk_top,
+                              jnp.max(jnp.where(yes, inbox.cmd_id, -1)))
+            topmin = jnp.minimum(state.rk_topmin, jnp.min(
+                jnp.where(yes, inbox.cmd_id, REVOKE_NONE)))
+            abandon = (ans & (inbox.op == 0)).any()
+            done1 = ~abandon & ph1 & (n_ack >= max(quorum1, quorum2))
+            done2 = ~abandon & (state.rk_phase == 2) & (n_ack >= quorum2)
+            # the no-ops start above every answerer's value and above the
+            # span the episode's sweep re-drives at the SAME ballot (10):
+            # one ballot, one value a slot
+            base = jnp.maximum(state.rk_at + sweep_span, top + R)
+            t2 = base + jnp.mod(o_k - base, R)
+            vals = (done1 & (owner_of == o_k) & (idx_abs >= low)
+                    & (idx_abs <= topmin) & (state.status == ACCEPTED)
+                    & (state.op != int(Op.NONE)))
+            at_k = jnp.arange(R) == o_k
+            m_lo, m_hi, m_ok = _merge_range(state.rv_lo, state.rv_hi, t2,
+                                            state.rp_hi, state.window_base, R)
+            stale_k = state.rv_hi < state.window_base
+            # phase 2 only where its no-ops join the range of this owner I
+            # already accepted (or that range lies below the window): an
+            # owner that came back, proposed past the range and fell silent
+            # again has values between the two, which one range announced
+            # from its first turn would cover
+            joins = _by_owner(m_ok, o_k)
+            to2 = done1 & joins
+            stop = abandon | done2 | (done1 & ~joins)
+            got_vals = to2 & (topmin >= low)
+            state = state._replace(
+                status=jnp.where(vals, COMMITTED, state.status),
+                # phase 1 done: my own acceptance of phase 2
+                rv_lo=jnp.where(at_k & to2, m_lo, state.rv_lo),
+                rv_hi=jnp.where(at_k & to2, m_hi, state.rv_hi),
+                rv_bal=jnp.where(at_k & to2, state.rk_bal, state.rv_bal),
+                rv_cm=jnp.where(
+                    at_k & to2,
+                    jnp.maximum(jnp.where(stale_k, -1, state.rv_cm), t2 - R),
+                    jnp.where(at_k & done2,
+                              jnp.maximum(state.rv_cm, state.rv_hi),
+                              state.rv_cm)),
+                rv_from=jnp.where(at_k & got_vals, low, state.rv_from),
+                rv_vend=jnp.where(at_k & got_vals, topmin, state.rv_vend),
+                rk_at=jnp.where(stop, -1, state.rk_at),
+                rk_phase=jnp.where(stop, 0, jnp.where(to2, 2, state.rk_phase)),
+                rk_t=jnp.where(to2, t2, state.rk_t),
+                rk_acks=jnp.where(to2, me_bit, acks),
+                rk_low=low, rk_top=top, rk_topmin=topmin,
+                max_recv_ballot=jnp.maximum(
+                    state.max_recv_ballot,
+                    jnp.max(jnp.where(ans, inbox.ballot, 0))),
+            )
+
         rel_pi, in_win_pi = _rel(state, inbox.inst, S)
         rel_pi_safe = jnp.minimum(rel_pi, S - 1)
-        pi_answer = is_pinst & (in_win_pi | (inbox.inst >= state.crt_inst))
+        pi_answer = (is_pinst & ~rq
+                     & (in_win_pi | (inbox.inst >= state.crt_inst)))
         pi_com = pi_answer & in_win_pi & (state.status[rel_pi_safe] >= COMMITTED)
         pi_occ = (pi_answer & ~pi_com & in_win_pi
                   & (state.status[rel_pi_safe] >= ACCEPTED))
         pi_val = pi_com | pi_occ
+        # an empty slot in a range of no-ops I accepted (7r) answers as
+        # that no-op at its ballot
+        o_pi = jnp.mod(inbox.inst, R)
+        pi_rv = (pi_answer & ~pi_val & in_win_pi
+                 & (inbox.inst >= _by_owner(state.rv_lo, o_pi))
+                 & (inbox.inst <= _by_owner(state.rv_hi, o_pi)))
         # promise: bump slot ballot so ballot-0 owner writes lose from here
         prom = pi_answer & ~pi_com & in_win_pi & (
             inbox.ballot > state.ballot[rel_pi_safe])
@@ -526,7 +889,9 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
             src=jnp.where(pi_answer, me, out.src),
             inst=jnp.where(pi_answer, inbox.inst, out.inst),
             ballot=jnp.where(pi_val, state.ballot[rel_pi_safe],
-                             jnp.where(pi_answer, NO_BALLOT, out.ballot)),
+                             jnp.where(pi_rv, _by_owner(state.rv_bal, o_pi),
+                                       jnp.where(pi_answer, NO_BALLOT,
+                                                 out.ballot))),
             # COMMIT answers carry my real frontier (it feeds receivers'
             # peer_commits, 9d, and crt_inst, section 6 — echoing the
             # sweep ballot there poisoned catch-up targeting); PIR answers
@@ -543,14 +908,15 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
             val_lo=jnp.where(pi_val, state.val_lo[rel_pi_safe], out.val_lo),
             cmd_id=jnp.where(pi_val, state.cmd_id[rel_pi_safe], out.cmd_id),
             client_id=jnp.where(pi_val, state.client_id[rel_pi_safe],
-                                out.client_id),
+                                jnp.where(pi_rv, -1, out.client_id)),
         )
         dst = jnp.where(pi_answer, inbox.src, dst)
 
         # 7b. collect PREPARE_INST_REPLY answers (mine): pvotes + adoption
         rel_v, in_win_v = _rel(state, inbox.inst, S)
         rel_v_safe = jnp.minimum(rel_v, S - 1)
-        pv_ok = (is_pir & (inbox.last_committed == state.takeover_ballot)
+        pv_ok = (is_pir & (inbox.client_id != REVOKE_MARK)
+                 & (inbox.last_committed == state.takeover_ballot)
                  & in_win_v)
         state = state._replace(
             pvotes=state.pvotes | scatter_vote_bits(S, rel_v, inbox.src,
@@ -565,6 +931,72 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         state = slot_write(state, win_v, hit_v)._replace(
             status=gather_const(hit_v, ACCEPTED, state.status),
             votes=gather_const(hit_v, me_bit, state.votes),
+        )
+
+    # paxlint: disable=trace-hazard -- `revoke` is a static bool
+    if revoke:
+        sec("px.revoke")
+        # ---- 8r. what a revocation chose (section 7r), in every step ----
+        # A revoker re-sends, every step, ONE COMMIT row with REVOKE_MARK
+        # per owner it revoked: the run [cmd_id, val_lo] of the owner's
+        # values its phase 1 found held by all its answerers (chosen), the
+        # no-ops it proposed from inst to key_hi at ballot, of which those
+        # to key_lo are chosen. Turns of a chosen range that enter the
+        # window become committed no-ops here, in the steady step too, so
+        # the frontier never waits for a turn nobody will propose; before
+        # the commit scan, so my own revocation's count in this step.
+        # per owner over the announcing rows: [R, M] compares and a
+        # reduction, not a scatter (a scatter costs its updates: the
+        # inbox's rows, every step)
+        at_r = ((jnp.mod(inbox.inst, R)[None, :] == jnp.arange(R)[:, None])
+                & is_revoked[None, :])
+
+        def rows_by_owner(how, init, col):
+            pick = jnp.where(at_r, col[None, :], init)
+            return pick.min(axis=1) if how == "min" else pick.max(axis=1)
+
+        rd_t = rows_by_owner("min", REVOKE_NONE, inbox.inst)
+        rd_cm = rows_by_owner("max", -1, inbox.key_lo)
+        rd_hi = rows_by_owner("max", -1, inbox.key_hi)
+        rd_from = rows_by_owner("min", REVOKE_NONE, inbox.cmd_id)
+        rd_vend = rows_by_owner("max", -1, inbox.val_lo)
+        rd_bal = rows_by_owner("max", NO_BALLOT, inbox.ballot)
+        p_lo, p_hi, p_ok = _merge_range(state.rp_lo, state.rp_hi, rd_t, rd_hi,
+                                        state.window_base, R)
+        a_lo, a_hi, a_ok = _merge_range(state.rv_lo, state.rv_hi, rd_t, rd_hi,
+                                        state.window_base, R)
+        take = (rd_hi >= 0) & p_ok & a_ok
+        state = state._replace(
+            rp_lo=jnp.where(take, p_lo, state.rp_lo),
+            rp_hi=jnp.where(take, p_hi, state.rp_hi),
+            rp_bal=jnp.where(take, jnp.maximum(state.rp_bal, rd_bal),
+                             state.rp_bal),
+            rv_lo=jnp.where(take, a_lo, state.rv_lo),
+            rv_hi=jnp.where(take, a_hi, state.rv_hi),
+            rv_bal=jnp.where(take, jnp.maximum(state.rv_bal, rd_bal),
+                             state.rv_bal),
+            rv_cm=jnp.where(take, jnp.maximum(
+                jnp.where(state.rv_hi < state.window_base, -1, state.rv_cm),
+                rd_cm), state.rv_cm),
+            max_recv_ballot=jnp.maximum(
+                state.max_recv_ballot,
+                jnp.max(jnp.where(take, rd_bal, 0))),
+        )
+        noop_cm = ((idx_abs >= _by_owner(state.rv_lo, owner_of))
+                   & (idx_abs <= _by_owner(state.rv_cm, owner_of))
+                   & (state.status < COMMITTED))
+        val_cm = ((idx_abs >= _by_owner(jnp.where(take, rd_from, REVOKE_NONE),
+                                         owner_of))
+                  & (idx_abs <= _by_owner(rd_vend, owner_of))
+                  & (state.status == ACCEPTED)
+                  & (state.op != int(Op.NONE)))
+        state = state._replace(
+            status=jnp.where(noop_cm | val_cm, COMMITTED, state.status),
+            ballot=jnp.where(noop_cm, _by_owner(state.rv_bal, owner_of),
+                             state.ballot),
+            op=jnp.where(noop_cm, int(Op.NONE), state.op),
+            cmd_id=jnp.where(noop_cm, 0, state.cmd_id),
+            client_id=jnp.where(noop_cm, -1, state.client_id),
         )
 
     sec("px.commit_scan")
@@ -650,10 +1082,14 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
     ta_rel = ta_slots - state.window_base
     ta_rel_safe = jnp.clip(ta_rel, 0, S - 1)
     ta_status, ta_slot, _ = slots_at(ta_rel_safe)
+    ta_own = jnp.mod(ta_slots, R)
     ta_ok = ((state.tk_anchor >= 0) & (ta_rel >= 0) & (ta_rel < S)
              & (ta_status >= COMMITTED)
              & (ta_slot["ballot"] > 0)
-             & (jnp.mod(ta_slot["ballot"], 16) == me))
+             & (jnp.mod(ta_slot["ballot"], 16) == me)
+             # a revocation's no-ops every replica learns from 8r
+             & ~((ta_slots >= _by_owner(state.rv_lo, ta_own))
+                 & (ta_slots <= _by_owner(state.rv_cm, ta_own))))
     ta = MsgBatch(
         kind=jnp.where(ta_ok, int(MsgKind.COMMIT), 0).astype(jnp.int32),
         src=jnp.full(K2b, me, jnp.int32),
@@ -748,7 +1184,10 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         # competing sweepers start serialized instead of dueling ballots
         # on the same tick (the reference staggers forceCommit the same
         # way, mencius.go:878-886 "50+Id").
-        do_tk = (in_flight
+        # ... and only a replica that heard some peer in the last
+        # noop_delay ticks before this step: one that hears nobody (a
+        # dead replica of the pod, an isolated server) has no one to ask
+        do_tk = (in_flight & heard_lately
                  & ((i_am_successor & (state.stall_ticks >= cfg.noop_delay))
                     | (state.stall_ticks >= (4 + me) * cfg.noop_delay)))
         # fresh takeover ballot when starting a new takeover episode
@@ -796,8 +1235,13 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
             client_id=jnp.where(fill, -1, state.client_id),
             votes=jnp.where(fill, me_bit, state.votes),
         )
+        # never a value into turns a revocation proposes as no-ops: the
+        # two share the episode's ballot (7r)
+        revoked_here = ((idx_abs >= _by_owner(state.rv_lo, owner_of))
+                        & (idx_abs <= _by_owner(state.rv_hi, owner_of)))
         redrive = (do_tk & in_tk_span & (state.status == ACCEPTED)
-                   & ((state.ballot == tb) | (pv_cnt >= quorum1)))
+                   & ((state.ballot == tb) | (pv_cnt >= quorum1))
+                   & ~revoked_here)
         bump = redrive & (state.ballot != tb)
         state = state._replace(
             ballot=jnp.where(bump, tb, state.ballot),
@@ -820,14 +1264,87 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
             cmd_id=state.cmd_id[rd_rel_safe],
             client_id=state.client_id[rd_rel_safe],
         )
+        # the successor proper (not a laggard sweeping for itself, nor
+        # one whose peers report frontiers past its own: its view of
+        # the owner is stale) also revokes the blocking owner's turns
+        # from the blocking slot on, at the sweep's ballot (section 7r;
+        # its no-ops start above the sweep's span, one value a slot at
+        # one ballot)
+        peers_ahead = jnp.max(jnp.where(jnp.arange(R) == me, -1,
+                                        state.peer_commits))
+        # It waits two ticks into the episode, time for the owner to
+        # answer the sweep's PREPARE_INST if it is there (a live owner
+        # that only looked silent is not revoked), and once the
+        # revocation covers the blocking turn it is not begun again.
+        covered = ((_by_owner(state.rp_lo, blk_owner) <= blocking)
+                   & (blocking <= _by_owner(state.rp_hi, blk_owner)))
+        # paxlint: disable=trace-hazard -- `revoke` is a static bool
+        if revoke:
+            state = begin_revoke(
+                state,
+                do_tk & i_am_successor
+                & (state.stall_ticks >= cfg.noop_delay + 2)
+                & (state.rk_at < 0) & (blk_owner != me) & ~covered
+                & (peers_ahead <= state.committed_upto)
+                & (_by_owner(quiet0, blk_owner) >= cfg.noop_delay),
+                blk_owner, blocking, tb)
     # takeover episode ends when the frontier moves again
     state = state._replace(
         takeover_ballot=jnp.where(advanced, jnp.int32(NO_BALLOT),
                                   state.takeover_ballot))
 
+    # paxlint: disable=trace-hazard -- `revoke` is a static bool
+    if revoke:
+        sec("px.revoke_send")
+        # ---- 10r. my revocation's rows, in every step (section 7r) ----
+        # While it is in flight, its request (phase 1 or 2) every step.
+        # While an owner I revoked stays silent (``quiet``) and the chosen
+        # no-ops come within a window of my frontier, the next range, at
+        # the same ballot, with no stall first. And the chosen
+        # part of each range I revoked, while still in the window, every
+        # step.
+        mine = (state.rv_bal > NO_BALLOT) & (jnp.mod(state.rv_bal, 16) == me)
+        due = (mine & (state.rv_cm >= state.window_base)
+               & (state.rv_cm - state.committed_upto < S)
+               & (state.quiet >= cfg.noop_delay)
+               & (jnp.arange(R) != me) & (state.rk_at < 0))
+        o_n = jnp.argmax(due).astype(jnp.int32)
+        # (its phase 1 starts a sweep span early, over turns already
+        # chosen, so that its no-ops start right after them)
+        state = begin_revoke(state, due.any(), o_n,
+                             _by_owner(state.rv_cm, o_n) + R - sweep_span,
+                             _by_owner(state.rv_bal, o_n))
+        rk_row = MsgBatch.empty(1)._replace(
+            kind=jnp.where(state.rk_at >= 0, int(MsgKind.PREPARE_INST),
+                           0)[None].astype(jnp.int32),
+            src=jnp.full(1, me, jnp.int32),
+            inst=jnp.where(state.rk_phase == 1, state.rk_at, state.rk_t)[None],
+            ballot=state.rk_bal[None],
+            op=state.rk_phase[None],
+        )
+        shown = mine & (jnp.maximum(state.rv_cm, state.rv_vend)
+                        >= state.window_base)
+        o_s = jnp.argmax(shown).astype(jnp.int32)
+        rv_row = MsgBatch.empty(1)._replace(
+            kind=jnp.where(shown.any(), int(MsgKind.COMMIT),
+                           0)[None].astype(jnp.int32),
+            src=jnp.full(1, me, jnp.int32),
+            inst=_by_owner(state.rv_lo, o_s)[None],
+            ballot=_by_owner(state.rv_bal, o_s)[None],
+            last_committed=state.committed_upto[None],
+            key_hi=_by_owner(state.rv_hi, o_s)[None],
+            key_lo=_by_owner(state.rv_cm, o_s)[None],
+            val_lo=_by_owner(state.rv_vend, o_s)[None],
+            cmd_id=_by_owner(state.rv_from, o_s)[None],
+            client_id=jnp.full(1, REVOKE_MARK, jnp.int32),
+        )
+
     sec("px.outbox")
     out = _concat_rows(_concat_rows(_concat_rows(_concat_rows(_concat_rows(
         _concat_rows(_concat_rows(out, skip_row), cb), ta), rt), cu), tk), rd)
+    # paxlint: disable=trace-hazard -- `revoke` is a static bool
+    if revoke:
+        out = _concat_rows(_concat_rows(out, rk_row), rv_row)
     dst = jnp.concatenate([
         dst,
         jnp.full(1, -1, jnp.int32),    # skip broadcast
@@ -837,7 +1354,7 @@ def _mencius_step_sections(sec, cfg, state, inbox, tick_inc, steady):
         jnp.full(K4, cu_peer, jnp.int32),  # catch-up -> lagging peer
         jnp.full(K2, -1, jnp.int32),   # takeover sweep
         jnp.full(K2, -1, jnp.int32),   # takeover re-drive
-    ])
+    ] + [jnp.full(2, -1, jnp.int32)] * revoke)  # revocation request, announce
 
     sec("px.exec")
     # ---- 11. conflict-aware out-of-order execution (:799-876) ----

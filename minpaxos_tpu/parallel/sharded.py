@@ -85,11 +85,17 @@ DONATION = {
     "count_commands": False,
 }
 
-#: the command counts of a multi-owner (Mencius) pod, int32[3],
+#: the command counts of a multi-owner (Mencius) pod, int32[6],
 #: cumulative from boot: client commands the cursor replica's merged
 #: frontier has passed, no-op slots (ceded, skipped or taken over) it
-#: has passed, client commands their owners have assigned a slot
-N_COUNTS = 3
+#: has passed, client commands their owners have assigned a slot; then
+#: of those no-op slots the ones a takeover or a revocation made (at a
+#: ballot above 0, not by their owner), the takeover phase 1s begun by
+#: any replica (a sweep's episode, a revocation of a silent owner's
+#: turns), and the rounds at whose end the cursor's frontier stood
+#: where it stood a round before, in every group, while commands were
+#: in flight
+N_COUNTS = 6
 
 
 def _init_sharded(cfg: MinPaxosConfig, n_shards: int,
@@ -509,7 +515,13 @@ def _has_owners(states) -> bool:
 
 def _owner_cursors(states, cursor_rep):
     """What ``_count_round`` needs of the state BEFORE a round."""
-    return states.committed_upto[:, cursor_rep], states.crt_own
+    return (states.committed_upto[:, cursor_rep], states.crt_own,
+            _phase1s(states))
+
+
+def _phase1s(states):
+    """[G, R] bool: the replica has a takeover phase 1 in flight."""
+    return (states.takeover_ballot >= 0) | (states.rk_at >= 0)
 
 
 def _count_round(cfg: MinPaxosConfig, cursor_rep, before, states, counts,
@@ -540,7 +552,7 @@ def _count_round(cfg: MinPaxosConfig, cursor_rep, before, states, counts,
 
     The ring is anchored at the cursor's u_prev + 1 and cannot alias
     while every owner's cursor stays within a window of it."""
-    u_prev, co_prev = before
+    u_prev, co_prev, p1_prev = before
     w, n = cfg.window, cfg.n_replicas
     idx = jnp.arange(w, dtype=jnp.int32)
     co_new = states.crt_own
@@ -557,12 +569,22 @@ def _count_round(cfg: MinPaxosConfig, cursor_rep, before, states, counts,
     u_new = states.committed_upto[:, cursor_rep]
     passed = slot <= u_new[:, None]
     rel = slot - states.window_base[:, cursor_rep][:, None]
-    op_there = jnp.take_along_axis(states.op[:, cursor_rep],
-                                   jnp.clip(rel, 0, w - 1), axis=1)
-    is_cmd = passed & (rel >= 0) & (rel < w) & (op_there != noop)
+    # the op and whether a ballot above 0 wrote it, in ONE gather (a
+    # gather costs its elements: the window's, every round)
+    tagged = (states.op[:, cursor_rep].astype(jnp.int32)
+              | jnp.where(states.ballot[:, cursor_rep] > 0, 256, 0))
+    there = jnp.take_along_axis(tagged, jnp.clip(rel, 0, w - 1), axis=1)
+    in_win = passed & (rel >= 0) & (rel < w)
+    is_cmd = in_win & ((there & 255) != int(Op.NONE))
     commands = is_cmd.sum(dtype=jnp.int32)
+    revoked = (in_win & ~is_cmd & (there >= 256)).sum(dtype=jnp.int32)
+    begun = (_phase1s(states) & ~p1_prev).sum(dtype=jnp.int32)
     counts = counts + jnp.stack(
-        [commands, passed.sum(dtype=jnp.int32) - commands, assigned])
+        [commands, passed.sum(dtype=jnp.int32) - commands, assigned,
+         revoked, begun, jnp.int32(0)])
+    stalled = ((u_new == u_prev).all()
+               & (counts[2] > counts[0])).astype(jnp.int32)
+    counts = counts.at[5].add(stalled)
     if inj is None:
         return counts, None, None
     owner = jnp.mod(slot, n)
@@ -977,7 +999,9 @@ class ShardedCluster:
             "working_capacity": small_tier_rows(
                 cfg, ext_rows, _has_owners(self.ss.states)),
             "tiers": None, "gates": None, "command_commits": None,
-            "noop_slots": None, "round_gates": None,
+            "noop_slots": None, "revoked_slots": None,
+            "takeover_episodes": None, "stalled_rounds": None,
+            "round_gates": None,
             "state_transfers": None, "state_transfer_bytes": None,
             "lagging_rounds": None})
 
@@ -1169,7 +1193,9 @@ class ShardedCluster:
         of this call,
         in ``obs.process_pods()`` — with, for a multi-owner pod, the
         ``command_commits`` and ``noop_slots`` the cursor replica's
-        frontier passed over the same rounds. A pod whose step declares
+        frontier passed over the same rounds, and of its counts
+        (``N_COUNTS``) ``revoked_slots``, ``takeover_episodes`` and
+        ``stalled_rounds`` over them. A pod whose step declares
         round sections (``transfer_round``) adds its recovery counts
         over the same rounds: ``round_gates`` (as ``gates``, for the
         round's own sections: ``px.state_transfer``),
@@ -1185,9 +1211,13 @@ class ShardedCluster:
         self._pod["gates"] = dict(zip(
             self._sections, np.asarray(self._gate_opens).tolist()))
         if self._counts is not None:
-            commands, noops, _ = (np.asarray(self._counts)
-                                  - np.asarray(self._counts_armed)).tolist()
-            self._pod.update(command_commits=commands, noop_slots=noops)
+            commands, noops, _, revoked, episodes, stalled = (
+                np.asarray(self._counts)
+                - np.asarray(self._counts_armed)).tolist()
+            self._pod.update(command_commits=commands, noop_slots=noops,
+                             revoked_slots=revoked,
+                             takeover_episodes=episodes,
+                             stalled_rounds=stalled)
         out = {**self._pod["tiers"], "gates": dict(self._pod["gates"]),
                "working_capacity": self._pod["working_capacity"],
                "inbox": self._pod["inbox"]}
@@ -1208,12 +1238,16 @@ class ShardedCluster:
     def command_counts(self) -> dict:
         """A multi-owner pod's counts, cumulative from boot, at the
         cursor replica (0): client ``commands`` and ``noop_slots`` its
-        merged frontier has passed (together: every slot up to it) and
-        commands ``assigned`` a slot by their owners. Blocks on the
+        merged frontier has passed (together: every slot up to it),
+        commands ``assigned`` a slot by their owners, and
+        ``revoked_slots``, ``takeover_episodes``, ``stalled_rounds``
+        (``N_COUNTS``). Blocks on the
         device: a post-window read."""
-        commands, noops, assigned = np.asarray(self._counts).tolist()
+        (commands, noops, assigned, revoked, episodes,
+         stalled) = np.asarray(self._counts).tolist()
         return {"commands": commands, "noop_slots": noops,
-                "assigned": assigned}
+                "assigned": assigned, "revoked_slots": revoked,
+                "takeover_episodes": episodes, "stalled_rounds": stalled}
 
     def resident_telemetry(self) -> np.ndarray:
         """The paxray post-window telemetry readback: written rows

@@ -398,10 +398,10 @@ class ReplicaServer:
         if protocol == "mencius":
             from minpaxos_tpu.models.mencius import (
                 init_mencius,
-                mencius_step_impl,
+                mencius_served_step_impl,
             )
 
-            step_impl, init_fn = mencius_step_impl, init_mencius
+            step_impl, init_fn = mencius_served_step_impl, init_mencius
         else:
             step_impl, init_fn = replica_step_impl, init_replica
         # paxmon registry (obs/metrics.py) — replaces the old bare
@@ -806,6 +806,11 @@ class ReplicaServer:
                     crt_own=jnp.maximum(
                         self.state.crt_own,
                         jnp.int32(own_max + self.cfg.n_replicas)))
+            # and the takeover ballots it drew before the crash are
+            # never drawn again: one ballot, one value a slot
+            self.state = self.state._replace(
+                max_recv_ballot=jnp.maximum(self.state.max_recv_ballot,
+                                            jnp.int32(max_ballot)))
         elif max_ballot > 0:
             # restore the ballot promise (ballot low 4 bits = proposer
             # id, bareminpaxos.go:383-385)

@@ -108,6 +108,9 @@ class Bounds:
     electable: tuple[int, ...] = (1,)  # who the extra election may pick
     n_cmds: int = 2  # distinct client commands in the workload
     propose_to: tuple[int, ...] = (0,)  # ingress queues carrying them
+    #: replicas never scheduled (a crashed server): nothing is
+    #: delivered to them, they never tick, and they send nothing
+    silent: tuple[int, ...] = ()
     max_states: int = 400_000  # hard backstop: stop exploring, not CI
     max_transitions: int = 2_000_000
 
@@ -350,7 +353,8 @@ class Explorer:
             targets = ([r for r in range(self.R) if r != src]
                        if d == -1 else [d] if 0 <= d < self.R else [])
             for t in targets:
-                links[(src, t)] = links.get((src, t), ()) + (row,)
+                if t not in self.bounds.silent:
+                    links[(src, t)] = links.get((src, t), ()) + (row,)
         return links
 
     def _apply_step(self, states: tuple, links: dict, to: int,
@@ -420,6 +424,8 @@ class Explorer:
         states, links, (drops, dups, reorders, internal, elects) = node
         out: list[dict] = []
         for link in sorted(links):
+            if link[1] in self.bounds.silent:
+                continue
             out.append({"a": "deliver", "link": list(link)})
             if drops > 0:
                 out.append({"a": "drop", "link": list(link)})
@@ -428,7 +434,7 @@ class Explorer:
             if reorders > 0 and len(links[link]) >= 2:
                 out.append({"a": "reorder", "link": list(link)})
         for r in range(self.R):
-            if internal[r] > 0:
+            if internal[r] > 0 and r not in self.bounds.silent:
                 out.append({"a": "tick", "r": r})
         if elects > 0 and self.protocol != "mencius":
             for r in self.bounds.electable:

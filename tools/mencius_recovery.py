@@ -1,7 +1,8 @@
 """The Mencius pod through an idle owner and a dead one, on the chip.
 
-No cell of the benchmark has a fault in it (PERF.md section 7), so
-this is where the rounds that are NOT steady get a reading:
+The benchmark's cell ``mencius64k_owner_down`` holds the dead-owner
+case; this is where the idle and the REVIVED owner, which no cell
+holds, get a reading, beside it:
 ``mencius5_pod_64k``'s deployment (``deployments.side_shapes``
 ``mencius_64k``, the benchmark's key space and rounds a dispatch)
 through ``ShardedCluster.run_resident``, in phases of a few dispatches

@@ -258,7 +258,7 @@ def main() -> int:
     # and cannot be read back without one
     jax.config.update("jax_enable_compilation_cache", False)
     c = json.loads(pathlib.Path(args.config).read_text())
-    owners = c["runner"] == "pod_mencius"
+    owners = c["runner"].startswith("pod_mencius")
     cfg = MinPaxosConfig(**{k: c[k] for k in (
         "n_replicas", "window", "inbox", "exec_batch", "kv_pow2",
         "catchup_rows", "recovery_rows") + ("noop_delay",) * owners})
