@@ -25,6 +25,52 @@ linear-probing engine made XLA copy all four table arrays through two
 while carries per protocol step, ~80MB of pure copy traffic per tick
 at kv_pow2=20).
 
+The probe: a batch reads the ``slot``, ``key_hi`` and ``key_lo`` of
+each key's 2W candidate ways ONCE, shared by the lookup and the insert
+(``_probe``). The TPU gathers elements at some 14 ns apiece whatever
+each fetches, so the three columns are stacked as ``int32[3, C]`` and
+read by ONE gather of ``[B, 2W]`` indices, each fetching three words;
+as three element gathers they were 22 % of ``pod128_steady``'s round
+(three ``s32[655360]`` at 9.22 ms each) and 14 % of
+``mencius64k_steady``'s (PERF_LEDGER.jsonl). The stack axis is the
+MAJOR one: a ``[C, 3]`` row gather pads every fetched row to 128 lanes
+(PERF.md section 7 row 21), and under the pod's ``[G, R]`` vmap such a
+table leaf would be padded 40x. ``KVState`` keeps its four arrays
+(snapshots, the benchmark and the tests read them by position), so the
+stack is made on every call: an O(C) copy, 3 x 4 B x C a table (503 MB
+a round over ``pod128_steady``'s 640 tables of 2^16, tiled to four
+rows, 671 MB; 15.7 MB over ``mencius64k_steady``'s 80 of 2^14; 9.4 MB
+over the served cells' three of 2^18). The write side stays O(B): the
+four scatters update in place under the steps' donation.
+
+Device time on a TPU v5e, isolated, ms
+(``tools/scatter_micro.py kvprobe``; [G, R] tables of 2^p entries, B
+rows x 2W = 8 ways; the stack's copy included; ``insert`` is
+``_insert_probed`` whole, claims and write scatters with it):
+
+==============================  ========  =======  =======  ======  ======
+shape                           elements  barrier  _probe   insert  insert
+                                                            parent  _probe
+==============================  ========  =======  =======  ======  ======
+pod128 [128,5] x 128 <- 2^16       29.89    12.31    12.31   58.37   41.86
+mencius64k [16,5] x 320 <- 2^14     8.97     1.82     1.82   13.98    8.14
+served3 [3] x 128 <- 2^18          0.086    0.040    0.039   0.508   0.462
+==============================  ========  =======  =======  ======  ======
+
+``elements`` is the parent's three element gathers; ``barrier`` the
+stacked gather with an ``optimization_barrier`` on the stack, which
+compiles to the same program as without one (XLA neither splits the
+gather again nor folds the stack into it), so ``_probe`` has none.
+Temporaries: 2.9 -> 1,174.5 MB for the probe alone and 172.3 -> 676.0
+MB for the insert at pod128 (the stack, tiled ``T(4,128)``), 0.9 -> 0.0
+and 17.8 -> 4.4 MB at mencius64k, none at served3; in the whole round
+the peak rose by 26.5 MB in ``pod128_steady`` and 3.8 MB in
+``mencius64k_steady``. In the round the stacked gather reads 8.29 ms,
+0.90 of one of the three it replaced, and the round fell by 14.0 ms,
+not the 19.4 those two give: the stack's copy (about 4 ms at pod128,
+the isolated ``_probe`` less its gather) is the rest (PERF.md section
+6).
+
 Keys are 64-bit on the wire and (hi, lo) i32 lane pairs on device
 (ops/packed.py). Values are a ``[*, L]`` i32 lane axis: the engine
 (``kv_init`` / ``kv_lookup_lanes`` / ``kv_apply_batch_lanes``) is
@@ -109,17 +155,32 @@ def _cand_pos(capacity: int, k_hi: jnp.ndarray, k_lo: jnp.ndarray):
         axis=1)
 
 
+def _probe(kv: KVState, pos: jnp.ndarray):
+    """The ``slot``, ``key_hi`` and ``key_lo`` of every candidate way:
+    three ``i32[B, 2W]`` planes from ONE gather of the three columns
+    stacked as ``i32[3, C]`` (module docstring, "The probe")."""
+    got = jnp.stack([kv.slot, kv.key_hi, kv.key_lo])[:, pos]
+    return got[0], got[1], got[2]
+
+
 def kv_lookup_lanes(kv: KVState, k_hi: jnp.ndarray, k_lo: jnp.ndarray,
                     valid: jnp.ndarray | None = None):
     """Batched lookup: returns (found bool[B], v i32[B, L]).
 
-    One fixed [B, 2W] gather of the two candidate buckets — loop-free."""
-    c, lanes = kv.val.shape
+    One fixed [B, 2W] probe of the two candidate buckets — loop-free."""
+    pos = _cand_pos(kv.key_hi.shape[0], k_hi, k_lo)
+    return _lookup_probed(kv, pos, _probe(kv, pos), k_hi, k_lo, valid)
+
+
+def _lookup_probed(kv, pos, probe, k_hi, k_lo, valid):
+    """``kv_lookup_lanes`` on the candidate ways ``pos`` and their
+    ``_probe`` planes."""
+    lanes = kv.val.shape[1]
     if valid is None:
         valid = jnp.ones(k_hi.shape[0], dtype=bool)
-    pos = _cand_pos(c, k_hi, k_lo)
-    hit = ((kv.slot[pos] == LIVE) & (kv.key_hi[pos] == k_hi[:, None])
-           & (kv.key_lo[pos] == k_lo[:, None]) & valid[:, None])
+    slot, key_hi, key_lo = probe
+    hit = ((slot == LIVE) & (key_hi == k_hi[:, None])
+           & (key_lo == k_lo[:, None]) & valid[:, None])
     found = hit.any(axis=1)
     # at most one way holds a key; argmax picks it (0 when absent)
     way = jnp.argmax(hit, axis=1)
@@ -140,14 +201,14 @@ def kv_insert_unique(kv: KVState, k_hi, k_lo, v, delete, valid) -> KVState:
     """Insert/overwrite/delete a batch of rows with DISTINCT keys.
 
     ``v`` is i32[B, L]. Entirely LOOP-FREE (round-5 redesign): one
-    [B, 2W] gather of each key's two candidate buckets resolves every
+    [B, 2W] probe of each key's two candidate buckets resolves every
     row's destination in a single pass, then ONE batch of four
     scatters writes the table. Under the protocol steps' state
-    donation the scatters update in place, so total table traffic is
-    O(B) and independent of capacity — the round-4 linear-probe
+    donation the scatters update in place: the round-4 linear-probe
     engine's while carries made XLA copy all four table arrays per
     step and materialize capacity-length claim arrays per probe
-    round.
+    round. What does grow with capacity is the probe's stack of three
+    columns, an O(C) copy (module docstring, "The probe").
 
     Placement:
 
@@ -170,6 +231,14 @@ def kv_insert_unique(kv: KVState, k_hi, k_lo, v, delete, valid) -> KVState:
       (callers should size kv_pow2 comfortably above the distinct-key
       count, as with any bounded table; the TCP runtime fail-stops on
       dropped > 0 — runtime/replica.py)."""
+    pos = _cand_pos(kv.key_hi.shape[0], k_hi, k_lo)
+    return _insert_probed(kv, pos, _probe(kv, pos), k_hi, k_lo, v,
+                          delete, valid)
+
+
+def _insert_probed(kv, pos, probe, k_hi, k_lo, v, delete, valid):
+    """``kv_insert_unique`` on the candidate ways ``pos`` (``[B, 2W]``)
+    and their ``_probe`` planes."""
     c = kv.key_hi.shape[0]
     b = k_hi.shape[0]
     nb = c // WAYS
@@ -177,10 +246,9 @@ def kv_insert_unique(kv: KVState, k_hi, k_lo, v, delete, valid) -> KVState:
     rows = jnp.arange(b, dtype=jnp.int32)
     way_ix = jnp.arange(WAYS, dtype=jnp.int32)
 
-    pos = _cand_pos(c, k_hi, k_lo)  # [B, 2W]
-    s = kv.slot[pos]
-    live_match = ((s == LIVE) & (kv.key_hi[pos] == k_hi[:, None])
-                  & (kv.key_lo[pos] == k_lo[:, None]))
+    s, key_hi, key_lo = probe
+    live_match = ((s == LIVE) & (key_hi == k_hi[:, None])
+                  & (key_lo == k_lo[:, None]))
     has_match = live_match.any(axis=1)
     match_pos = pos[rows, jnp.argmax(live_match, axis=1)]
 
@@ -294,9 +362,17 @@ def _kv_apply_sections(sec, kv, op, k_hi, k_lo, v, valid):
     prev_present = has_prev & s_put[pw]
     prev_v = s_v[pw]
 
+    sec("px.kv.insert")
+    # ONE probe of the pre-batch table for the lookup and the insert,
+    # both at the sorted keys; in the insert's scope, where the pod
+    # (which drops the lookup) has always had it
+    cand = _cand_pos(kv.key_hi.shape[0], s_khi, s_klo)
+    probe = _probe(kv, cand)
+
     sec("px.kv.lookup")
     # pre-batch table state for rows with no in-batch predecessor
-    t_found, t_v = kv_lookup_lanes(kv, s_khi, s_klo, s_valid & ~has_prev)
+    t_found, t_v = _lookup_probed(kv, cand, probe, s_khi, s_klo,
+                                  s_valid & ~has_prev)
 
     sec("px.kv.output")
     eff_present = jnp.where(has_prev, prev_present, t_found)
@@ -320,9 +396,8 @@ def _kv_apply_sections(sec, kv, op, k_hi, k_lo, v, valid):
     seg_total = segmented_scan_max(seg_max_w[::-1], seg_end[::-1])[::-1]
     is_final_writer = s_write & (pos == seg_total)
 
-    kv = kv_insert_unique(
-        kv, s_khi, s_klo, s_v, delete=s_del, valid=is_final_writer
-    )
+    kv = _insert_probed(kv, cand, probe, s_khi, s_klo, s_v, s_del,
+                        is_final_writer)
     return kv, out, found
 
 

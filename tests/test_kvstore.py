@@ -168,3 +168,106 @@ def test_probe_chain_with_collisions():
     kv, out, found = _apply_np(kv, [Op.GET] * 12, keys, np.zeros(12))
     assert found.all()
     assert (out == keys + 1).all()
+
+
+# -- the probe pinned to the three element gathers it replaced: the
+# table's slot, key_hi and key_lo read one column at a time at the
+# candidate ways. Swapping ``kvstore._probe`` for this gives that
+# formulation of the engine, which every leaf and output must equal,
+# bit for bit.
+def _three_gathers(kv, pos):
+    return kv.slot[pos], kv.key_hi[pos], kv.key_lo[pos]
+
+
+def _both(monkeypatch, fn, *args):
+    """``fn(*args)`` jitted through the engine's probe and through the
+    three gathers (a fresh jit each, so neither reuses a trace)."""
+    from minpaxos_tpu.ops import kvstore
+
+    got = jax.jit(lambda *a: fn(*a))(*args)
+    with monkeypatch.context() as m:
+        m.setattr(kvstore, "_probe", _three_gathers)
+        want = jax.jit(lambda *a: fn(*a))(*args)
+    return got, want
+
+
+def _assert_bitwise(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.atleast_1d(g), np.atleast_1d(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g.view(np.uint8), w.view(np.uint8))
+
+
+def _spread(k):
+    """Small ints to 64-bit keys all over the hash's input."""
+    return (np.asarray(k, np.int64).astype(np.uint64)
+            * np.uint64(0x9E3779B97F4A7C15)).view(np.int64)
+
+
+def _batch(rng, b, keys, lanes):
+    """A PUT / GET / DELETE mix over ``keys`` distinct keys (so
+    duplicates within a batch, deletes of absent keys and keys already
+    live all occur), about a tenth of the rows invalid."""
+    ops = rng.choice([Op.PUT, Op.GET, Op.DELETE], size=b,
+                     p=[0.5, 0.3, 0.2]).astype(np.int32)
+    k_hi, k_lo = split_i64(_spread(rng.integers(0, keys, b)))
+    vals = rng.integers(-(2**31), 2**31, (b, lanes)).astype(np.int32)
+    valid = rng.random(b) < 0.9
+    return tuple(jnp.asarray(x) for x in (ops, k_hi, k_lo, vals, valid))
+
+
+@pytest.mark.parametrize("lanes,kv_pow2,keys,full", [
+    (2, 10, 300, False),  # roomy: nothing dropped
+    (2, 4, 64, True),  # 16 ways, 64 keys: both buckets full, dropped moves
+    (256, 8, 120, False),
+    (256, 4, 48, True),
+])
+def test_probe_equals_three_gathers(monkeypatch, lanes, kv_pow2, keys,
+                                    full):
+    from minpaxos_tpu.ops.kvstore import (
+        kv_apply_batch_lanes, kv_insert_unique, kv_lookup_lanes)
+
+    rng = np.random.default_rng(4200 + kv_pow2 + lanes)
+    kv = kv_init(kv_pow2, val_lanes=lanes)
+    for _ in range(4):
+        args = _batch(rng, 96, keys, lanes)
+        got, want = _both(monkeypatch, kv_apply_batch_lanes, kv, *args)
+        _assert_bitwise(got, want)  # every KVState leaf, out, found
+        kv = got[0]
+    assert (int(kv.dropped) > 0) == full
+    assert int((np.asarray(kv.slot) == 1).sum()) > 0
+    # each public entry point alone makes its own probe: a lookup of
+    # live and absent keys, an insert of distinct keys over live ones
+    _, k_hi, k_lo, vals, valid = _batch(rng, 64, keys, lanes)
+    _assert_bitwise(*_both(monkeypatch, kv_lookup_lanes, kv, k_hi, k_lo,
+                           valid))
+    k_hi, k_lo = split_i64(_spread(rng.permutation(keys)[:32]))
+    d = jnp.asarray(rng.random(32) < 0.3)
+    _assert_bitwise(*_both(monkeypatch, kv_insert_unique, kv,
+                           jnp.asarray(k_hi), jnp.asarray(k_lo),
+                           vals[:32], d, valid[:32]))
+
+
+def test_probe_equals_three_gathers_under_pod_vmap(monkeypatch):
+    """The pod applies its exec batches under a [G, R] vmap: the
+    stacked probe is then a batched gather, pinned the same way."""
+    g, r, b, kv_pow2 = 3, 2, 48, 4
+    rng = np.random.default_rng(4242)
+    kv = jax.tree_util.tree_map(
+        lambda x: jnp.broadcast_to(x, (g, r) + x.shape), kv_init(kv_pow2))
+
+    def apply(kv, op, k_hi, k_lo, v_hi, v_lo, valid):
+        return jax.vmap(jax.vmap(kv_apply_batch))(
+            kv, op, k_hi, k_lo, v_hi, v_lo, valid)
+
+    for _ in range(3):
+        cols = [_batch(rng, b, 40, 2) for _ in range(g * r)]
+        op, k_hi, k_lo, v, valid = (
+            jnp.stack([c[i] for c in cols]).reshape((g, r) + cols[0][i].shape)
+            for i in range(5))
+        got, want = _both(monkeypatch, apply, kv, op, k_hi, k_lo,
+                          v[..., 0], v[..., 1], valid)
+        _assert_bitwise(got, want)
+        kv = got[0]
+    assert (np.asarray(kv.dropped) > 0).any()  # 40 keys into 16 ways

@@ -33,6 +33,14 @@ M=1408 updates, S=2048 targets):
      outbox row, per destination, at the route's call shapes:
      ``python tools/scatter_micro.py route [part of a label]
      [leg=part of a formulation's name]``
+  k. KV probe: the slot, key_hi and key_lo of each key's 2W
+     candidate ways in the KV table (ops/kvstore.py ``_probe``), the
+     parent's three element gathers beside one gather of the stacked
+     ``[3, C]`` columns with and without an optimization barrier on
+     the stack (the stack's copy included), alone and inside the
+     insert, at the call shapes of the cells:
+     ``python tools/scatter_micro.py kvprobe [part of a label]
+     [leg=part of a formulation's name]``
 
 Run: python tools/scatter_micro.py (on the machine with the chip; one
 process owns it)
@@ -563,6 +571,91 @@ def route_leg(shapes=ROUTE_SHAPES) -> None:
                   f"temp {temp / 1e6:7.1f} MB  equal {same}", flush=True)
 
 
+# -- KV probe: "read the slot and the key of every candidate
+# way", ops/kvstore.py ``_probe`` inside ``kv_insert_unique``: [G, R]
+# tables of 2^kv_pow2 entries, B exec-batch rows x 2W ways each
+# (benchmarks/configs/*.json; the served shape is
+# minpaxos_tpu/deployments.py SERVER_SHAPE's).
+KVPROBE_SHAPES = [
+    ("pod128", 128, 5, 128, 16),
+    ("mencius64k", 16, 5, 320, 14),
+    ("served3", 3, 1, 128, 18),
+]
+
+
+def kv_probe_leg(shapes=KVPROBE_SHAPES) -> None:
+    """Leg k: the probe in every formulation at every shape, alone and
+    inside the insert (``_insert_probed``: claims and the four write
+    scatters too), device-timed, with the compiled program's
+    temporaries; ``_probe`` is what ``ops/kvstore.py`` ships."""
+    from minpaxos_tpu.ops import kvstore as kvs
+
+    def elements(kv, pos):  # the parent: a gather a column
+        return kv.slot[pos], kv.key_hi[pos], kv.key_lo[pos]
+
+    def stacked(barrier):
+        def f(kv, pos):
+            cols = jnp.stack([kv.slot, kv.key_hi, kv.key_lo])
+            if barrier:
+                cols = jax.lax.optimization_barrier(cols)
+            got = cols[:, pos]
+            return got[0], got[1], got[2]
+        return f
+
+    def insert(probe):
+        def f(kv, pos, k_hi, k_lo, v, delete, valid):
+            return kvs._insert_probed(kv, pos, probe(kv, pos), k_hi, k_lo,
+                                      v, delete, valid)
+        return f
+
+    probes = {"element gathers (parent)": elements,
+              "stacked [3, C], barrier": stacked(True),
+              "stacked [3, C]": stacked(False),
+              "_probe": kvs._probe}
+    legs = {**{name: (p, False) for name, p in probes.items()},
+            **{f"insert, {name}": (insert(p), True)
+               for name, p in probes.items()}}
+    only = [a[4:] for a in sys.argv[2:] if a.startswith("leg=")]
+    rng = np.random.default_rng(0)
+    for label, g, r, b, kv_pow2 in shapes:
+        c = 1 << kv_pow2
+
+        def ints(*shape):
+            return jnp.asarray(rng.integers(-2 ** 31, 2 ** 31, (g, r) + shape,
+                                            dtype=np.int64).astype(np.int32))
+
+        # a table a quarter live, and a batch of distinct random keys
+        kv = kvs.KVState(ints(c), ints(c), ints(c, kvs.VAL_LANES),
+                         jnp.asarray((rng.random((g, r, c)) < 0.25)
+                                     .astype(np.int32)),
+                         jnp.zeros((g, r), jnp.int32))
+        k_hi, k_lo, v = ints(b), ints(b), ints(b, kvs.VAL_LANES)
+        delete = jnp.asarray(rng.random((g, r, b)) < 0.1)
+        valid = jnp.ones((g, r, b), bool)
+        pos = jax.jit(jax.vmap(jax.vmap(
+            lambda h, lo: kvs._cand_pos(c, h, lo))))(k_hi, k_lo)
+        print(f"k. KV probe, {label}: [{g}, {r}] x {b} rows x "
+              f"{2 * kvs.WAYS} ways into 2^{kv_pow2} entries")
+        refs = {}
+        for name, (f, whole) in legs.items():
+            if only and not any(o in name for o in only):
+                continue
+            args = ((kv, pos, k_hi, k_lo, v, delete, valid) if whole
+                    else (kv, pos))
+            fn = jax.jit(jax.vmap(jax.vmap(f)))
+            temp = fn.lower(*args).compile() \
+                .memory_analysis().temp_size_in_bytes
+            got = [np.asarray(x) for x in jax.tree_util.tree_leaves(
+                fn(*args))]
+            ref = refs.setdefault(whole, got)
+            same = all((a == b).all() for a, b in zip(got, ref))
+            ms_dev = _device_ms(fn, *args)
+            dev = "not measured" if ms_dev is None else f"{ms_dev:9.3f} ms"
+            print(f"   {name:34s} device {dev}  host "
+                  f"{_time(fn, *args, iters=5):9.3f} ms  "
+                  f"temp {temp / 1e6:7.1f} MB  equal {same}", flush=True)
+
+
 def _labelled(shapes):
     return [sh for sh in shapes
             if all(a in sh[0] for a in sys.argv[2:] if "=" not in a)]
@@ -577,9 +670,12 @@ if __name__ == "__main__":
         state_read_leg(_labelled(STATEREAD_SHAPES))
     elif sys.argv[1:2] == ["route"]:  # leg j: [label part] [leg=part]
         route_leg(_labelled(ROUTE_SHAPES))
+    elif sys.argv[1:2] == ["kvprobe"]:  # leg k: [label part] [leg=part]
+        kv_probe_leg(_labelled(KVPROBE_SHAPES))
     else:
         main()
         rank_select_leg()
         slot_write_leg()
         state_read_leg()
         route_leg()
+        kv_probe_leg()
